@@ -658,6 +658,9 @@ def main():
     ap.add_argument("--history", default="BENCH_history.jsonl",
                     help="trajectory store to append to ('' disables)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     result = run_decode_step(args.ticks, args.out,
                              history_path=args.history or None)
     d = result["decode_step"]

@@ -330,6 +330,9 @@ def main():
     ap.add_argument("--ticks", type=int, default=12)
     ap.add_argument("--out", default="BENCH_decode_step.json")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     s = run_prefix(args.ticks, args.out)
     print(json.dumps(s, indent=1))
     h = s["headline"]

@@ -25,6 +25,9 @@ def main() -> None:
              "fingerprinted absolute-throughput record to ('' disables)",
     )
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     only = [s for s in args.only.split(",") if s]
     history = args.history or None
 

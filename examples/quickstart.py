@@ -13,7 +13,10 @@ import numpy as np
 from repro.core import (
     chunk_partial, finalize, make_schedule, merge, mha_decode_ref,
 )
+from repro.compile_cache import use_compile_cache
 from repro.kernels import lean_decode
+
+use_compile_cache()
 
 rng = np.random.default_rng(0)
 B, Hq, Hkv, S, d = 2, 8, 4, 1000, 64

@@ -9,12 +9,14 @@ stream-K schedule keep workers balanced as context lengths diverge.
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_smoke_config
 from repro.models import init_params
 from repro.serving.config import EngineConfig, PagedConfig
 from repro.serving.engine import DecodeEngine
 from repro.serving.scheduler import RequestState, Scheduler, SchedulerConfig
 
+use_compile_cache()
 cfg = get_smoke_config("mistral-nemo-12b")
 params = init_params(jax.random.PRNGKey(0), cfg)
 rng = np.random.default_rng(0)

@@ -3,6 +3,7 @@ config family), with checkpoint/restart demonstrated mid-run.
 
   PYTHONPATH=src python examples/train_lm.py
 """
+import os
 import shutil
 import subprocess
 import sys
@@ -18,6 +19,8 @@ base = [sys.executable, "-m", "repro.launch.train", "--arch",
         "mistral-nemo-12b", "--smoke", "--batch", "8", "--seq", "64",
         "--ckpt-dir", str(CKPT), "--ckpt-every", "50", "--log-every", "20"]
 env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"}
+if "JAX_COMPILATION_CACHE_DIR" in os.environ:       # the children's cache
+    env["JAX_COMPILATION_CACHE_DIR"] = os.environ["JAX_COMPILATION_CACHE_DIR"]
 
 print("== phase 1: 100 steps ==")
 subprocess.run(base + ["--steps", "100"], check=True, env=env)

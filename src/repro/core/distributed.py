@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -110,7 +109,7 @@ def sp_decode_attention(
         P(b_spec),
     )
     out_specs = P(b_spec, h_spec, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

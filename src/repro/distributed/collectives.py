@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.distributed import lean_merge_collective  # noqa: F401
@@ -36,7 +35,7 @@ def compressed_psum(x: jax.Array, mesh: Mesh, axis: str = "pod"):
         return s.astype(jnp.float32) * scale
 
     n = mesh.shape[axis]
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=P(axis), out_specs=P(axis),
         check_vma=False,

@@ -25,7 +25,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -86,7 +85,7 @@ def pipeline_forward(
         ) if n_stages > 1 else out["acc"]
         return acc
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),

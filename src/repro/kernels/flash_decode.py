@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -139,7 +138,7 @@ def flash_decode_partials(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -150,7 +149,7 @@ def flash_prefill(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -292,7 +291,7 @@ def flash_prefill_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N * Hq, Cp, d), q.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

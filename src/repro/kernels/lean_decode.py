@@ -49,7 +49,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core.leantile import LeanSchedule
 
 NEG_INF = -1e30
@@ -69,6 +68,19 @@ OP_PAD, OP_PARTIAL, OP_MERGE = 0, 1, 2
 def pack_descriptors(sched: LeanSchedule) -> np.ndarray:
     """Packed (7, G*T) int32 descriptors (memoized on the schedule)."""
     return sched.packed_descriptors()
+
+
+def scale_operands(k_scales, v_scales, index_map):
+    """BlockSpecs and operands of the int8 dequant sidecar, or ``([], ())``
+    for an fp pool. The ``(rows, 1)`` per-row scales ride as ``(rows, 1, 1)``
+    so each routed ``(1, 1, 1)`` block spans the array's last two dims —
+    the only block a single scalar may be on the TPU."""
+    if k_scales is None:
+        return [], ()
+    spec = pl.BlockSpec((1, 1, 1), index_map)
+    return [spec, spec], tuple(
+        s.astype(jnp.float32).reshape(-1, 1, 1) for s in (k_scales, v_scales)
+    )
 
 
 def _online_softmax_tile(
@@ -120,10 +132,10 @@ def _lean_decode_kernel(
     q_ref,         # (1, gq, d)     current segment's query group
     k_ref,         # (1, tile, d)   current LeanTile of K
     v_ref,         # (1, tile, d)   current LeanTile of V
-    *refs,         # [ks_ref (1,1), vs_ref (1,1)] when quantized, then:
+    *refs,         # [ks_ref (1,1,1), vs_ref (1,1,1)] when quantized, then:
                    # o_ref (1, gq, d)  partial un-scaled output (piece slot)
-                   # m_ref (1, gq)     partial row-max
-                   # l_ref (1, gq)     partial exp-sum
+                   # m_ref (1, gq, 1)  partial row-max
+                   # l_ref (1, gq, 1)  partial exp-sum
                    # acc_ref   VMEM (gq, d) f32
                    # m_acc_ref VMEM (gq, 1) f32
                    # l_acc_ref VMEM (gq, 1) f32
@@ -163,15 +175,15 @@ def _lean_decode_kernel(
         )
         _online_softmax_tile(
             q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
-            k_scale=None if ks_ref is None else ks_ref[0, 0],
-            v_scale=None if vs_ref is None else vs_ref[0, 0],
+            k_scale=None if ks_ref is None else ks_ref[0],
+            v_scale=None if vs_ref is None else vs_ref[0],
         )
 
         @pl.when(last == 1)
         def _flush():  # StorePartials (Algorithm 2 lines 20-22)
             o_ref[0] = acc_ref[...]
-            m_ref[0] = m_acc_ref[..., 0]
-            l_ref[0] = l_acc_ref[..., 0]
+            m_ref[0] = m_acc_ref[...]
+            l_ref[0] = l_acc_ref[...]
 
 
 def lean_decode_partials(
@@ -237,32 +249,25 @@ def lean_decode_partials(
     kv_map = kv_map_paged if paged else kv_map_dense
 
     def scale_map(g, t, desc, ctx, route):
-        return (route[g * T + t], 0)
+        return (route[g * T + t], 0, 0)
 
     def out_map(g, t, desc, *_):
         return (desc[DESC_PIECE, g * T + t], 0, 0)
-
-    def stat_map(g, t, desc, *_):
-        return (desc[DESC_PIECE, g * T + t], 0)
 
     in_specs = [
         pl.BlockSpec((1, gq, d), q_map),
         pl.BlockSpec((1, tile, d), kv_map),
         pl.BlockSpec((1, tile, d), kv_map),
     ]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ]
+    scale_specs, scale_inputs = scale_operands(k_scales, v_scales, scale_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if paged else 2,
         grid=(G, T),
-        in_specs=in_specs,
+        in_specs=in_specs + scale_specs,
         out_specs=[
             pl.BlockSpec((1, gq, d), out_map),
-            pl.BlockSpec((1, gq), stat_map),
-            pl.BlockSpec((1, gq), stat_map),
+            pl.BlockSpec((1, gq, 1), out_map),
+            pl.BlockSpec((1, gq, 1), out_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((gq, d), jnp.float32),
@@ -276,27 +281,22 @@ def lean_decode_partials(
     )
     out_shapes = [
         jax.ShapeDtypeStruct((P + 1, gq, d), jnp.float32),
-        jax.ShapeDtypeStruct((P + 1, gq), jnp.float32),
-        jax.ShapeDtypeStruct((P + 1, gq), jnp.float32),
+        jax.ShapeDtypeStruct((P + 1, gq, 1), jnp.float32),
+        jax.ShapeDtypeStruct((P + 1, gq, 1), jnp.float32),
     ]
     operands = (desc, seg_ctx.astype(jnp.int32))
     if paged:
         operands += (route.astype(jnp.int32),)
-    inputs = (q_seg, k_seg, v_seg)
-    if quant:
-        inputs += (
-            k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-        )
     o_p, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*operands, *inputs)
-    return o_p[:P], m_p[:P], l_p[:P]
+    )(*operands, q_seg, k_seg, v_seg, *scale_inputs)
+    return o_p[:P], m_p[:P, :, 0], l_p[:P, :, 0]
 
 
 def _lean_decode_fused_kernel(
@@ -305,15 +305,15 @@ def _lean_decode_fused_kernel(
     q_ref,         # (1, gq, d)
     k_ref,         # (1, tile, d)
     v_ref,         # (1, tile, d)
-    *refs,         # [ks_ref (1,1), vs_ref (1,1)] when quantized, then:
+    *refs,         # [ks_ref (1,1,1), vs_ref (1,1,1)] when quantized, then:
                    # o_ref (S, gq, d)  final outputs — VMEM-resident
-                   # lse_ref (S, gq)   final logsumexp
+                   # lse_ref (S, gq, 1) final logsumexp
                    # acc_ref   VMEM (gq, d) f32  shared partial/merge acc
                    # m_acc_ref VMEM (gq, 1) f32
                    # l_acc_ref VMEM (gq, 1) f32
                    # po_ref VMEM (P+1, gq, d) f32  piece partials (VMEM only)
-                   # pm_ref VMEM (P+1, gq) f32
-                   # pl_ref VMEM (P+1, gq) f32
+                   # pm_ref VMEM (P+1, gq, 1) f32
+                   # pl_ref VMEM (P+1, gq, 1) f32
     scale: float,
     tile_size: int,
     quantized: bool = False,
@@ -345,15 +345,15 @@ def _lean_decode_fused_kernel(
         )
         _online_softmax_tile(
             q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
-            k_scale=None if ks_ref is None else ks_ref[0, 0],
-            v_scale=None if vs_ref is None else vs_ref[0, 0],
+            k_scale=None if ks_ref is None else ks_ref[0],
+            v_scale=None if vs_ref is None else vs_ref[0],
         )
 
         @pl.when(last == 1)
         def _flush():  # StorePartials — into VMEM scratch, not HBM
             po_ref[pl.ds(piece, 1)] = acc_ref[...][None]
-            pm_ref[pl.ds(piece, 1)] = m_acc_ref[..., 0][None]
-            pl_ref[pl.ds(piece, 1)] = l_acc_ref[..., 0][None]
+            pm_ref[pl.ds(piece, 1)] = m_acc_ref[...][None]
+            pl_ref[pl.ds(piece, 1)] = l_acc_ref[...][None]
 
     @pl.when(op == OP_MERGE)
     def _merge():  # Algorithm 2 reduction, re-using the same accumulators
@@ -363,8 +363,8 @@ def _lean_decode_fused_kernel(
             m_acc_ref[...] = jnp.full_like(m_acc_ref, NEG_INF)
             l_acc_ref[...] = jnp.zeros_like(l_acc_ref)
 
-        m_piece = pm_ref[pl.ds(piece, 1)][0][:, None]       # (gq, 1)
-        l_piece = pl_ref[pl.ds(piece, 1)][0][:, None]
+        m_piece = pm_ref[pl.ds(piece, 1)][0]                # (gq, 1)
+        l_piece = pl_ref[pl.ds(piece, 1)][0]
         o_piece = po_ref[pl.ds(piece, 1)][0]                # (gq, d)
         m_new = jnp.maximum(m_acc_ref[...], m_piece)
         a_old = jnp.exp(m_acc_ref[...] - m_new)
@@ -378,7 +378,7 @@ def _lean_decode_fused_kernel(
             o_ref[pl.ds(seg, 1)] = (acc_ref[...] / l_acc_ref[...])[None]
             lse_ref[pl.ds(seg, 1)] = (
                 m_acc_ref[...] + jnp.log(l_acc_ref[...])
-            )[None, :, 0]
+            )[None]
 
 
 def fused_vmem_bytes(
@@ -460,36 +460,32 @@ def lean_decode_fused(
     kv_map = kv_map_paged if paged else kv_map_dense
 
     def scale_map(i, desc, ctx, route):
-        return (route[i], 0)
+        return (route[i], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, gq, d), q_map),
         pl.BlockSpec((1, tile, d), kv_map),
         pl.BlockSpec((1, tile, d), kv_map),
     ]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ]
+    scale_specs, scale_inputs = scale_operands(k_scales, v_scales, scale_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if paged else 2,
         grid=(N,),
-        in_specs=in_specs,
+        in_specs=in_specs + scale_specs,
         out_specs=[
             # whole-output blocks: the index maps are constant, so the
             # buffers stay VMEM-resident across the grid and flush to HBM
             # exactly once at the end — no revisit hazards
             pl.BlockSpec((S_seg, gq, d), lambda i, *_: (0, 0, 0)),
-            pl.BlockSpec((S_seg, gq), lambda i, *_: (0, 0)),
+            pl.BlockSpec((S_seg, gq, 1), lambda i, *_: (0, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((gq, d), jnp.float32),
             pltpu.VMEM((gq, 1), jnp.float32),
             pltpu.VMEM((gq, 1), jnp.float32),
             pltpu.VMEM((P + 1, gq, d), jnp.float32),
-            pltpu.VMEM((P + 1, gq), jnp.float32),
-            pltpu.VMEM((P + 1, gq), jnp.float32),
+            pltpu.VMEM((P + 1, gq, 1), jnp.float32),
+            pltpu.VMEM((P + 1, gq, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -498,26 +494,21 @@ def lean_decode_fused(
     )
     out_shapes = [
         jax.ShapeDtypeStruct((S_seg, gq, d), jnp.float32),
-        jax.ShapeDtypeStruct((S_seg, gq), jnp.float32),
+        jax.ShapeDtypeStruct((S_seg, gq, 1), jnp.float32),
     ]
     operands = (desc, seg_ctx.astype(jnp.int32))
     if paged:
         operands += (route.astype(jnp.int32),)
-    inputs = (q_seg, k_seg, v_seg)
-    if quant:
-        inputs += (
-            k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-        )
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(*operands, *inputs)
-    return o, lse
+    )(*operands, q_seg, k_seg, v_seg, *scale_inputs)
+    return o, lse[..., 0]
 
 
 # ------------------------------------------------------------------ paged
@@ -608,18 +599,18 @@ def _lean_cascade_fused_kernel(
     q_ref,         # (1, qmax, d)   current segment's stacked query block
     k_ref,         # (1, tile, d)
     v_ref,         # (1, tile, d)
-    *refs,         # [ks_ref (1,1), vs_ref (1,1)] when quantized, then:
+    *refs,         # [ks_ref (1,1,1), vs_ref (1,1,1)] when quantized, then:
                    # o_ref (S + 1, g, d)  final outputs (+ garbage row), VMEM
-                   # lse_ref (S + 1, g)
+                   # lse_ref (S + 1, g, 1)
                    # acc_ref   VMEM (qmax, d) f32  partial-phase accumulators
                    # m_acc_ref VMEM (qmax, 1) f32
                    # l_acc_ref VMEM (qmax, 1) f32
                    # g_acc_ref VMEM (g, d) f32     merge-phase accumulators
                    # g_m_ref   VMEM (g, 1) f32
                    # g_l_ref   VMEM (g, 1) f32
-                   # po_ref VMEM (P_tot + 1, qmax, d) f32  piece partials
-                   # pm_ref VMEM (P_tot + 1, qmax) f32
-                   # pl_ref VMEM (P_tot + 1, qmax) f32
+                   # po_ref VMEM (P_tot + 1, nmax, g, d) f32  piece partials
+                   # pm_ref VMEM (P_tot + 1, nmax, g, 1) f32  (member-ranked)
+                   # pl_ref VMEM (P_tot + 1, nmax, g, 1) f32
     scale: float,
     tile_size: int,
     gq: int,
@@ -652,15 +643,17 @@ def _lean_cascade_fused_kernel(
         )
         _online_softmax_tile(
             q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
-            k_scale=None if ks_ref is None else ks_ref[0, 0],
-            v_scale=None if vs_ref is None else vs_ref[0, 0],
+            k_scale=None if ks_ref is None else ks_ref[0],
+            v_scale=None if vs_ref is None else vs_ref[0],
         )
 
         @pl.when(last == 1)
-        def _flush():
-            po_ref[pl.ds(piece, 1)] = acc_ref[...][None]
-            pm_ref[pl.ds(piece, 1)] = m_acc_ref[..., 0][None]
-            pl_ref[pl.ds(piece, 1)] = l_acc_ref[..., 0][None]
+        def _flush():  # split the stacked rows by member rank
+            for r in range(po_ref.shape[1]):
+                rows = pl.ds(r * gq, gq)
+                po_ref[pl.ds(piece, 1), r] = acc_ref[rows, :][None]
+                pm_ref[pl.ds(piece, 1), r] = m_acc_ref[rows, :][None]
+                pl_ref[pl.ds(piece, 1), r] = l_acc_ref[rows, :][None]
 
     @pl.when(op == OP_MERGE)
     def _merge():
@@ -670,13 +663,10 @@ def _lean_cascade_fused_kernel(
             g_m_ref[...] = jnp.full_like(g_m_ref, NEG_INF)
             g_l_ref[...] = jnp.zeros_like(g_l_ref)
 
-        off = desc_ref[DESC_TILE, i] * gq      # member rank -> row offset
-        o_row = po_ref[pl.ds(piece, 1)][0]     # (qmax, d)
-        m_row = pm_ref[pl.ds(piece, 1)][0]     # (qmax,)
-        l_row = pl_ref[pl.ds(piece, 1)][0]
-        o_piece = jax.lax.dynamic_slice_in_dim(o_row, off, gq, axis=0)
-        m_piece = jax.lax.dynamic_slice_in_dim(m_row, off, gq, axis=0)[:, None]
-        l_piece = jax.lax.dynamic_slice_in_dim(l_row, off, gq, axis=0)[:, None]
+        rank = pl.ds(desc_ref[DESC_TILE, i], 1)  # member rank
+        o_piece = po_ref[pl.ds(piece, 1), rank][0, 0]   # (g, d)
+        m_piece = pm_ref[pl.ds(piece, 1), rank][0, 0]   # (g, 1)
+        l_piece = pl_ref[pl.ds(piece, 1), rank][0, 0]
         m_new = jnp.maximum(g_m_ref[...], m_piece)
         a_old = jnp.exp(g_m_ref[...] - m_new)
         a_new = jnp.exp(m_piece - m_new)
@@ -689,7 +679,7 @@ def _lean_cascade_fused_kernel(
             o_ref[pl.ds(seg, 1)] = (g_acc_ref[...] / g_l_ref[...])[None]
             lse_ref[pl.ds(seg, 1)] = (
                 g_m_ref[...] + jnp.log(g_l_ref[...])
-            )[None, :, 0]
+            )[None]
 
 
 def cascade_fused_vmem_bytes(
@@ -740,6 +730,7 @@ def lean_cascade_fused(
     N = csched.fused_grid_iters
     Ptot = csched.num_pieces_total
     S = csched.batch * csched.num_kv_heads
+    nmax = qmax // gq
     quant = k_scales is not None
 
     def q_map(i, desc, *_):
@@ -749,26 +740,19 @@ def lean_cascade_fused(
     def kv_map(i, desc, ctx, route):
         return (route[i], 0, 0)
 
-    def scale_map(i, desc, ctx, route):
-        return (route[i], 0)
-
     in_specs = [
         pl.BlockSpec((1, qmax, d), q_map),
         pl.BlockSpec((1, tile, d), kv_map),
         pl.BlockSpec((1, tile, d), kv_map),
     ]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ]
+    scale_specs, scale_inputs = scale_operands(k_scales, v_scales, kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(N,),
-        in_specs=in_specs,
+        in_specs=in_specs + scale_specs,
         out_specs=[
             pl.BlockSpec((S + 1, gq, d), lambda i, *_: (0, 0, 0)),
-            pl.BlockSpec((S + 1, gq), lambda i, *_: (0, 0)),
+            pl.BlockSpec((S + 1, gq, 1), lambda i, *_: (0, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((qmax, d), jnp.float32),
@@ -777,9 +761,9 @@ def lean_cascade_fused(
             pltpu.VMEM((gq, d), jnp.float32),
             pltpu.VMEM((gq, 1), jnp.float32),
             pltpu.VMEM((gq, 1), jnp.float32),
-            pltpu.VMEM((Ptot + 1, qmax, d), jnp.float32),
-            pltpu.VMEM((Ptot + 1, qmax), jnp.float32),
-            pltpu.VMEM((Ptot + 1, qmax), jnp.float32),
+            pltpu.VMEM((Ptot + 1, nmax, gq, d), jnp.float32),
+            pltpu.VMEM((Ptot + 1, nmax, gq, 1), jnp.float32),
+            pltpu.VMEM((Ptot + 1, nmax, gq, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -788,35 +772,30 @@ def lean_cascade_fused(
     )
     out_shapes = [
         jax.ShapeDtypeStruct((S + 1, gq, d), jnp.float32),
-        jax.ShapeDtypeStruct((S + 1, gq), jnp.float32),
+        jax.ShapeDtypeStruct((S + 1, gq, 1), jnp.float32),
     ]
-    inputs = (q_stack, k_rows, v_rows)
-    if quant:
-        inputs += (
-            k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-        )
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(
         desc.astype(jnp.int32), ctx_all.astype(jnp.int32),
-        route.astype(jnp.int32), *inputs,
+        route.astype(jnp.int32), q_stack, k_rows, v_rows, *scale_inputs,
     )
-    return o[:S], lse[:S]
+    return o[:S], lse[:S, :, 0]
 
 
 def _lean_merge_kernel(
     meta_ref,      # (2, S) scalar prefetch: piece start / piece count
     o_p_ref,       # (1, gq, d)  one piece's partial o (revisited per j)
-    m_p_ref,       # (1, gq)
-    l_p_ref,       # (1, gq)
+    m_p_ref,       # (1, gq, 1)
+    l_p_ref,       # (1, gq, 1)
     o_ref,         # (1, gq, d)  final output for this segment
-    l_out_ref,     # (1, gq)     logsumexp (for paged/backward use)
+    l_out_ref,     # (1, gq, 1)  logsumexp (for paged/backward use)
     acc_ref,       # VMEM (gq, d) f32
     m_acc_ref,     # VMEM (gq, 1)
     l_acc_ref,     # VMEM (gq, 1)
@@ -833,11 +812,11 @@ def _lean_merge_kernel(
 
     @pl.when(j < cnt)
     def _merge():  # Algorithm 2 lines 29-35: softmax re-scaling reduction
-        m_piece = m_p_ref[0][:, None]
+        m_piece = m_p_ref[0]
         m_new = jnp.maximum(m_acc_ref[...], m_piece)
         a_old = jnp.exp(m_acc_ref[...] - m_new)
         a_new = jnp.exp(m_piece - m_new)
-        l_acc_ref[...] = a_old * l_acc_ref[...] + a_new * l_p_ref[0][:, None]
+        l_acc_ref[...] = a_old * l_acc_ref[...] + a_new * l_p_ref[0]
         acc_ref[...] = a_old * acc_ref[...] + a_new * o_p_ref[0].astype(
             jnp.float32
         )
@@ -846,7 +825,7 @@ def _lean_merge_kernel(
     @pl.when(j == pl.num_programs(1) - 1)
     def _final():  # Algorithm 2 lines 38-39
         o_ref[0] = acc_ref[...] / l_acc_ref[...]
-        l_out_ref[0] = (m_acc_ref[...] + jnp.log(l_acc_ref[...]))[:, 0]
+        l_out_ref[0] = m_acc_ref[...] + jnp.log(l_acc_ref[...])
 
 
 def lean_merge_pallas(
@@ -872,21 +851,20 @@ def lean_merge_pallas(
         row = meta[0, s] + jnp.minimum(j, meta[1, s] - 1)
         return (jnp.clip(row, 0, P - 1), 0, 0)
 
-    def piece_stat_map(s, j, meta):
-        row = meta[0, s] + jnp.minimum(j, meta[1, s] - 1)
-        return (jnp.clip(row, 0, P - 1), 0)
+    def seg_map(s, j, meta):
+        return (s, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(S, pmax),
         in_specs=[
             pl.BlockSpec((1, gq, d), piece_map),
-            pl.BlockSpec((1, gq), piece_stat_map),
-            pl.BlockSpec((1, gq), piece_stat_map),
+            pl.BlockSpec((1, gq, 1), piece_map),
+            pl.BlockSpec((1, gq, 1), piece_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, gq, d), lambda s, j, meta: (s, 0, 0)),
-            pl.BlockSpec((1, gq), lambda s, j, meta: (s, 0)),
+            pl.BlockSpec((1, gq, d), seg_map),
+            pl.BlockSpec((1, gq, 1), seg_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((gq, d), jnp.float32),
@@ -896,15 +874,15 @@ def lean_merge_pallas(
     )
     out_shapes = [
         jax.ShapeDtypeStruct((S, gq, d), jnp.float32),
-        jax.ShapeDtypeStruct((S, gq), jnp.float32),
+        jax.ShapeDtypeStruct((S, gq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
         _lean_merge_kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(meta, o_p, m_p, l_p)
-    return o, lse
+    )(meta, o_p, m_p[..., None], l_p[..., None])
+    return o, lse[..., 0]

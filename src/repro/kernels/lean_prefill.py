@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core.leantile import LeanSchedule
 from .lean_decode import (
     DESC_FIRST,
@@ -45,6 +44,7 @@ from .lean_decode import (
     NEG_INF,
     OP_PARTIAL,
     pack_descriptors,
+    scale_operands,
 )
 
 
@@ -56,10 +56,10 @@ def _lean_prefill_kernel(
     q_ref,         # (1, gq, d)    gq = g * chunk_cap query rows
     k_ref,         # (1, tile, d)  current LeanTile fetched via route
     v_ref,         # (1, tile, d)
-    *refs,         # [ks_ref (1,1), vs_ref (1,1)] when quantized, then:
+    *refs,         # [ks_ref (1,1,1), vs_ref (1,1,1)] when quantized, then:
                    # o_ref (1, gq, d)  partial un-scaled output (piece slot)
-                   # m_ref (1, gq)
-                   # l_ref (1, gq)
+                   # m_ref (1, gq, 1)
+                   # l_ref (1, gq, 1)
                    # acc_ref   VMEM (gq, d) f32
                    # m_acc_ref VMEM (gq, 1) f32
                    # l_acc_ref VMEM (gq, 1) f32
@@ -99,9 +99,9 @@ def _lean_prefill_kernel(
         k = k_ref[0].astype(jnp.float32)                   # (tile, d)
         v = v_ref[0].astype(jnp.float32)
         if ks_ref is not None:
-            k = k * ks_ref[0, 0]                           # int8 tile dequant
+            k = k * ks_ref[0]                              # int8 tile dequant
         if vs_ref is not None:
-            v = v * vs_ref[0, 0]
+            v = v * vs_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -130,8 +130,8 @@ def _lean_prefill_kernel(
         @pl.when(last == 1)
         def _flush():
             o_ref[0] = acc_ref[...]
-            m_ref[0] = m_acc_ref[..., 0]
-            l_ref[0] = l_acc_ref[..., 0]
+            m_ref[0] = m_acc_ref[...]
+            l_ref[0] = l_acc_ref[...]
 
 
 def lean_prefill_chunk_partials(
@@ -178,33 +178,23 @@ def lean_prefill_chunk_partials(
     def kv_map(g, t, desc, ctx, qstart, route):
         return (route[g * T + t], 0, 0)
 
-    def scale_map(g, t, desc, ctx, qstart, route):
-        return (route[g * T + t], 0)
-
     def out_map(g, t, desc, *_):
         return (desc[DESC_PIECE, g * T + t], 0, 0)
-
-    def stat_map(g, t, desc, *_):
-        return (desc[DESC_PIECE, g * T + t], 0)
 
     in_specs = [
         pl.BlockSpec((1, gq, d), q_map),
         pl.BlockSpec((1, tile, d), kv_map),
         pl.BlockSpec((1, tile, d), kv_map),
     ]
-    if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), scale_map),
-            pl.BlockSpec((1, 1), scale_map),
-        ]
+    scale_specs, scale_inputs = scale_operands(k_scales, v_scales, kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(G, T),
-        in_specs=in_specs,
+        in_specs=in_specs + scale_specs,
         out_specs=[
             pl.BlockSpec((1, gq, d), out_map),
-            pl.BlockSpec((1, gq), stat_map),
-            pl.BlockSpec((1, gq), stat_map),
+            pl.BlockSpec((1, gq, 1), out_map),
+            pl.BlockSpec((1, gq, 1), out_map),
         ],
         scratch_shapes=[
             pltpu.VMEM((gq, d), jnp.float32),
@@ -219,19 +209,14 @@ def lean_prefill_chunk_partials(
     )
     out_shapes = [
         jax.ShapeDtypeStruct((P + 1, gq, d), jnp.float32),
-        jax.ShapeDtypeStruct((P + 1, gq), jnp.float32),
-        jax.ShapeDtypeStruct((P + 1, gq), jnp.float32),
+        jax.ShapeDtypeStruct((P + 1, gq, 1), jnp.float32),
+        jax.ShapeDtypeStruct((P + 1, gq, 1), jnp.float32),
     ]
-    inputs = (q_seg, k_rows, v_rows)
-    if quant:
-        inputs += (
-            k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
-        )
     o_p, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -240,6 +225,6 @@ def lean_prefill_chunk_partials(
         seg_ctx.astype(jnp.int32),
         seg_qstart.astype(jnp.int32),
         route.astype(jnp.int32),
-        *inputs,
+        q_seg, k_rows, v_rows, *scale_inputs,
     )
-    return o_p[:P], m_p[:P], l_p[:P]
+    return o_p[:P], m_p[:P, :, 0], l_p[:P, :, 0]

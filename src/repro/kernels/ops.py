@@ -70,6 +70,7 @@ __all__ = [
     "lean_decode_cascade_from_schedule",
     "cascade_tables",
     "cascade_uses_fused",
+    "decode_uses_fused",
     "lean_prefill_chunks",
     "flash_decode",
     "flash_decode_from_lens",
@@ -121,6 +122,17 @@ def _clamp_ctx_lens(ctx_lens: Sequence[int], caps, what: str, note=None):
 # fused-path resident-state budget; ~half of a TPU core's VMEM, leaving room
 # for pipelined KV tiles. Schedules above this fall back to two-phase.
 FUSED_VMEM_BUDGET = 8 * 2**20
+
+
+def decode_uses_fused(
+    sched: LeanSchedule, gq: int, d: int, kv_elem_bytes: int = 4
+) -> bool:
+    """Whether the fused single-kernel decode fits the VMEM budget for this
+    schedule (else the two-phase kernels run). ``kv_elem_bytes`` is the KV
+    element width: 2 for bf16, 1 for int8 pools."""
+    return fused_vmem_bytes(
+        sched, gq, d, kv_elem_bytes=kv_elem_bytes
+    ) <= FUSED_VMEM_BUDGET
 
 
 def default_num_workers(n_cores: int = 8, pipeline_factor: int = 2) -> int:
@@ -330,11 +342,9 @@ def _dense_decode_impl(q, k, v, seg_ctx, plan: DecodePlan):
     gq = q_seg.shape[1]
     seg_ctx = seg_ctx.astype(jnp.int32)
 
-    kv_eb = jnp.dtype(k.dtype).itemsize
-    if fused and fused_vmem_bytes(
-        sched, gq, d, kv_elem_bytes=kv_eb
-    ) > FUSED_VMEM_BUDGET:
-        fused = False
+    fused = fused and decode_uses_fused(
+        sched, gq, d, kv_elem_bytes=jnp.dtype(k.dtype).itemsize
+    )
     if fused:
         o_seg, lse = lean_decode_fused(
             q_seg, k_seg, v_seg, seg_ctx, sched, scale,
@@ -497,11 +507,9 @@ def _paged_decode_impl(q, k_pool, v_pool, seg_ctx, page_tbl,
         k_pool, v_pool, k_scales, v_scales
     )
 
-    kv_eb = jnp.dtype(k_pool.dtype).itemsize
-    if fused and fused_vmem_bytes(
-        sched, gq, d, kv_elem_bytes=kv_eb
-    ) > FUSED_VMEM_BUDGET:
-        fused = False
+    fused = fused and decode_uses_fused(
+        sched, gq, d, kv_elem_bytes=jnp.dtype(k_pool.dtype).itemsize
+    )
     route = _paged_route(sched, page_tbl, Hkv, fused)
     if fused:
         o_seg, lse = lean_decode_paged_fused(

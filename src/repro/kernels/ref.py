@@ -2,21 +2,35 @@
 
 These are the ground truth in kernel sweeps (tests/test_kernels_*.py):
 each kernel output must ``assert_allclose`` against its oracle over a grid
-of shapes/dtypes, including ragged context lengths.
+of shapes/dtypes, including ragged context lengths. Each oracle runs its
+matmuls at ``precision="highest"``: on a TPU a float32 matmul otherwise
+runs in bf16 passes, and fp32 inputs would get a bf16 reference.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 
 from repro.core.attention import (
+    fixed_split_decode,
+    mha_chunk_prefill_paged_ref,
     mha_decode_ref,
     mha_prefill_ref,
-    fixed_split_decode,
 )
 
 
+def _highest(fn):
+    @functools.wraps(fn)
+    def at_highest(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return at_highest
+
+
+@_highest
 def lean_decode_ref(
     q: jax.Array,
     k: jax.Array,
@@ -28,6 +42,7 @@ def lean_decode_ref(
     return mha_decode_ref(q, k, v, ctx_lens=ctx_lens, scale=scale)
 
 
+@_highest
 def flash_decode_ref(
     q: jax.Array,
     k: jax.Array,
@@ -39,6 +54,7 @@ def flash_decode_ref(
     return mha_decode_ref(q, k, v, ctx_lens=ctx_lens, scale=scale)
 
 
+@_highest
 def flash_prefill_ref(
     q: jax.Array,
     k: jax.Array,
@@ -53,4 +69,20 @@ def flash_prefill_ref(
     )
 
 
-fixed_split_decode_ref = fixed_split_decode
+@_highest
+def lean_prefill_chunks_ref(
+    q: jax.Array,           # (N, Hq, C, d) one prompt chunk per row
+    k_pool: jax.Array,      # (num_pages, Hkv, page_size, d)
+    v_pool: jax.Array,
+    page_tbls: jax.Array,   # (N, W) int32
+    offs: jax.Array,        # (N,) int32 absolute position of each q[0]
+    scale: Optional[float] = None,
+) -> jax.Array:
+    """Chunked prefill through the page table: gather, then causal attention
+    at each row's own depth (oracle of ``ops.lean_prefill_chunks``)."""
+    return mha_chunk_prefill_paged_ref(
+        q, k_pool, v_pool, page_tbls, offs, scale=scale
+    )
+
+
+fixed_split_decode_ref = _highest(fixed_split_decode)

@@ -16,6 +16,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, batch_at
 from repro.models import init_params
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dcfg = DataConfig(

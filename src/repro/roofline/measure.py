@@ -67,11 +67,9 @@ class CostVec:
 
 
 def cost_of(compiled, hlo_text) -> CostVec:
-    from repro import compat
-
     from .analysis import collective_bytes
 
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     colls = collective_bytes(hlo_text)
     return CostVec(
         float(ca.get("flops", 0.0)),

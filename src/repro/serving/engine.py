@@ -74,6 +74,7 @@ from repro.kernels import flash_decode, lean_decode
 from repro.kernels.ops import (
     cascade_tables,
     cascade_uses_fused,
+    decode_uses_fused,
     flash_decode_from_lens,
     flash_prefill_paged,
     lean_decode_cascade_from_schedule,
@@ -152,6 +153,7 @@ _STAT_COUNTERS = (
     "prefix_matched_tokens",     # prompt tokens served from the radix cache
     "prefix_attach_count",       # admissions that hit the radix cache
     "cow_copies",                # copy-on-write page copies
+    "fused_decode_ticks",        # fast-path lean ticks on the fused kernel
     "cascade_ticks",             # decode ticks run on the cascade path
     "cascade_grouped_slots",     # cumulative slots decoded via a group
     "cascade_grouped_passes",    # cumulative grouped passes executed
@@ -714,6 +716,8 @@ class DecodeEngine:
 
             cfg = dataclasses.replace(cfg, kv_cache_dtype=kv_dtype)
         self.quant = paged and cfg.kv_cache_dtype == "int8"
+        # KV cache element width: int8/f8 caches are 1 byte, all else bf16
+        self.kv_elem_bytes = 1 if cfg.kv_cache_dtype in ("int8", "f8") else 2
         if cfg.kv_cache_dtype == "int8" and not paged:
             raise ValueError(
                 "kv_dtype='int8' quantizes the paged pools — requires "
@@ -1720,8 +1724,7 @@ class DecodeEngine:
             prefix_tbl, suffix_tbl = cascade_tables(ptbl_np, binding)
             fused = self.cascade_fused and cascade_uses_fused(
                 csched, self.cfg.n_heads // self.cfg.n_kv_heads,
-                self.cfg.head_dim,
-                kv_elem_bytes=1 if self.quant else 2,
+                self.cfg.head_dim, kv_elem_bytes=self.kv_elem_bytes,
             )
             fused_desc = self._cascade_fused_desc(csched, binding, fused)
             if csched.signature not in self._casc_signatures:
@@ -1778,6 +1781,14 @@ class DecodeEngine:
                     int(sched.seg_len.max(initial=1)),
                     sched.num_segments, self.tile, self.num_workers,
                 )
+                if self.attn_backend == "lean":
+                    self.stats.fused_decode_ticks += int(
+                        self.fused and decode_uses_fused(
+                            sched, self.cfg.n_heads // self.cfg.n_kv_heads,
+                            self.cfg.head_dim,
+                            kv_elem_bytes=self.kv_elem_bytes,
+                        )
+                    )
                 with _quiet_donation():
                     if self.paged:
                         logits, self.cache = self._jit_kernel_step_paged(

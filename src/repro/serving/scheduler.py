@@ -709,6 +709,7 @@ class Scheduler:
             "prefix_matched_tokens": es.prefix_matched_tokens,
             "prefix_attach_count": es.prefix_attach_count,
             "cow_copies": es.cow_copies,
+            "fused_decode_ticks": es.fused_decode_ticks,
             "cascade_ticks": es.cascade_ticks,
             "cascade_fused_ticks": es.cascade_fused_ticks,
             "cascade_grouped_passes": es.cascade_grouped_passes,

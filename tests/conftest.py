@@ -1,14 +1,10 @@
-"""Test bootstrap: register the hypothesis stub when the real package is
-absent (the pinned container has no hypothesis and installs are disallowed)."""
-import sys
-from pathlib import Path
+"""Test bootstrap: one hypothesis profile for the whole suite.
 
-sys.path.insert(0, str(Path(__file__).resolve().parent))
+Interpret-mode Pallas examples compile on first use, so a per-example
+deadline measures the compiler, not the property; and the example database
+would write ``.hypothesis/`` into the checkout. Both are off.
+"""
+from hypothesis import settings
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _hypothesis_stub
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
+settings.register_profile("repro", deadline=None, database=None)
+settings.load_profile("repro")

@@ -58,6 +58,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.training.train_loop import make_train_step
         from repro.distributed.sharding import param_specs, batch_specs, to_named
         from repro.distributed.hints import activation_mesh
+        from repro.launch.mesh import make_host_mesh
 
         cfg = ModelConfig(name="t", d_model=32, n_layers=2, n_heads=4,
             n_kv_heads=2, head_dim=8, d_ff=64, vocab_size=64,
@@ -70,7 +71,7 @@ def test_sharded_train_step_matches_single_device():
 
         p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_host_mesh((4, 2), ("data", "model"))
         pspec = param_specs(params, mesh, cfg)
         put = lambda t, s: jax.device_put(t, NamedSharding(mesh, s))
         params_s = jax.tree.map(put, params, pspec,

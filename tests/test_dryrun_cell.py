@@ -37,8 +37,7 @@ def test_build_cell_small_mesh():
         with activation_mesh(mesh, dp=dp_axes(mesh, layout)):
             lowered = build_cell(cfg, "tiny_decode", mesh, layout)
             compiled = lowered.compile()
-        from repro.compat import cost_analysis
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         assert ca["flops"] > 0
         ma = compiled.memory_analysis()
         assert ma.argument_size_in_bytes > 0

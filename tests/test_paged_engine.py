@@ -172,3 +172,44 @@ def test_paged_lifecycle_churn_stress(setup):
     assert eng.stats.schedule_cache["hit_rate"] > 0.5
     # the pool really was the constraint at some point
     assert eng.stats.kv_pool["high_water"] >= int(0.75 * eng.pool.usable_pages)
+
+
+@pytest.mark.parametrize(
+    "budget, kv_dtype",
+    [(None, "bf16"), (0, "bf16"), (None, "int8")],
+    ids=["default", "no_vmem", "int8"],
+)
+def test_fused_decode_ticks_follow_the_vmem_gate(
+    setup, monkeypatch, budget, kv_dtype
+):
+    """``fused_decode_ticks`` counts the lean decode ticks that ran the
+    fused kernel: all of them under the default VMEM budget, none when the
+    budget admits nothing. The kernel the decode steps traced agrees with
+    the count, the engine gates with the pool's element width, and the
+    tokens are the same either way."""
+    from repro.kernels import ops
+
+    cfg, params = setup
+    traced = []
+    fused_kernel = ops.lean_decode_paged_fused
+
+    def spy(*a, **kw):
+        traced.append(True)
+        return fused_kernel(*a, **kw)
+
+    monkeypatch.setattr(ops, "lean_decode_paged_fused", spy)
+    if budget is not None:
+        monkeypatch.setattr(ops, "FUSED_VMEM_BUDGET", budget)
+    kw = dict(max_batch=2, cache_len=32, num_workers=4, attn_backend="lean",
+              paged=True, page_size=8, kv_dtype=kv_dtype)
+    reqs = _requests(cfg, new=4)
+    eng = _run(cfg, params, reqs, **kw)
+    assert eng.kv_elem_bytes == eng.cache[0][0]["k"].dtype.itemsize
+    assert eng.stats.ticks > 0
+    want = eng.stats.ticks if budget is None else 0
+    assert eng.stats.fused_decode_ticks == want
+    assert bool(traced) == (want > 0)
+    monkeypatch.undo()
+    ref = _requests(cfg, new=4)
+    _run(cfg, params, ref, **kw)
+    assert [r.generated for r in reqs] == [r.generated for r in ref]

@@ -1,7 +1,6 @@
 """Property-based invariants of the paged KV subsystem.
 
-Three families of properties (via hypothesis, or the deterministic stub in
-``tests/_hypothesis_stub.py`` when the real package is absent):
+Three families of properties, via hypothesis:
 
   * schedule coverage — for random ragged length sets, the stream-K
     schedule visits every (segment, tile) pair exactly once, and the paged

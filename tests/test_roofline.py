@@ -46,9 +46,7 @@ def test_xla_counts_scan_body_once():
     def scanned(x, ws):
         return jax.lax.scan(lambda c, w: (c @ w, None), x, ws)[0]
 
-    from repro.compat import cost_analysis
-
-    flops = cost_analysis(jax.jit(scanned).lower(x, ws).compile())["flops"]
+    flops = jax.jit(scanned).lower(x, ws).compile().cost_analysis()["flops"]
     one = 2 * 64**3
     assert flops < 2 * one, "XLA started multiplying loop bodies: simplify!"
 

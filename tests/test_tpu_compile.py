@@ -1,0 +1,210 @@
+"""The main-path stream-K kernels compile for a TPU v5e at Mistral-NeMo-12B
+attention widths (32 q / 8 kv heads, head_dim 128, bf16 or int8 KV, 8
+sequences of 4k context in 128-token pages), and the fused decode kernel
+compiles at the largest batch its VMEM gate admits.
+
+Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
+*described* v5e chip, which refuses what Mosaic cannot lower (block shapes
+off the (8, 128) tiling, unsupported in-kernel gathers and slices) that
+interpret mode on the CPU accepts. The topology is described inside the
+fixture, never at import, so every test worker collects the same tests and
+only the one running this file loads the TPU compiler.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.leantile import (
+    cascade_fused_descriptors,
+    make_cascade_schedule,
+    make_chunk_schedule,
+    make_schedule,
+)
+from repro.kernels.lean_decode import (
+    lean_cascade_fused,
+    lean_decode_fused,
+    lean_decode_paged_fused,
+    lean_decode_paged_partials,
+    lean_decode_partials,
+    lean_merge_pallas,
+)
+from repro.kernels.lean_prefill import lean_prefill_chunk_partials
+from repro.kernels.ops import decode_uses_fused
+
+B, HQ, HKV, D, CTX, PAGE, WORKERS = 8, 32, 8, 128, 4096, 128, 16
+GQ = HQ // HKV
+SEG = B * HKV
+ROWS = (B * CTX // PAGE + 1) * HKV          # pool rows: (page, kv head)
+SCALE = 1.0 / np.sqrt(D)
+CHUNK, PACK = 256, 4                        # chunk-prefill pack geometry
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # no TPU compiler in this installation
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+SCHED = make_schedule([CTX] * B, HKV, PAGE, WORKERS)
+Q = ((SEG, GQ, D), jnp.bfloat16)
+KV_DENSE = ((SEG, CTX, D), jnp.bfloat16)
+KV_ROWS = ((ROWS, PAGE, D), jnp.bfloat16)
+CTX_ARG = ((SEG,), jnp.int32)
+ROWS8 = ((ROWS, PAGE, D), jnp.int8)
+SCALES = ((ROWS, 1), jnp.float32)
+
+
+def test_dense_two_phase_decode(one_chip):
+    _compile(
+        one_chip,
+        lambda q, k, v, c: lean_decode_partials(q, k, v, c, SCHED, SCALE),
+        Q, KV_DENSE, KV_DENSE, CTX_ARG,
+    )
+
+
+def test_dense_fused_decode(one_chip):
+    _compile(
+        one_chip,
+        lambda q, k, v, c: lean_decode_fused(q, k, v, c, SCHED, SCALE),
+        Q, KV_DENSE, KV_DENSE, CTX_ARG,
+    )
+
+
+def test_paged_fused_decode(one_chip):
+    _compile(
+        one_chip,
+        lambda q, k, v, c, r: lean_decode_paged_fused(
+            q, k, v, c, r, SCHED, SCALE
+        ),
+        Q, KV_ROWS, KV_ROWS, CTX_ARG,
+        ((SCHED.grid_iters + SCHED.num_pieces,), jnp.int32),
+    )
+
+
+def test_int8_paged_two_phase_decode(one_chip):
+    _compile(
+        one_chip,
+        lambda q, k, v, c, r, ks, vs: lean_decode_paged_partials(
+            q, k, v, c, r, SCHED, SCALE, k_scales=ks, v_scales=vs
+        ),
+        Q, ROWS8, ROWS8, CTX_ARG, ((SCHED.grid_iters,), jnp.int32),
+        SCALES, SCALES,
+    )
+
+
+def test_int8_paged_fused_decode(one_chip):
+    _compile(
+        one_chip,
+        lambda q, k, v, c, r, ks, vs: lean_decode_paged_fused(
+            q, k, v, c, r, SCHED, SCALE, k_scales=ks, v_scales=vs
+        ),
+        Q, ROWS8, ROWS8, CTX_ARG,
+        ((SCHED.grid_iters + SCHED.num_pieces,), jnp.int32), SCALES, SCALES,
+    )
+
+
+# Short contexts keep the scalar-prefetched descriptors inside SMEM, so
+# the batch reaches the VMEM gate: at 512 tokens it admits 250 sequences.
+GATE_CTX = 512
+
+
+def _gate_schedule(batch):
+    return make_schedule([GATE_CTX] * batch, HKV, PAGE, WORKERS)
+
+
+def _largest_fused_batch():
+    b = 1
+    while decode_uses_fused(_gate_schedule(b + 1), GQ, D, kv_elem_bytes=2):
+        b += 1
+    return b
+
+
+@pytest.mark.parametrize("above", [False, True], ids=["at_gate", "above_gate"])
+def test_paged_decode_at_the_vmem_gate(one_chip, above):
+    """At the largest batch the gate admits, the fused kernel compiles; one
+    sequence more, the gate picks two-phase, which compiles there."""
+    batch = _largest_fused_batch() + above
+    sched = _gate_schedule(batch)
+    assert decode_uses_fused(sched, GQ, D, kv_elem_bytes=2) is not above
+    seg = batch * HKV
+    rows = ((batch * GATE_CTX // PAGE + 1) * HKV, PAGE, D)
+    kernel = lean_decode_paged_partials if above else lean_decode_paged_fused
+    routes = sched.grid_iters + (0 if above else sched.num_pieces)
+    _compile(
+        one_chip,
+        lambda q, k, v, c, r: kernel(q, k, v, c, r, sched, SCALE),
+        ((seg, GQ, D), jnp.bfloat16), (rows, jnp.bfloat16),
+        (rows, jnp.bfloat16), ((seg,), jnp.int32), ((routes,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_chunk_prefill(one_chip, kv):
+    sched = make_chunk_schedule([4 * CHUNK] * PACK, HKV, PAGE, WORKERS)
+    seg = ((PACK * HKV,), jnp.int32)
+    pool, scales = (KV_ROWS, ()) if kv == "bf16" else (ROWS8, (SCALES,) * 2)
+    _compile(
+        one_chip,
+        lambda q, k, v, c, qs, r, *sc: lean_prefill_chunk_partials(
+            q, k, v, c, qs, r, sched, SCALE, chunk_cap=CHUNK,
+            k_scales=sc[0] if sc else None, v_scales=sc[1] if sc else None,
+        ),
+        ((PACK * HKV, GQ * CHUNK, D), jnp.bfloat16), pool, pool,
+        seg, seg, ((sched.grid_iters,), jnp.int32), *scales,
+    )
+
+
+def test_merge_pallas(one_chip):
+    P = SCHED.num_pieces
+    _compile(
+        one_chip,
+        lambda o, m, l: lean_merge_pallas(o, m, l, SCHED),
+        ((P, GQ, D), jnp.float32), ((P, GQ), jnp.float32),
+        ((P, GQ), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_cascade_fused_decode(one_chip, kv):
+    # all 8 sequences share a 16-page system prompt
+    cs, binding = make_cascade_schedule(
+        [CTX] * B, [list(range(B))], [16], HKV, PAGE, WORKERS, max_len=CTX
+    )
+    desc = cascade_fused_descriptors(cs, binding)
+    seg_tot = (cs.num_groups + B) * HKV
+    pool, scales = (KV_ROWS, ()) if kv == "bf16" else (ROWS8, (SCALES,) * 2)
+    _compile(
+        one_chip,
+        lambda q, k, v, c, r, de, *sc: lean_cascade_fused(
+            q, k, v, c, r, de, cs, SCALE, GQ,
+            k_scales=sc[0] if sc else None, v_scales=sc[1] if sc else None,
+        ),
+        ((seg_tot, cs.group_size * GQ, D), jnp.bfloat16), pool, pool,
+        ((seg_tot,), jnp.int32), ((cs.fused_grid_iters,), jnp.int32),
+        (desc.shape, jnp.int32), *scales,
+    )
