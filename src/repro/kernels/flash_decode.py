@@ -141,5 +141,6 @@ def flash_decode_partials(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="flash_decode_partials",
         interpret=interpret,
     )(seg_lens.astype(jnp.int32), q_seg, k_seg, v_seg)

@@ -152,6 +152,7 @@ def flash_prefill(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="flash_prefill",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, Hq, Lqp, d)[:, :, :Lq, :]
@@ -294,6 +295,7 @@ def flash_prefill_paged(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="flash_prefill_paged",
         interpret=interpret,
     )(
         page_tbls.reshape(-1).astype(jnp.int32),

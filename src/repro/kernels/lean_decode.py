@@ -294,6 +294,7 @@ def lean_decode_partials(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="lean_decode_partials",
         interpret=interpret,
     )(*operands, q_seg, k_seg, v_seg, *scale_inputs)
     return o_p[:P], m_p[:P, :, 0], l_p[:P, :, 0]
@@ -506,6 +507,7 @@ def lean_decode_fused(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        name="lean_decode_fused",
         interpret=interpret,
     )(*operands, q_seg, k_seg, v_seg, *scale_inputs)
     return o, lse[..., 0]
@@ -781,6 +783,7 @@ def lean_cascade_fused(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        name="lean_cascade_fused",
         interpret=interpret,
     )(
         desc.astype(jnp.int32), ctx_all.astype(jnp.int32),
@@ -883,6 +886,7 @@ def lean_merge_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="lean_merge",
         interpret=interpret,
     )(meta, o_p, m_p[..., None], l_p[..., None])
     return o, lse[..., 0]

@@ -219,6 +219,7 @@ def lean_prefill_chunk_partials(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="lean_prefill_chunk",
         interpret=interpret,
     )(
         desc,

@@ -258,23 +258,29 @@ def attn_decode_paged(
         pos = ctx_lens[:, None]                      # (B, 1) per slot
         q = rope(q, pos, rope_theta)
         k = rope(k, pos, rope_theta)
-    # scatter the token into its slot's current page
+    # scatter the token into its slot's current page; the pool writes, and
+    # the relayout copies XLA puts around them, are scoped as KV pool I/O
     write_pos = jnp.minimum(ctx_lens, capacity - 1)
-    if quant:
-        ones = jnp.ones((B,), jnp.int32)
-        k_pool, k_scale = paged_scatter_tokens_quant(
-            k_pool, k_scale, page_tbl, write_pos, ones, k,
-            per_head=scale_per_head,
-        )
-        v_pool, v_scale = paged_scatter_tokens_quant(
-            v_pool, v_scale, page_tbl, write_pos, ones, v,
-            per_head=scale_per_head,
-        )
-    else:
-        pages_w = page_tbl[jnp.arange(B), write_pos // ps]
-        offs = write_pos % ps
-        k_pool = k_pool.at[pages_w, :, offs].set(k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[pages_w, :, offs].set(v[:, 0].astype(v_pool.dtype))
+    with jax.named_scope("kv_pool_io"):
+        if quant:
+            ones = jnp.ones((B,), jnp.int32)
+            k_pool, k_scale = paged_scatter_tokens_quant(
+                k_pool, k_scale, page_tbl, write_pos, ones, k,
+                per_head=scale_per_head,
+            )
+            v_pool, v_scale = paged_scatter_tokens_quant(
+                v_pool, v_scale, page_tbl, write_pos, ones, v,
+                per_head=scale_per_head,
+            )
+        else:
+            pages_w = page_tbl[jnp.arange(B), write_pos // ps]
+            offs = write_pos % ps
+            k_pool = k_pool.at[pages_w, :, offs].set(
+                k[:, 0].astype(k_pool.dtype)
+            )
+            v_pool = v_pool.at[pages_w, :, offs].set(
+                v[:, 0].astype(v_pool.dtype)
+            )
     ctx = jnp.minimum(ctx_lens + 1, capacity).astype(jnp.int32)
     qd = q.reshape(B, n_heads, head_dim)
     k_eff, v_eff = k_pool, v_pool
@@ -366,16 +372,19 @@ def attn_prefill_chunk_paged(
         k = rope(k, pos, rope_theta)
     # append the chunk's KV to the pool FIRST — queries attend their own
     # chunk (causally), so the read below must see these writes
-    if quant:
-        k_pool, k_scale = paged_scatter_tokens_quant(
-            k_pool, k_scale, page_tbls, offs, lens, k, per_head=scale_per_head
-        )
-        v_pool, v_scale = paged_scatter_tokens_quant(
-            v_pool, v_scale, page_tbls, offs, lens, v, per_head=scale_per_head
-        )
-    else:
-        k_pool = paged_scatter_tokens(k_pool, page_tbls, offs, lens, k)
-        v_pool = paged_scatter_tokens(v_pool, page_tbls, offs, lens, v)
+    with jax.named_scope("kv_pool_io"):
+        if quant:
+            k_pool, k_scale = paged_scatter_tokens_quant(
+                k_pool, k_scale, page_tbls, offs, lens, k,
+                per_head=scale_per_head,
+            )
+            v_pool, v_scale = paged_scatter_tokens_quant(
+                v_pool, v_scale, page_tbls, offs, lens, v,
+                per_head=scale_per_head,
+            )
+        else:
+            k_pool = paged_scatter_tokens(k_pool, page_tbls, offs, lens, k)
+            v_pool = paged_scatter_tokens(v_pool, page_tbls, offs, lens, v)
     qh = jnp.swapaxes(q, 1, 2)                             # (N, Hq, C, hd)
     k_eff, v_eff = k_pool, v_pool
     if not quant and k_pool.dtype not in (

@@ -339,6 +339,7 @@ def _attn_full(p, x, cfg: ModelConfig, kind, img_emb, q_offset=0):
     return x, kv, xkv
 
 
+@jax.named_scope("ffn")
 def _ffn_part(p, x, cfg: ModelConfig):
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
@@ -384,6 +385,7 @@ def _embed(params, cfg: ModelConfig, tokens, offset=0):
     return x
 
 
+@jax.named_scope("unembed")
 def _unembed(params, cfg: ModelConfig, x):
     w = (
         params["embed"].T if cfg.tie_embeddings else params["unembed"]
@@ -629,6 +631,50 @@ def supports_chunked_prefill(cfg: ModelConfig) -> bool:
     )
 
 
+def _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg: ModelConfig):
+    """Run ``reps`` units of one stage against its stacked cache; returns
+    ``(x, stage_cache)``. Each unit reads its layer of the cache and the
+    stack takes back what the unit wrote. Under ``scan_layers`` the cache
+    rides in the scan CARRY, updated in place via dynamic-update-slice —
+    as xs/ys XLA double-buffers the multi-GB stacked KV cache through the
+    loop. Those reads and write-backs are scoped ``kv_pool_io``, so a
+    profile can tell them from the layers' own work."""
+    if reps == 1 or not cfg.scan_layers:
+        ncs = []
+        for r in range(reps):
+            up = jax.tree.map(lambda a: a[r], stage_p)
+            with jax.named_scope("kv_pool_io"):
+                uc = jax.tree.map(lambda a: a[r], stage_c)
+            x, nc = unit_fn(x, (up, uc))
+            ncs.append(nc)
+        with jax.named_scope("kv_pool_io"):
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+
+    def body(carry, up_i):
+        x, cache_c = carry
+        up, r = up_i
+        with jax.named_scope("kv_pool_io"):
+            uc = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
+                cache_c,
+            )
+        x, nc = unit_fn(x, (up, uc))
+        with jax.named_scope("kv_pool_io"):
+            cache_c = jax.tree.map(
+                lambda buf, new: jax.lax.dynamic_update_index_in_dim(
+                    buf, new.astype(buf.dtype), r, 0
+                ),
+                cache_c,
+                nc,
+            )
+        return (x, cache_c), None
+
+    (x, stage_nc), _ = jax.lax.scan(
+        body, (x, stage_c), (stage_p, jnp.arange(reps))
+    )
+    return x, stage_nc
+
+
 def _chunk_forward(
     params,
     cfg: ModelConfig,
@@ -662,16 +708,19 @@ def _chunk_forward(
             new_cs = []
             for kind, lp, lc in zip(pattern, up, uc):
                 quant = "k_scale" in lc
-                out = attn_prefill_chunk_paged(
-                    lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
-                    lc["k"], lc["v"], page_tbls, offs, lens,
-                    n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                    head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-                    attn_fn=attn_fn,
-                    k_scale=lc["k_scale"] if quant else None,
-                    v_scale=lc["v_scale"] if quant else None,
-                    scale_per_head=cfg.kv_scale_granularity == "page_head",
-                )
+                with jax.named_scope("attn"):
+                    out = attn_prefill_chunk_paged(
+                        lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
+                        lc["k"], lc["v"], page_tbls, offs, lens,
+                        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+                        attn_fn=attn_fn,
+                        k_scale=lc["k_scale"] if quant else None,
+                        v_scale=lc["v_scale"] if quant else None,
+                        scale_per_head=(
+                            cfg.kv_scale_granularity == "page_head"
+                        ),
+                    )
                 if quant:
                     h, kc, vc, ks, vs = out
                     nc = {"k": kc, "v": vc, "k_scale": ks, "v_scale": vs}
@@ -683,39 +732,7 @@ def _chunk_forward(
                 new_cs.append(nc)
             return x, tuple(new_cs)
 
-        if reps == 1 or not cfg.scan_layers:
-            ncs = []
-            for r in range(reps):
-                up = jax.tree.map(lambda a: a[r], stage_p)
-                uc = jax.tree.map(lambda a: a[r], stage_c)
-                x, nc = unit_fn(x, (up, uc))
-                ncs.append(nc)
-            stage_nc = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
-        else:
-            # same carry pattern as decode_step: the stacked pools ride in
-            # the scan carry, updated in place layer by layer
-            def body(carry, up_i):
-                x, cache_c = carry
-                up, r = up_i
-                uc = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, r, 0, keepdims=False
-                    ),
-                    cache_c,
-                )
-                x, nc = unit_fn(x, (up, uc))
-                cache_c = jax.tree.map(
-                    lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                        buf, new.astype(buf.dtype), r, 0
-                    ),
-                    cache_c,
-                    nc,
-                )
-                return (x, cache_c), None
-
-            (x, stage_nc), _ = jax.lax.scan(
-                body, (x, stage_c), (stage_p, jnp.arange(reps))
-            )
+        x, stage_nc = _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg)
         new_cache.append(stage_nc)
     return x, new_cache
 
@@ -822,58 +839,59 @@ def decode_step(
             new_cs = []
             for kind, lp, lc in zip(pattern, up, uc):
                 if kind in ATTN_KINDS:
-                    window = cfg.window if kind == "win" else None
-                    if page_tbl is not None and kind == "attn":
-                        quant = "k_scale" in lc
-                        out = attn_decode_paged(
-                            lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
-                            lc["k"], lc["v"], page_tbl, cur_len,
-                            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                            head_dim=cfg.head_dim,
-                            rope_theta=cfg.rope_theta,
-                            attn_fn=attn_fn, ctx_lens=ctx_lens,
-                            k_scale=lc["k_scale"] if quant else None,
-                            v_scale=lc["v_scale"] if quant else None,
-                            scale_per_head=(
-                                cfg.kv_scale_granularity == "page_head"
-                            ),
-                        )
-                        if quant:
-                            h, kc, vc, ks, vs = out
+                    with jax.named_scope("attn"):
+                        window = cfg.window if kind == "win" else None
+                        if page_tbl is not None and kind == "attn":
+                            quant = "k_scale" in lc
+                            out = attn_decode_paged(
+                                lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
+                                lc["k"], lc["v"], page_tbl, cur_len,
+                                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                                head_dim=cfg.head_dim,
+                                rope_theta=cfg.rope_theta,
+                                attn_fn=attn_fn, ctx_lens=ctx_lens,
+                                k_scale=lc["k_scale"] if quant else None,
+                                v_scale=lc["v_scale"] if quant else None,
+                                scale_per_head=(
+                                    cfg.kv_scale_granularity == "page_head"
+                                ),
+                            )
+                            if quant:
+                                h, kc, vc, ks, vs = out
+                            else:
+                                h, kc, vc = out
+                                ks = vs = None
                         else:
-                            h, kc, vc = out
+                            h, kc, vc = attn_decode(
+                                lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
+                                lc["k"], lc["v"], cur_len,
+                                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                                head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+                                window=window,
+                                attn_fn=win_attn_fn if kind == "win" else attn_fn,
+                                ctx_lens=ctx_lens,
+                            )
                             ks = vs = None
-                    else:
-                        h, kc, vc = attn_decode(
-                            lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
-                            lc["k"], lc["v"], cur_len,
-                            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                            head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-                            window=window,
-                            attn_fn=win_attn_fn if kind == "win" else attn_fn,
-                            ctx_lens=ctx_lens,
-                        )
-                        ks = vs = None
-                    x = x + h
-                    nc = {"k": kc, "v": vc}
-                    if ks is not None:
-                        nc["k_scale"] = ks
-                        nc["v_scale"] = vs
-                    if kind == "xattn":
-                        from repro.core.attention import mha_decode_ref
+                        x = x + h
+                        nc = {"k": kc, "v": vc}
+                        if ks is not None:
+                            nc["k_scale"] = ks
+                            nc["v_scale"] = vs
+                        if kind == "xattn":
+                            from repro.core.attention import mha_decode_ref
 
-                        xn = rms_norm(x, lp["ln_x"], cfg.norm_eps)
-                        xc_ = xn.astype(jnp.bfloat16)
-                        ap = lp["xattn"]
-                        qx = (xc_ @ ap["wq"].astype(xc_.dtype)).reshape(
-                            x.shape[0], cfg.n_heads, cfg.head_dim
-                        )
-                        ox = mha_decode_ref(qx, lc["xk"], lc["xv"])
-                        ox = ox.reshape(x.shape[0], 1, -1).astype(xc_.dtype)
-                        hx = (ox @ ap["wo"].astype(xc_.dtype)).astype(x.dtype)
-                        x = x + jnp.tanh(lp["xgate"]) * hx
-                        nc["xk"] = lc["xk"]
-                        nc["xv"] = lc["xv"]
+                            xn = rms_norm(x, lp["ln_x"], cfg.norm_eps)
+                            xc_ = xn.astype(jnp.bfloat16)
+                            ap = lp["xattn"]
+                            qx = (xc_ @ ap["wq"].astype(xc_.dtype)).reshape(
+                                x.shape[0], cfg.n_heads, cfg.head_dim
+                            )
+                            ox = mha_decode_ref(qx, lc["xk"], lc["xv"])
+                            ox = ox.reshape(x.shape[0], 1, -1).astype(xc_.dtype)
+                            hx = (ox @ ap["wo"].astype(xc_.dtype)).astype(x.dtype)
+                            x = x + jnp.tanh(lp["xgate"]) * hx
+                            nc["xk"] = lc["xk"]
+                            nc["xv"] = lc["xv"]
                     x, _ = _ffn_part(lp, x, cfg)
                 elif kind == "rglru":
                     h, hn, conv = rec.rglru_step(
@@ -900,40 +918,7 @@ def decode_step(
                 new_cs.append(nc)
             return x, tuple(new_cs)
 
-        if reps == 1 or not cfg.scan_layers:
-            ncs = []
-            for r in range(reps):
-                up = jax.tree.map(lambda a: a[r], stage_p)
-                uc = jax.tree.map(lambda a: a[r], stage_c)
-                x, nc = unit_fn(x, (up, uc))
-                ncs.append(nc)
-            stage_nc = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
-        else:
-            # the cache rides in the scan CARRY, updated in place via
-            # dynamic-update-slice — as xs/ys XLA double-buffers the
-            # multi-GB stacked KV cache through the loop.
-            def body(carry, up_i):
-                x, cache_c = carry
-                up, r = up_i
-                uc = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, r, 0, keepdims=False
-                    ),
-                    cache_c,
-                )
-                x, nc = unit_fn(x, (up, uc))
-                cache_c = jax.tree.map(
-                    lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                        buf, new.astype(buf.dtype), r, 0
-                    ),
-                    cache_c,
-                    nc,
-                )
-                return (x, cache_c), None
-
-            (x, stage_nc), _ = jax.lax.scan(
-                body, (x, stage_c), (stage_p, jnp.arange(reps))
-            )
+        x, stage_nc = _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg)
         new_cache.append(stage_nc)
     x = rms_norm(x[:, -1], params["final_norm"], cfg.norm_eps)
     return _unembed(params, cfg, x), new_cache

@@ -15,13 +15,23 @@ Two complementary views of one serving run:
     streaming token-gap accumulator, from which :meth:`request_summary`
     derives TTFT, TPOT, and queue wait without storing per-token events.
 
+Every span, enabled or not, also marks the profiler's trace with a
+``jax.profiler.TraceAnnotation``, so a device trace shows what the host
+was doing beside each device op: ``tick`` appears as ``engine.tick``. A
+name that carries its layer (``sched.step``, ``engine.dispatch``) marks
+the profiler's trace only and is never recorded here; those marks cut
+the decode tick at its layer boundaries without changing the in-memory
+record that :mod:`repro.obs.report` and the watchdog read. An
+annotation costs well under a microsecond unless a profile is being
+collected.
+
 Overhead discipline: a disabled tracer (``enabled=False``, or the module
-singleton :data:`NULL_TRACER`) does no timing, no allocation, and no
-dict building — every public method early-outs and :meth:`span` returns
-a shared no-op context manager whose truthiness is ``False``, so callers
-can gate optional work (e.g. an extra ``block_until_ready`` for sync
-attribution) with ``if sp:``. The observability bench gates traced
-throughput at >= 0.97x untraced.
+singleton :data:`NULL_TRACER`) does no timing and no dict building —
+every public method early-outs and :meth:`span` returns a profiler-only
+span whose truthiness is ``False``, so callers can gate optional work
+(e.g. an extra ``block_until_ready`` for sync attribution) with
+``if sp:``. The observability bench gates traced throughput at >= 0.97x
+untraced.
 """
 from __future__ import annotations
 
@@ -30,9 +40,13 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "NULL_TRACER", "load_trace"]
 
 TRACE_FORMAT_VERSION = 1
+# profiler names of the spans this module records: ``tick`` -> ``engine.tick``
+ENGINE_LAYER = "engine."
 
 
 class _NullSpan:
@@ -59,11 +73,29 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _MarkSpan(_NullSpan):
+    """A span that only marks the profiler's trace: falsy and inert like
+    the null span, but its region shows in a profile under ``name``."""
+
+    __slots__ = ("_mark",)
+
+    def __init__(self, name: str):
+        self._mark = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._mark.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._mark.__exit__(*exc)
+        return False
+
+
 class _Span:
     """A live span. Created only by an enabled :class:`Tracer`."""
 
     __slots__ = ("tracer", "name", "meta", "depth", "tick",
-                 "_t0", "sync_s")
+                 "_t0", "sync_s", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, meta: dict):
         self.tracer = tracer
@@ -73,6 +105,7 @@ class _Span:
         self.tick = tracer.tick_index
         self._t0 = 0.0
         self.sync_s = 0.0
+        self._mark = TraceAnnotation(ENGINE_LAYER + name)
 
     def __bool__(self):
         return True
@@ -84,11 +117,13 @@ class _Span:
             self.tick = tr.tick_index
         self.depth = len(tr._stack)
         tr._stack.append(self)
+        self._mark.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         ms = (time.perf_counter() - self._t0) * 1e3
+        self._mark.__exit__(*exc)
         tr = self.tracer
         if tr._stack and tr._stack[-1] is self:
             tr._stack.pop()
@@ -150,9 +185,16 @@ class Tracer:
 
     # --------------------------------------------------------------- spans
     def span(self, name: str, **meta):
-        """Open a nestable span: ``with tracer.span("tick"): ...``."""
+        """Open a nestable span: ``with tracer.span("tick"): ...``.
+
+        A bare name is recorded while the tracer is enabled and marks the
+        profiler's trace as ``engine.<name>`` either way. A name that
+        carries its layer (``"sched.step"``) only marks the profiler's
+        trace, under that name."""
+        if "." in name:
+            return _MarkSpan(name)
         if not self.enabled:
-            return _NULL_SPAN
+            return _MarkSpan(ENGINE_LAYER + name)
         return _Span(self, name, meta)
 
     def annotate(self, **meta) -> None:

@@ -123,6 +123,13 @@ def _quiet_donation():
         yield
 
 
+def _bind(fn, **kw):
+    """``functools.partial`` that keeps ``fn``'s name, so the program jitted
+    from it is named after it in a profile (``jit__kernel_decode_step_paged``,
+    not ``jit__unknown``)."""
+    return functools.update_wrapper(functools.partial(fn, **kw), fn)
+
+
 @dataclass
 class Request:
     uid: int
@@ -918,39 +925,37 @@ class DecodeEngine:
         )
         self._jit_prefill_bucketed = jax.jit(self._prefill_fn_bucketed)
         self._jit_prefill_chunks = jax.jit(
-            functools.partial(_chunk_prefill_step, cfg=cfg),
+            _bind(_chunk_prefill_step, cfg=cfg),
             static_argnames=("backend", "sched", "interpret"),
             donate_argnames=("cache",),
         )
         self._jit_admit = jax.jit(_write_slot, donate_argnums=(0,))
         self._jit_admit_paged = jax.jit(
-            functools.partial(
-                _write_slot_paged, cfg=cfg, page_size=self.tile
-            ),
+            _bind(_write_slot_paged, cfg=cfg, page_size=self.tile),
             donate_argnums=(0,),
         )
         self._jit_kernel_step = jax.jit(
-            functools.partial(_kernel_decode_step, cfg=cfg),
+            _bind(_kernel_decode_step, cfg=cfg),
             static_argnames=("backend", "sched", "num_splits", "fused",
                              "interpret"),
             donate_argnames=("cache",),
         )
         self._jit_kernel_step_paged = jax.jit(
-            functools.partial(_kernel_decode_step_paged, cfg=cfg),
+            _bind(_kernel_decode_step_paged, cfg=cfg),
             static_argnames=("backend", "sched", "num_splits", "fused",
                              "interpret"),
             donate_argnames=("cache",),
         )
         self._jit_kernel_step_cascade = jax.jit(
-            functools.partial(_kernel_decode_step_cascade, cfg=cfg),
+            _bind(_kernel_decode_step_cascade, cfg=cfg),
             static_argnames=("csched", "fused", "interpret"),
             donate_argnames=("cache",),
         )
         self._jit_copy_page = jax.jit(
-            functools.partial(_copy_page, cfg=cfg), donate_argnums=(0,)
+            _bind(_copy_page, cfg=cfg), donate_argnums=(0,)
         )
         self._jit_fill_page = jax.jit(
-            functools.partial(_fill_page, cfg=cfg), donate_argnums=(0,)
+            _bind(_fill_page, cfg=cfg), donate_argnums=(0,)
         )
         self._jit_screen = jax.jit(_screen_logits)
 
@@ -982,7 +987,7 @@ class DecodeEngine:
                 help="accepted / proposed draft tokens (cumulative)",
             )
         self._jit_spec_verify = jax.jit(
-            functools.partial(_spec_verify_step, cfg=cfg),
+            _bind(_spec_verify_step, cfg=cfg),
             static_argnames=("backend", "sched", "interpret"),
             donate_argnames=("cache",),
         )
@@ -1351,19 +1356,20 @@ class DecodeEngine:
         with sp:
             sched = None
             if self.attn_backend == "lean":
-                sched = make_chunk_schedule(
-                    visible, self.cfg.n_kv_heads, self.tile,
-                    self.num_workers,
-                    max_len=self.pages_per_slot * self.tile,
-                    cache=self.sched_cache,
-                )
+                with self.tracer.span("engine.schedule_build"):
+                    sched = make_chunk_schedule(
+                        visible, self.cfg.n_kv_heads, self.tile,
+                        self.num_workers,
+                        max_len=self.pages_per_slot * self.tile,
+                        cache=self.sched_cache,
+                    )
                 if sp:
                     sp.annotate(**sched.work_summary())
-            with _quiet_donation():
+            with self.tracer.span("engine.inputs"):
+                args = [jnp.asarray(a) for a in (toks, offs, lens, tbls)]
+            with self.tracer.span("engine.dispatch"), _quiet_donation():
                 next_tok, self.cache = self._jit_prefill_chunks(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(offs), jnp.asarray(lens),
-                    jnp.asarray(tbls),
+                    self.params, self.cache, *args,
                     backend=self.attn_backend, sched=sched,
                     interpret=self.interpret,
                 )
@@ -1371,10 +1377,12 @@ class DecodeEngine:
                 t0 = time.perf_counter()
                 jax.block_until_ready(next_tok)
                 sp.add_sync(time.perf_counter() - t0)
+            with self.tracer.span("engine.token_wait"):
+                next_tok = np.asarray(next_tok)
         self.stats.chunk_prefills += len(work)
         self.stats.prefill_tokens += n_tokens
         self._log_tick_tokens(self.stats.tick_prefill_tokens, n_tokens)
-        return np.asarray(next_tok)
+        return next_tok
 
     # ------------------------------------------------------------ paged mgmt
     def _ensure_decode_pages(self, active: List[int]) -> List[int]:
@@ -1643,7 +1651,8 @@ class DecodeEngine:
             if self.slot_req[s] and s not in exclude
         ]
         if self.paged:
-            active = self._ensure_decode_pages(active)
+            with self.tracer.span("engine.pages"):
+                active = self._ensure_decode_pages(active)
         if self.guard_cfg is not None:
             self._run_audits()
         if not active:
@@ -1678,13 +1687,15 @@ class DecodeEngine:
         results = []
         for lvl, slots in passes:
             logits = self._decode_pass(lvl, slots, active, exclude)
-            if guard_on:
-                nxt, fin = self._jit_screen(logits)
-                results.append((slots, np.asarray(nxt), np.array(fin)))
-            else:
-                results.append(
-                    (slots, np.asarray(jnp.argmax(logits, axis=-1)), None)
-                )
+            # the tick's one wait for the device
+            with self.tracer.span("engine.token_wait"):
+                if guard_on:
+                    nxt, fin = self._jit_screen(logits)
+                    results.append((slots, np.asarray(nxt), np.array(fin)))
+                else:
+                    results.append(
+                        (slots, np.asarray(jnp.argmax(logits, axis=-1)), None)
+                    )
 
         spec = (
             self._spec_verify(spec_slots, exclude, guard_on)
@@ -1706,7 +1717,8 @@ class DecodeEngine:
                 if spec is not None and v in spec[0]:
                     spec[2][v] = False
 
-        return self._emit_tokens(results, guard_on, spec=spec)
+        with self.tracer.span("engine.emit"):
+            return self._emit_tokens(results, guard_on, spec=spec)
 
     def _decode_pass_main(self, active: List[int], ctx_np, ptbl_np):
         """The engine's configured (level-0) decode path: cascade grouping
@@ -1730,17 +1742,15 @@ class DecodeEngine:
             if csched.signature not in self._casc_signatures:
                 self._casc_signatures.add(csched.signature)
                 self.stats.cascade_retraces += 1
-            with _quiet_donation():
+            with self.tracer.span("engine.inputs"):
+                args = [jnp.asarray(a) for a in (
+                    self.next_tokens, ctx_np.astype(np.int32), ptbl_np,
+                    prefix_tbl, suffix_tbl, binding.members,
+                    binding.prefix_lens, binding.seq_prefix_len, fused_desc,
+                )]
+            with self.tracer.span("engine.dispatch"), _quiet_donation():
                 logits, self.cache = self._jit_kernel_step_cascade(
-                    self.params, self.cache,
-                    jnp.asarray(self.next_tokens),
-                    jnp.asarray(ctx_np, jnp.int32),
-                    jnp.asarray(ptbl_np),
-                    jnp.asarray(prefix_tbl), jnp.asarray(suffix_tbl),
-                    jnp.asarray(binding.members),
-                    jnp.asarray(binding.prefix_lens),
-                    jnp.asarray(binding.seq_prefix_len),
-                    jnp.asarray(fused_desc),
+                    self.params, self.cache, *args,
                     csched=csched, fused=fused, interpret=self.interpret,
                 )
             grouped = np.unique(binding.members[binding.members >= 0])
@@ -1764,18 +1774,21 @@ class DecodeEngine:
             # the kernel step — nothing is derived twice per tick
             sched = self._tick_schedule(ctx_np)
             self._note_schedule(sched, "fast")
-            tokens = jnp.asarray(self.next_tokens)
-            ctx = jnp.asarray(ctx_np, jnp.int32)
-            ptbl = jnp.asarray(ptbl_np) if self.paged else None
+            with self.tracer.span("engine.inputs"):
+                tokens = jnp.asarray(self.next_tokens)
+                ctx = jnp.asarray(ctx_np, jnp.int32)
+                ptbl = jnp.asarray(ptbl_np) if self.paged else None
+            dispatch = self.tracer.span("engine.dispatch")
             if self.attn_backend == "ref":
-                if self.paged:
-                    logits, self.cache = self._jit_decode_paged(
-                        self.params, self.cache, tokens, ctx, ptbl
-                    )
-                else:
-                    logits, self.cache = self._jit_decode(
-                        self.params, self.cache, tokens, ctx
-                    )
+                with dispatch:
+                    if self.paged:
+                        logits, self.cache = self._jit_decode_paged(
+                            self.params, self.cache, tokens, ctx, ptbl
+                        )
+                    else:
+                        logits, self.cache = self._jit_decode(
+                            self.params, self.cache, tokens, ctx
+                        )
             else:
                 num_splits = fixed_split_factor(
                     int(sched.seg_len.max(initial=1)),
@@ -1789,7 +1802,7 @@ class DecodeEngine:
                             kv_elem_bytes=self.kv_elem_bytes,
                         )
                     )
-                with _quiet_donation():
+                with dispatch, _quiet_donation():
                     if self.paged:
                         logits, self.cache = self._jit_kernel_step_paged(
                             self.params, self.cache, tokens, ctx, ptbl,
@@ -1848,13 +1861,15 @@ class DecodeEngine:
                     ptbl_np[s, :] = 0
         if level == 0:
             return self._decode_pass_main(slots, ctx_np, ptbl_np)
-        tokens = jnp.asarray(self.next_tokens)
-        ctx = jnp.asarray(ctx_np, jnp.int32)
-        ptbl = jnp.asarray(ptbl_np)
+        with self.tracer.span("engine.inputs"):
+            tokens = jnp.asarray(self.next_tokens)
+            ctx = jnp.asarray(ctx_np, jnp.int32)
+            ptbl = jnp.asarray(ptbl_np)
         if level >= 3 or self.attn_backend != "lean":
-            logits, self.cache = self._jit_decode_paged(
-                self.params, self.cache, tokens, ctx, ptbl
-            )
+            with self.tracer.span("engine.dispatch"):
+                logits, self.cache = self._jit_decode_paged(
+                    self.params, self.cache, tokens, ctx, ptbl
+                )
             return logits
         sched = self._tick_schedule(ctx_np)
         if self.tracer.enabled:
@@ -1867,7 +1882,7 @@ class DecodeEngine:
             int(sched.seg_len.max(initial=1)),
             sched.num_segments, self.tile, self.num_workers,
         )
-        with _quiet_donation():
+        with self.tracer.span("engine.dispatch"), _quiet_donation():
             logits, self.cache = self._jit_kernel_step_paged(
                 self.params, self.cache, tokens, ctx, ptbl,
                 backend="lean", sched=sched, num_splits=num_splits,
@@ -1950,19 +1965,20 @@ class DecodeEngine:
                 int(self.ctx_lens[s]) if s in drafts else 0
                 for s in range(N)
             ]
-            sched = make_spec_schedule(
-                spec_ctx, R, self.cfg.n_kv_heads, self.tile,
-                self.num_workers,
-                max_len=self.pages_per_slot * self.tile,
-                cache=self.sched_cache,
-            )
+            with self.tracer.span("engine.schedule_build"):
+                sched = make_spec_schedule(
+                    spec_ctx, R, self.cfg.n_kv_heads, self.tile,
+                    self.num_workers,
+                    max_len=self.pages_per_slot * self.tile,
+                    cache=self.sched_cache,
+                )
         sp = self.tracer.span("spec_verify", slots=len(slots), rows=R)
         with sp:
-            with _quiet_donation():
+            with self.tracer.span("engine.inputs"):
+                args = [jnp.asarray(a) for a in (toks, offs, lens, tbls)]
+            with self.tracer.span("engine.dispatch"), _quiet_donation():
                 rows, fin, self.cache = self._jit_spec_verify(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(offs), jnp.asarray(lens),
-                    jnp.asarray(tbls),
+                    self.params, self.cache, *args,
                     backend=self.attn_backend, sched=sched,
                     interpret=self.interpret,
                 )
@@ -1970,10 +1986,10 @@ class DecodeEngine:
                 t0 = time.perf_counter()
                 jax.block_until_ready(rows)
                 sp.add_sync(time.perf_counter() - t0)
-        return (
-            slots, np.asarray(rows),
-            np.array(fin) if guard_on else None, drafts,
-        )
+            with self.tracer.span("engine.token_wait"):
+                rows = np.asarray(rows)
+                fin = np.array(fin) if guard_on else None
+        return slots, rows, fin, drafts
 
     def _emit_spec_tokens(self, spec, out, guard_on: bool, cap: int) -> int:
         """Acceptance-rejection + emission for this tick's verify sweep.

@@ -648,32 +648,40 @@ class Scheduler:
         Returns {uid: token} for decode-produced tokens — token lists with
         speculative decode on (first tokens stream via callbacks and
         ``handle.generated``)."""
+        with self.tracer.span("sched.step"):
+            return self._step()
+
+    def _step(self) -> Dict[int, int]:
         self.stats.steps += 1
         self.stats.log_depth(len(self.queue))
         self._check_deadlines()
-        self._admit()
+        with self.tracer.span("sched.admit"):
+            self._admit()
         if self.chunked:
-            self._run_prefill()
+            with self.tracer.span("sched.prefill"):
+                self._run_prefill()
         prefilling = [
             s for s, sr in self._slot_sr.items()
             if sr.state is RequestState.PREFILLING
         ]
         out = self.engine.decode_tick(exclude=prefilling)
-        for uid, tok in out.items():
-            sr = self.requests[uid]
-            # the engine frees the slot when the budget is spent OR the
-            # context cap is hit — either way this request is terminal, and
-            # the stream contract owes its consumer a done=True token
-            finished = self.engine.slot_req[sr.slot] is not sr.req
-            # speculative ticks emit token *lists* (1..k+1 per slot); the
-            # stream contract is per-token either way, done only on the last
-            toks = tok if isinstance(tok, list) else [tok]
-            for j, t in enumerate(toks):
-                self._emit_decode_token(
-                    sr, t, done=finished and j == len(toks) - 1
-                )
-            if finished:
-                self._finish(sr)
+        with self.tracer.span("sched.deliver"):
+            for uid, tok in out.items():
+                sr = self.requests[uid]
+                # the engine frees the slot when the budget is spent OR the
+                # context cap is hit — either way this request is terminal,
+                # and the stream contract owes its consumer a done=True token
+                finished = self.engine.slot_req[sr.slot] is not sr.req
+                # speculative ticks emit token *lists* (1..k+1 per slot); the
+                # stream contract is per-token either way, done only on the
+                # last
+                toks = tok if isinstance(tok, list) else [tok]
+                for j, t in enumerate(toks):
+                    self._emit_decode_token(
+                        sr, t, done=finished and j == len(toks) - 1
+                    )
+                if finished:
+                    self._finish(sr)
         return out
 
     # -------------------------------------------------------------- draining

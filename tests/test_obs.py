@@ -71,8 +71,23 @@ def test_disabled_tracer_is_inert_and_falsy():
     assert tr.spans == []
     assert tr.request_uids() == []
     assert tr.request_summary(0) is None
-    # the module singleton is one shared disabled instance
-    assert NULL_TRACER.span("anything") is NULL_TRACER.span("other")
+    # the module singleton's spans only mark the profiler's trace: falsy,
+    # and nothing is kept in memory
+    with NULL_TRACER.span("anything") as s:
+        assert not s
+    assert not NULL_TRACER.span("sched.step")
+    assert NULL_TRACER.spans == []
+
+
+def test_layer_named_spans_only_mark_the_profile():
+    tr = Tracer()
+    with tr.span("tick") as tick:
+        with tr.span("engine.dispatch") as sp:
+            assert not sp              # never recorded, so never gated on
+            tr.annotate(level=0)       # lands on the recorded tick
+        assert tick
+    assert [s["name"] for s in tr.spans] == ["tick"]
+    assert tr.spans[0]["meta"] == {"level": 0}
 
 
 def test_span_capacity_is_a_ring():
