@@ -58,38 +58,77 @@ def paged_gather_kv(pool: jax.Array, page_tbl: jax.Array) -> jax.Array:
     return jnp.moveaxis(g, 2, 1).reshape(B, H, T * ps, d)
 
 
-def paged_scatter_tokens(
+def paged_write_rows(
     pool: jax.Array,        # (num_pages, H, page_size, d)
-    page_tbls: jax.Array,   # (N, W) int32 page table rows
-    offs: jax.Array,        # (N,) int32 first logical position of each chunk
-    lens: jax.Array,        # (N,) int32 valid tokens per chunk
-    vals: jax.Array,        # (N, C, H, d) new K or V rows
+    pages: jax.Array,       # (T,) int32 physical page of each token
+    offsets: jax.Array,     # (T,) int32 position of each token in its page
+    vals: jax.Array,        # (T, H, d) the tokens' K or V vectors
 ) -> jax.Array:
-    """Scatter chunk tokens *directly* into a paged pool via the page table.
+    """Write token vectors into a paged pool row-wise, in place.
 
-    Chunk row ``n`` writes token ``i < lens[n]`` at logical position
-    ``offs[n] + i`` — physical page ``page_tbls[n, pos // page_size]``,
-    offset ``pos % page_size``. Invalid positions (``i >= lens[n]``, e.g.
-    chunk padding or dummy pack rows) route to the null page, whose contents
-    are always masked by runtime context lengths. This is the chunked
-    prefill's KV append: no dense per-slot staging cache, no copy-on-admit.
-
-    Live chunk rows never collide (requests hold disjoint page sets and a
-    chunk's positions are distinct); only null-page writes may overlap,
-    which is harmless by construction.
+    The pool is viewed as ``num_pages * H`` pool rows of ``(page_size, d)``
+    (the rows the paged kernels read): head ``h`` of token ``t`` lands in
+    row ``pages[t] * H + h`` at offset ``offsets[t]``. The scatter's only
+    window dim is the minor ``d``, so the pool keeps the kernels' layout
+    and is updated where it lies. With a ``(H, d)`` window XLA's TPU
+    layout pass wants the heads second-minor, and copies the whole pool
+    into that layout and back around every write.
     """
-    N, C, H, d = vals.shape
-    ps = pool.shape[2]
+    num_pages, H, ps, d = pool.shape
+    rows = pages[:, None] * H + jnp.arange(H)[None, :]        # (T, H)
+    at = jnp.broadcast_to(offsets[:, None], rows.shape)
+    out = pool.reshape(num_pages * H, ps, d).at[
+        rows.reshape(-1), at.reshape(-1)
+    ].set(vals.reshape(-1, d).astype(pool.dtype))
+    return out.reshape(pool.shape)
+
+
+def _chunk_targets(page_tbls, offs, lens, C, ps, page_base):
+    """Physical page and in-page offset of each chunk position ``(N, C)``;
+    positions past ``lens`` go to the null page (page ``page_base``) at 0.
+    Also returns the ``(N, C)`` validity mask."""
     W = page_tbls.shape[1]
     pos = offs[:, None] + jnp.arange(C)[None, :]              # (N, C)
     valid = jnp.arange(C)[None, :] < lens[:, None]
     tile_idx = jnp.clip(pos // ps, 0, W - 1)
     pages = jnp.where(
         valid, jnp.take_along_axis(page_tbls, tile_idx, axis=1), 0
-    )
+    ) + page_base
     offsets = jnp.where(valid, pos % ps, 0)
-    return pool.at[pages.reshape(-1), :, offsets.reshape(-1)].set(
-        vals.reshape(N * C, H, d).astype(pool.dtype)
+    return pages, offsets, valid
+
+
+def paged_scatter_tokens(
+    pool: jax.Array,        # (num_pages, H, page_size, d)
+    page_tbls: jax.Array,   # (N, W) int32 page table rows
+    offs: jax.Array,        # (N,) int32 first logical position of each chunk
+    lens: jax.Array,        # (N,) int32 valid tokens per chunk
+    vals: jax.Array,        # (N, C, H, d) new K or V rows
+    page_base=0,            # pool page of the table's page 0
+) -> jax.Array:
+    """Scatter chunk tokens *directly* into a paged pool via the page table.
+
+    Chunk row ``n`` writes token ``i < lens[n]`` at logical position
+    ``offs[n] + i`` — physical page ``page_base + page_tbls[n, pos //
+    page_size]``, offset ``pos % page_size``. Invalid positions (``i >=
+    lens[n]``, e.g. chunk padding or dummy pack rows) route to the null
+    page, whose contents are always masked by runtime context lengths. This
+    is the chunked prefill's KV append: no dense per-slot staging cache, no
+    copy-on-admit. ``page_base`` lets ``pool`` be a stack of layers' pools
+    viewed as one: layer ``r``'s tables pass ``r * pages_per_layer``, and
+    its null page is its own page 0.
+
+    Live chunk rows never collide (requests hold disjoint page sets and a
+    chunk's positions are distinct); only null-page writes may overlap,
+    which is harmless by construction.
+    """
+    N, C, H, d = vals.shape
+    pages, offsets, _ = _chunk_targets(
+        page_tbls, offs, lens, C, pool.shape[2], page_base
+    )
+    return paged_write_rows(
+        pool, pages.reshape(-1), offsets.reshape(-1),
+        vals.reshape(N * C, H, d),
     )
 
 
@@ -148,6 +187,7 @@ def paged_scatter_tokens_quant(
     lens: jax.Array,        # (N,) int32 valid tokens per chunk
     vals: jax.Array,        # (N, C, H, d) new K or V rows (fp)
     per_head: bool = True,
+    page_base=0,            # pool page of the table's page 0
 ):
     """Quantizing counterpart of :func:`paged_scatter_tokens`.
 
@@ -157,19 +197,14 @@ def paged_scatter_tokens_quant(
     live; (2) requantize the touched pages' *existing* int8 content by
     ``round(q * old/new)`` (untouched pages keep old == new and are never
     read); (3) quantize the new tokens at the final scale and scatter.
-    Invalid positions route to the null page exactly like the fp path.
+    Invalid positions route to the null page, and ``page_base`` offsets
+    the table into a stack of layers' pools, exactly like the fp path.
     Returns ``(pool, scales)``.
     """
     N, C, H, d = vals.shape
-    ps = pool.shape[2]
-    W = page_tbls.shape[1]
-    pos = offs[:, None] + jnp.arange(C)[None, :]              # (N, C)
-    valid = jnp.arange(C)[None, :] < lens[:, None]
-    tile_idx = jnp.clip(pos // ps, 0, W - 1)
-    pages = jnp.where(
-        valid, jnp.take_along_axis(page_tbls, tile_idx, axis=1), 0
+    pages, offsets, valid = _chunk_targets(
+        page_tbls, offs, lens, C, pool.shape[2], page_base
     )
-    offsets = jnp.where(valid, pos % ps, 0)
     flat_pages = pages.reshape(-1)                            # (N*C,)
 
     vals_f = vals.astype(jnp.float32)
@@ -197,8 +232,8 @@ def paged_scatter_tokens_quant(
     inv = jnp.where(tok_s > 0, 1.0 / jnp.maximum(tok_s, 1e-30), 0.0)
     q = jnp.round(vals_f * inv[..., None])
     q = jnp.clip(q, -INT8_QMAX, INT8_QMAX).astype(pool.dtype)
-    pool = pool.at[flat_pages, :, offsets.reshape(-1)].set(
-        q.reshape(N * C, H, d)
+    pool = paged_write_rows(
+        pool, flat_pages, offsets.reshape(-1), q.reshape(N * C, H, d)
     )
     return pool, new_scales
 

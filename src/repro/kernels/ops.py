@@ -263,6 +263,7 @@ def decode(
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
     cascade: Optional[CascadeOperands] = None,
+    page_base=0,
 ):
     """The one decode dispatcher: ``plan`` picks the kernel family, the
     arrays ride alongside.
@@ -272,7 +273,10 @@ def decode(
     ``ctx`` carries the runtime lengths: per-segment context for decode
     kinds, visible KV (``off + len``) for ``'verify'``, suffix lengths for
     ``'cascade'``. ``qstart`` (verify only) is the per-segment causal
-    offset of query row 0. Pure in every array argument; ``plan`` is the
+    offset of query row 0. ``page_base`` (paged kinds) is the pool page
+    of the tables' page 0: the pools may be a stack of ``L`` layers' pools
+    viewed as one of ``L * P`` pages, read in place at layer ``r``'s pages
+    with ``page_base = r * P``. Pure in every array argument; ``plan`` is the
     only static key, so an enclosing ``jax.jit(...,
     static_argnames=('plan',))`` traces once per plan and replays across
     page migrations, bucket hits, and draft blocks alike.
@@ -284,20 +288,20 @@ def decode(
         if page_tbl is None:
             raise ValueError("paged plans need page_tbl")
         return _paged_decode_impl(
-            q, k, v, ctx, page_tbl, plan, k_scales, v_scales
+            q, k, v, ctx, page_tbl, plan, k_scales, v_scales, page_base
         )
     if plan.kind == "cascade":
         if cascade is None:
             raise ValueError("cascade plans need CascadeOperands")
         return _cascade_decode_impl(q, k, v, ctx, cascade, plan,
-                                    k_scales, v_scales)
+                                    k_scales, v_scales, page_base)
     if plan.kind == "flash":
         return _flash_decode_impl(q, k, v, ctx, plan)
     # 'verify': multi-q-row paged attention with runtime causal offset
     if page_tbl is None or qstart is None:
         raise ValueError("verify plans need page_tbl and qstart")
     return _verify_impl(
-        q, k, v, ctx, qstart, page_tbl, plan, k_scales, v_scales
+        q, k, v, ctx, qstart, page_tbl, plan, k_scales, v_scales, page_base
     )
 
 
@@ -410,20 +414,23 @@ def lean_decode(
 
 
 def _paged_route(
-    sched: LeanSchedule, page_tbl: jax.Array, num_kv_heads: int, fused: bool
+    sched: LeanSchedule, page_tbl: jax.Array, num_kv_heads: int, fused: bool,
+    page_base=0,
 ) -> jax.Array:
     """Per-grid-iteration flattened pool row ``page * H_kv + head``.
 
     The schedule contributes static logical routing (batch, head, tile per
     iteration — :meth:`LeanSchedule.iter_kv_meta`); the runtime page table
-    contributes the physical page. Invalid/merge iterations (and tiles past
-    the table width, which the runtime length always masks) route to the
-    null page's rows.
+    contributes the physical page, offset by ``page_base`` (the page of
+    the table's page 0 in a stack of layers' pools). Invalid/merge
+    iterations (and tiles past the table width, which the runtime length
+    always masks) route to the null page's rows, page ``page_base``.
+    This is the one place a page id becomes a pool row.
     """
     batch, head, tile_idx, ok = sched.iter_kv_meta(fused=fused)
     width = page_tbl.shape[1]
     pages = page_tbl[batch, np.minimum(tile_idx, width - 1)]
-    pages = jnp.where(jnp.asarray(ok) == 1, pages, 0)
+    pages = jnp.where(jnp.asarray(ok) == 1, pages, 0) + page_base
     return pages.astype(jnp.int32) * num_kv_heads + jnp.asarray(head)
 
 
@@ -442,6 +449,7 @@ def lean_decode_paged_from_schedule(
     return_lse: bool = False,
     k_scales: Optional[jax.Array] = None,   # int8 pools: (num_pages, Hkv) f32
     v_scales: Optional[jax.Array] = None,
+    page_base=0,                   # pool page of the table's page 0
 ):
     """Jit-stable *paged* LeanAttention decode against a prebuilt schedule.
 
@@ -470,7 +478,7 @@ def lean_decode_paged_from_schedule(
     )
     return decode(
         q, (k_pool, v_pool), plan=plan, ctx=seg_ctx, page_tbl=page_tbl,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, page_base=page_base,
     )
 
 
@@ -489,7 +497,7 @@ def _pool_rows(k_pool, v_pool, k_scales, v_scales):
 
 
 def _paged_decode_impl(q, k_pool, v_pool, seg_ctx, page_tbl,
-                       plan: DecodePlan, k_scales, v_scales):
+                       plan: DecodePlan, k_scales, v_scales, page_base):
     B, Hq, d = q.shape
     num_pages, Hkv, page_size, _ = k_pool.shape
     sched = plan.sched
@@ -510,7 +518,7 @@ def _paged_decode_impl(q, k_pool, v_pool, seg_ctx, page_tbl,
     fused = fused and decode_uses_fused(
         sched, gq, d, kv_elem_bytes=jnp.dtype(k_pool.dtype).itemsize
     )
-    route = _paged_route(sched, page_tbl, Hkv, fused)
+    route = _paged_route(sched, page_tbl, Hkv, fused, page_base)
     if fused:
         o_seg, lse = lean_decode_paged_fused(
             q_seg, k_rows, v_rows, seg_ctx, route, sched, scale,
@@ -624,6 +632,7 @@ def lean_decode_cascade_from_schedule(
     return_lse: bool = False,
     k_scales: Optional[jax.Array] = None,   # int8 pools: (num_pages, Hkv) f32
     v_scales: Optional[jax.Array] = None,
+    page_base=0,                   # pool page of the tables' page 0
 ):
     """Jit-stable cascade (prefix-grouped) paged LeanAttention decode.
 
@@ -664,12 +673,12 @@ def lean_decode_cascade_from_schedule(
     )
     return decode(
         q, (k_pool, v_pool), plan=plan, ctx=seg_ctx_suffix, cascade=ops_c,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, page_base=page_base,
     )
 
 
 def _cascade_decode_impl(q, k_pool, v_pool, seg_ctx_suffix, ops_c,
-                         plan: DecodePlan, k_scales, v_scales):
+                         plan: DecodePlan, k_scales, v_scales, page_base):
     csched = plan.sched
     scale, fused, interpret = plan.scale, plan.fused, plan.interpret
     return_lse = plan.return_lse
@@ -687,12 +696,9 @@ def _cascade_decode_impl(q, k_pool, v_pool, seg_ctx_suffix, ops_c,
     g = Hq // Hkv
     nmax = csched.group_size
     NP = csched.num_groups
-    k_rows = k_pool.reshape(num_pages * Hkv, page_size, d)
-    v_rows = v_pool.reshape(num_pages * Hkv, page_size, d)
-    ks_rows = vs_rows = None
-    if k_scales is not None:
-        ks_rows = k_scales.reshape(num_pages * Hkv, 1)
-        vs_rows = v_scales.reshape(num_pages * Hkv, 1)
+    k_rows, v_rows, ks_rows, vs_rows = _pool_rows(
+        k_pool, v_pool, k_scales, v_scales
+    )
 
     # stacked member queries: padding ranks carry member-0 copies whose
     # partial rows are dropped (or garbage-targeted) at merge
@@ -703,8 +709,12 @@ def _cascade_decode_impl(q, k_pool, v_pool, seg_ctx_suffix, ops_c,
     seg_ctx_prefix = jnp.repeat(
         jnp.asarray(prefix_lens, jnp.int32), Hkv
     )
-    route_p = _paged_route(csched.prefix_sched, prefix_tbl, Hkv, fused=False)
-    route_s = _paged_route(csched.suffix_sched, suffix_tbl, Hkv, fused=False)
+    route_p = _paged_route(
+        csched.prefix_sched, prefix_tbl, Hkv, False, page_base
+    )
+    route_s = _paged_route(
+        csched.suffix_sched, suffix_tbl, Hkv, False, page_base
+    )
     seg_ctx_suffix = seg_ctx_suffix.astype(jnp.int32)
 
     if fused and not cascade_uses_fused(
@@ -896,6 +906,7 @@ def lean_prefill_chunks(
     interpret: bool = False,
     k_scales: Optional[jax.Array] = None,   # int8 pools: (num_pages, Hkv) f32
     v_scales: Optional[jax.Array] = None,
+    page_base=0,                   # pool page of the tables' page 0
 ):
     """Jit-stable stream-K chunked prefill against a prebuilt chunk schedule.
 
@@ -921,11 +932,12 @@ def lean_prefill_chunks(
     return decode(
         q, (k_pool, v_pool), plan=plan, ctx=seg_ctx, page_tbl=page_tbls,
         qstart=seg_qstart, k_scales=k_scales, v_scales=v_scales,
+        page_base=page_base,
     )
 
 
 def _verify_impl(q, k_pool, v_pool, seg_ctx, seg_qstart, page_tbls,
-                 plan: DecodePlan, k_scales, v_scales):
+                 plan: DecodePlan, k_scales, v_scales, page_base):
     N, Hq, C, d = q.shape
     num_pages, Hkv, page_size, _ = k_pool.shape
     sched = plan.sched
@@ -944,7 +956,7 @@ def _verify_impl(q, k_pool, v_pool, seg_ctx, seg_qstart, page_tbls,
     k_rows, v_rows, ks_rows, vs_rows = _pool_rows(
         k_pool, v_pool, k_scales, v_scales
     )
-    route = _paged_route(sched, page_tbls, Hkv, fused=False)
+    route = _paged_route(sched, page_tbls, Hkv, False, page_base)
     o_p, m_p, l_p = lean_prefill_chunk_partials(
         q_seg, k_rows, v_rows, seg_ctx.astype(jnp.int32),
         seg_qstart.astype(jnp.int32), route, sched, scale,

@@ -203,35 +203,100 @@ def attn_decode(
     return out.astype(x.dtype), k_cache, v_cache
 
 
+def _pool_view(pools, layer):
+    """One stage's stacked pools ``(L, P, ...)`` (K, V and, for int8, their
+    scales; ``None`` entries pass through) seen as single pools of
+    ``L * P`` pages, which is a free reshape, and layer ``layer``'s page
+    base ``layer * P``: that layer's page ``p`` is page ``layer * P + p``
+    of the view. Nothing is sliced out, so nothing is copied."""
+    P = pools[0].shape[1]
+    flat = tuple(
+        None if a is None else a.reshape((-1,) + a.shape[2:]) for a in pools
+    )
+    return flat, layer * P
+
+
+def _write_kv(pools, page_tbls, offs, lens, k, v, page_base, per_head):
+    """Append ``k``/``v`` ``(N, C, Hkv, hd)`` through the page tables into
+    the viewed pools, in place; int8 pools requantize through their scales
+    (:func:`paged_scatter_tokens_quant`). The writes are KV pool I/O."""
+    k_pool, v_pool, k_scale, v_scale = pools
+    with jax.named_scope("kv_pool_io"):
+        if k_scale is not None:
+            k_pool, k_scale = paged_scatter_tokens_quant(
+                k_pool, k_scale, page_tbls, offs, lens, k,
+                per_head=per_head, page_base=page_base,
+            )
+            v_pool, v_scale = paged_scatter_tokens_quant(
+                v_pool, v_scale, page_tbls, offs, lens, v,
+                per_head=per_head, page_base=page_base,
+            )
+        else:
+            k_pool = paged_scatter_tokens(
+                k_pool, page_tbls, offs, lens, k, page_base=page_base
+            )
+            v_pool = paged_scatter_tokens(
+                v_pool, page_tbls, offs, lens, v, page_base=page_base
+            )
+    return k_pool, v_pool, k_scale, v_scale
+
+
+def _read_view(k_pool, v_pool, quant, page_base, layer_pages, compute_dtype):
+    """The pools attention reads and the page base to read them at. bf16,
+    f16, f32 and int8 pools are read where they lie; fp8 pools are widened
+    to ``compute_dtype``, this layer's pages only, and read at base 0."""
+    if quant or k_pool.dtype in (jnp.bfloat16, jnp.float16, jnp.float32):
+        return k_pool, v_pool, page_base
+    k_eff, v_eff = (
+        jax.lax.dynamic_slice_in_dim(a, page_base, layer_pages)
+        .astype(compute_dtype)
+        for a in (k_pool, v_pool)
+    )
+    return k_eff, v_eff, 0
+
+
+def _stacked(pools, like):
+    """Undo :func:`_pool_view`: each pool back to its stacked shape."""
+    return tuple(
+        None if a is None else a.reshape(b.shape) for a, b in zip(pools, like)
+    )
+
+
 def attn_decode_paged(
     p,
     x: jax.Array,                 # (B, 1, D) current token
-    k_pool: jax.Array,            # (num_pages, Hkv, page_size, hd)
+    k_pool: jax.Array,            # (L, num_pages, Hkv, page_size, hd)
     v_pool: jax.Array,
     page_tbl: jax.Array,          # (B, pages_per_slot) int32
     cur_len,                      # scalar int32 (kept for API symmetry)
     *,
+    layer=0,                      # which of the L stacked pools is this one's
     n_heads: int,
     n_kv: int,
     head_dim: int,
     rope_theta: Optional[float] = 10000.0,
     compute_dtype=jnp.bfloat16,
-    attn_fn=None,                 # override: f(q, k_pool, v_pool, ctx) -> out
+    attn_fn=None,    # override: f(q, k_pool, v_pool, ctx, page_base) -> out
     ctx_lens: Optional[jax.Array] = None,   # (B,) per-slot lengths, required
-    k_scale: Optional[jax.Array] = None,    # int8 pools: (num_pages, Hkv) f32
+    k_scale: Optional[jax.Array] = None,    # int8 pools: (L, num_pages, Hkv)
     v_scale: Optional[jax.Array] = None,
     scale_per_head: bool = True,
 ):
     """Paged twin of :func:`attn_decode` for global-attention layers.
 
     The KV cache is a global page pool shared by every slot; ``page_tbl``
-    maps each slot's logical tiles to physical pages. The new token's K/V
-    scatter into page ``page_tbl[b, ctx_b // page_size]`` at offset
-    ``ctx_b % page_size`` — idle slots (``ctx == 0`` with an all-null table
-    row) write the reserved null page, whose contents are always masked.
-    ``attn_fn`` receives the *pools* plus the visible lengths (the paged
-    lean kernel consumes them natively; ref/fixed backends gather first).
-    Returns (out, k_pool, v_pool).
+    maps each slot's logical tiles to physical pages. ``k_pool``/``v_pool``
+    hold the pools of a stage's ``L`` layers stacked, and this layer's are
+    ``k_pool[layer]``; they are read and written where they lie, never
+    sliced out (:func:`_pool_view`): every page id is offset by the
+    layer's page base. The new token's K/V go row-wise into page
+    ``page_tbl[b, ctx_b // page_size]`` at offset ``ctx_b % page_size``
+    (:func:`repro.core.attention.paged_write_rows`) — idle slots (``ctx ==
+    0`` with an all-null table row) write the layer's reserved null page,
+    whose contents are always masked. ``attn_fn`` receives the viewed
+    *pools*, the visible lengths and the page base (the paged lean kernel
+    consumes them natively; ref/fixed backends gather first).
+    Returns (out, k_pool, v_pool), the pools in their stacked shape.
 
     ``k_scale``/``v_scale`` flip the pools to quantized int8 storage: the
     token write goes through :func:`paged_scatter_tokens_quant` (scales
@@ -245,7 +310,7 @@ def attn_decode_paged(
         raise ValueError("paged decode requires per-slot ctx_lens")
     quant = k_scale is not None
     B, _, D = x.shape
-    ps = k_pool.shape[2]
+    layer_pages, ps = k_pool.shape[1], k_pool.shape[3]
     capacity = page_tbl.shape[1] * ps
     xc = x.astype(compute_dtype)
     q = (xc @ p["wq"].astype(compute_dtype)).reshape(B, 1, n_heads, head_dim)
@@ -258,98 +323,77 @@ def attn_decode_paged(
         pos = ctx_lens[:, None]                      # (B, 1) per slot
         q = rope(q, pos, rope_theta)
         k = rope(k, pos, rope_theta)
-    # scatter the token into its slot's current page; the pool writes, and
-    # the relayout copies XLA puts around them, are scoped as KV pool I/O
+    # the token goes into its slot's current page
+    stacked = (k_pool, v_pool, k_scale, v_scale)
+    pools, base = _pool_view(stacked, layer)
     write_pos = jnp.minimum(ctx_lens, capacity - 1)
-    with jax.named_scope("kv_pool_io"):
-        if quant:
-            ones = jnp.ones((B,), jnp.int32)
-            k_pool, k_scale = paged_scatter_tokens_quant(
-                k_pool, k_scale, page_tbl, write_pos, ones, k,
-                per_head=scale_per_head,
-            )
-            v_pool, v_scale = paged_scatter_tokens_quant(
-                v_pool, v_scale, page_tbl, write_pos, ones, v,
-                per_head=scale_per_head,
-            )
-        else:
-            pages_w = page_tbl[jnp.arange(B), write_pos // ps]
-            offs = write_pos % ps
-            k_pool = k_pool.at[pages_w, :, offs].set(
-                k[:, 0].astype(k_pool.dtype)
-            )
-            v_pool = v_pool.at[pages_w, :, offs].set(
-                v[:, 0].astype(v_pool.dtype)
-            )
+    pools = _write_kv(
+        pools, page_tbl, write_pos, jnp.ones((B,), jnp.int32), k, v, base,
+        scale_per_head,
+    )
+    kp, vp, ks, vs = pools
     ctx = jnp.minimum(ctx_lens + 1, capacity).astype(jnp.int32)
     qd = q.reshape(B, n_heads, head_dim)
-    k_eff, v_eff = k_pool, v_pool
-    if not quant and k_pool.dtype not in (
-        jnp.bfloat16, jnp.float16, jnp.float32
-    ):
-        # fp8 caches: reads upcast in-register; int8 pools instead stay
-        # quantized all the way to the kernel (scales ride alongside)
-        k_eff = k_pool.astype(compute_dtype)
-        v_eff = v_pool.astype(compute_dtype)
+    k_eff, v_eff, rbase = _read_view(
+        kp, vp, quant, base, layer_pages, compute_dtype
+    )
     if attn_fn is not None:
         if quant:
-            o = attn_fn(
-                qd, k_eff, v_eff, ctx, k_scales=k_scale, v_scales=v_scale
-            )
+            o = attn_fn(qd, k_eff, v_eff, ctx, rbase, k_scales=ks, v_scales=vs)
         else:
-            o = attn_fn(qd, k_eff, v_eff, ctx)
+            o = attn_fn(qd, k_eff, v_eff, ctx, rbase)
     else:
         from repro.core.attention import (
             mha_decode_ref, paged_gather_kv, paged_gather_kv_dequant,
         )
 
+        tbl = page_tbl + rbase
         if quant:
-            kd = paged_gather_kv_dequant(
-                k_eff, k_scale, page_tbl, dtype=compute_dtype
-            )
-            vd = paged_gather_kv_dequant(
-                v_eff, v_scale, page_tbl, dtype=compute_dtype
-            )
+            kd = paged_gather_kv_dequant(k_eff, ks, tbl, dtype=compute_dtype)
+            vd = paged_gather_kv_dequant(v_eff, vs, tbl, dtype=compute_dtype)
         else:
-            kd = paged_gather_kv(k_eff, page_tbl)
-            vd = paged_gather_kv(v_eff, page_tbl)
+            kd = paged_gather_kv(k_eff, tbl)
+            vd = paged_gather_kv(v_eff, tbl)
         o = mha_decode_ref(qd, kd, vd, ctx_lens=ctx)
     o = o.reshape(B, 1, n_heads * head_dim).astype(compute_dtype)
     out = o @ p["wo"].astype(compute_dtype)
+    kp, vp, ks, vs = _stacked(pools, stacked)
     if quant:
-        return out.astype(x.dtype), k_pool, v_pool, k_scale, v_scale
-    return out.astype(x.dtype), k_pool, v_pool
+        return out.astype(x.dtype), kp, vp, ks, vs
+    return out.astype(x.dtype), kp, vp
 
 
 def attn_prefill_chunk_paged(
     p,
     x: jax.Array,                 # (N, C, D) one prompt chunk per row
-    k_pool: jax.Array,            # (num_pages, Hkv, page_size, hd)
+    k_pool: jax.Array,            # (L, num_pages, Hkv, page_size, hd)
     v_pool: jax.Array,
     page_tbls: jax.Array,         # (N, W) int32 page table rows
     offs: jax.Array,              # (N,) int32 absolute position of chunk[0]
     lens: jax.Array,              # (N,) int32 valid tokens per chunk
     *,
+    layer=0,                      # which of the L stacked pools is this one's
     n_heads: int,
     n_kv: int,
     head_dim: int,
     rope_theta: Optional[float] = 10000.0,
     compute_dtype=jnp.bfloat16,
-    attn_fn=None,     # override: f(q, k_pool, v_pool, page_tbls, offs) -> o
-    k_scale: Optional[jax.Array] = None,    # int8 pools: (num_pages, Hkv) f32
+    attn_fn=None,  # override: f(q, k_pool, v_pool, tbls, offs, base) -> o
+    k_scale: Optional[jax.Array] = None,    # int8 pools: (L, num_pages, Hkv)
     v_scale: Optional[jax.Array] = None,
     scale_per_head: bool = True,
 ):
     """Chunked-prefill attention for global-attention layers (paged KV).
 
-    The prefill sibling of :func:`attn_decode_paged`: each batch row is one
-    prompt *chunk* of an in-flight request — ``C`` positions starting at
-    absolute offset ``offs[n]``, of which ``lens[n]`` are valid. The chunk's
-    K/V append **directly into the page pool** through the row's page table
-    (no dense staging cache), then queries attend causally over the row's
-    visible prefix ``[0, offs[n] + lens[n])`` read back through the same
-    table. RoPE uses absolute positions, so chunked and whole-prompt
-    prefill produce the same cache contents.
+    The prefill sibling of :func:`attn_decode_paged`, with the same stacked
+    pools and page base: each batch row is one prompt *chunk* of an
+    in-flight request — ``C`` positions starting at absolute offset
+    ``offs[n]``, of which ``lens[n]`` are valid. The chunk's K/V append
+    **directly into the page pool** through the row's page table (no dense
+    staging cache), then queries attend causally over the row's visible
+    prefix ``[0, offs[n] + lens[n])`` read back through the same table.
+    RoPE uses absolute positions, so chunked and whole-prompt prefill
+    produce the same cache contents.
 
     Chunk-padding positions (``i >= lens[n]``) write the null page and
     produce garbage activations confined to their own rows; callers gather
@@ -372,50 +416,43 @@ def attn_prefill_chunk_paged(
         k = rope(k, pos, rope_theta)
     # append the chunk's KV to the pool FIRST — queries attend their own
     # chunk (causally), so the read below must see these writes
-    with jax.named_scope("kv_pool_io"):
-        if quant:
-            k_pool, k_scale = paged_scatter_tokens_quant(
-                k_pool, k_scale, page_tbls, offs, lens, k,
-                per_head=scale_per_head,
-            )
-            v_pool, v_scale = paged_scatter_tokens_quant(
-                v_pool, v_scale, page_tbls, offs, lens, v,
-                per_head=scale_per_head,
-            )
-        else:
-            k_pool = paged_scatter_tokens(k_pool, page_tbls, offs, lens, k)
-            v_pool = paged_scatter_tokens(v_pool, page_tbls, offs, lens, v)
+    stacked = (k_pool, v_pool, k_scale, v_scale)
+    pools, base = _pool_view(stacked, layer)
+    pools = _write_kv(
+        pools, page_tbls, offs, lens, k, v, base, scale_per_head
+    )
+    kp, vp, ks, vs = pools
     qh = jnp.swapaxes(q, 1, 2)                             # (N, Hq, C, hd)
-    k_eff, v_eff = k_pool, v_pool
-    if not quant and k_pool.dtype not in (
-        jnp.bfloat16, jnp.float16, jnp.float32
-    ):
-        k_eff = k_pool.astype(compute_dtype)
-        v_eff = v_pool.astype(compute_dtype)
+    k_eff, v_eff, rbase = _read_view(
+        kp, vp, quant, base, k_pool.shape[1], compute_dtype
+    )
     if attn_fn is not None:
         if quant:
             o = attn_fn(
-                qh, k_eff, v_eff, page_tbls, offs,
-                k_scales=k_scale, v_scales=v_scale,
+                qh, k_eff, v_eff, page_tbls, offs, rbase,
+                k_scales=ks, v_scales=vs,
             )
         else:
-            o = attn_fn(qh, k_eff, v_eff, page_tbls, offs)
+            o = attn_fn(qh, k_eff, v_eff, page_tbls, offs, rbase)
     else:
         if quant:
             # reference path: dequantize the whole pool densely (tests /
             # fallback only — the kernel path never materializes this)
             k_eff = (
-                k_pool.astype(jnp.float32) * k_scale[:, :, None, None]
+                kp.astype(jnp.float32) * ks[:, :, None, None]
             ).astype(compute_dtype)
             v_eff = (
-                v_pool.astype(jnp.float32) * v_scale[:, :, None, None]
+                vp.astype(jnp.float32) * vs[:, :, None, None]
             ).astype(compute_dtype)
-        o = mha_chunk_prefill_paged_ref(qh, k_eff, v_eff, page_tbls, offs)
+        o = mha_chunk_prefill_paged_ref(
+            qh, k_eff, v_eff, page_tbls + rbase, offs
+        )
     o = jnp.swapaxes(o, 1, 2).reshape(N, C, n_heads * head_dim)
     out = o.astype(compute_dtype) @ p["wo"].astype(compute_dtype)
+    kp, vp, ks, vs = _stacked(pools, stacked)
     if quant:
-        return out.astype(x.dtype), k_pool, v_pool, k_scale, v_scale
-    return out.astype(x.dtype), k_pool, v_pool
+        return out.astype(x.dtype), kp, vp, ks, vs
+    return out.astype(x.dtype), kp, vp
 
 
 # ---------------------------------------------------------------- FFN

@@ -631,43 +631,55 @@ def supports_chunked_prefill(cfg: ModelConfig) -> bool:
     )
 
 
-def _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg: ModelConfig):
+def _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg: ModelConfig, pooled):
     """Run ``reps`` units of one stage against its stacked cache; returns
-    ``(x, stage_cache)``. Each unit reads its layer of the cache and the
-    stack takes back what the unit wrote. Under ``scan_layers`` the cache
-    rides in the scan CARRY, updated in place via dynamic-update-slice —
-    as xs/ys XLA double-buffers the multi-GB stacked KV cache through the
-    loop. Those reads and write-backs are scoped ``kv_pool_io``, so a
-    profile can tell them from the layers' own work."""
+    ``(x, stage_cache)``. ``unit_fn(x, unit_params, unit_cache, r)`` runs
+    unit ``r`` and returns ``(x, new_unit_cache)``.
+
+    ``pooled[i]`` says whether layer ``i`` of the unit holds page pools (a
+    paged ``attn`` layer). Its leaves stay whole, ``(reps, num_pages,
+    ...)``: the unit gets the stack with its index ``r``, writes its tokens
+    into layer ``r``'s pages and reads them there, and hands the stack
+    back, so no layer's pool is ever copied. Every other layer's leaves
+    (window rings, cross-attention, recurrent state, dense per-slot
+    caches) are sliced out for the unit and written back into the stack;
+    those slices and write-backs are scoped ``kv_pool_io``, as are the
+    pools' token writes, so a profile can tell them from the layers' own
+    work. Under ``scan_layers`` the cache rides in the scan CARRY: as
+    xs/ys XLA would double-buffer the multi-GB stack through the loop."""
+
+    def unit(x, cache_c, up, r):
+        with jax.named_scope("kv_pool_io"):
+            uc = tuple(
+                lc if pool else jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, r, 0, keepdims=False
+                    ),
+                    lc,
+                )
+                for lc, pool in zip(cache_c, pooled)
+            )
+        x, nc = unit_fn(x, up, uc, r)
+        with jax.named_scope("kv_pool_io"):
+            cache_c = tuple(
+                new if pool else jax.tree.map(
+                    lambda buf, a: jax.lax.dynamic_update_index_in_dim(
+                        buf, a.astype(buf.dtype), r, 0
+                    ),
+                    buf_c, new,
+                )
+                for buf_c, new, pool in zip(cache_c, nc, pooled)
+            )
+        return x, cache_c
+
     if reps == 1 or not cfg.scan_layers:
-        ncs = []
         for r in range(reps):
             up = jax.tree.map(lambda a: a[r], stage_p)
-            with jax.named_scope("kv_pool_io"):
-                uc = jax.tree.map(lambda a: a[r], stage_c)
-            x, nc = unit_fn(x, (up, uc))
-            ncs.append(nc)
-        with jax.named_scope("kv_pool_io"):
-            return x, jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+            x, stage_c = unit(x, stage_c, up, r)
+        return x, stage_c
 
-    def body(carry, up_i):
-        x, cache_c = carry
-        up, r = up_i
-        with jax.named_scope("kv_pool_io"):
-            uc = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
-                cache_c,
-            )
-        x, nc = unit_fn(x, (up, uc))
-        with jax.named_scope("kv_pool_io"):
-            cache_c = jax.tree.map(
-                lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                    buf, new.astype(buf.dtype), r, 0
-                ),
-                cache_c,
-                nc,
-            )
-        return (x, cache_c), None
+    def body(carry, up_r):
+        return unit(*carry, *up_r), None
 
     (x, stage_nc), _ = jax.lax.scan(
         body, (x, stage_c), (stage_p, jnp.arange(reps))
@@ -703,15 +715,14 @@ def _chunk_forward(
         cfg.stages, params["stages"], cache
     ):
 
-        def unit_fn(x, up_uc):
-            up, uc = up_uc
+        def unit_fn(x, up, uc, r):
             new_cs = []
             for kind, lp, lc in zip(pattern, up, uc):
                 quant = "k_scale" in lc
                 with jax.named_scope("attn"):
                     out = attn_prefill_chunk_paged(
                         lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
-                        lc["k"], lc["v"], page_tbls, offs, lens,
+                        lc["k"], lc["v"], page_tbls, offs, lens, layer=r,
                         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                         head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
                         attn_fn=attn_fn,
@@ -732,7 +743,9 @@ def _chunk_forward(
                 new_cs.append(nc)
             return x, tuple(new_cs)
 
-        x, stage_nc = _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg)
+        x, stage_nc = _run_stage(
+            unit_fn, x, stage_p, stage_c, reps, cfg, (True,) * len(pattern)
+        )
         new_cache.append(stage_nc)
     return x, new_cache
 
@@ -834,8 +847,7 @@ def decode_step(
         cfg.stages, params["stages"], cache
     ):
 
-        def unit_fn(x, up_uc):
-            up, uc = up_uc
+        def unit_fn(x, up, uc, r):
             new_cs = []
             for kind, lp, lc in zip(pattern, up, uc):
                 if kind in ATTN_KINDS:
@@ -846,6 +858,7 @@ def decode_step(
                             out = attn_decode_paged(
                                 lp["attn"], rms_norm(x, lp["ln1"], cfg.norm_eps),
                                 lc["k"], lc["v"], page_tbl, cur_len,
+                                layer=r,
                                 n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                                 head_dim=cfg.head_dim,
                                 rope_theta=cfg.rope_theta,
@@ -918,7 +931,12 @@ def decode_step(
                 new_cs.append(nc)
             return x, tuple(new_cs)
 
-        x, stage_nc = _run_stage(unit_fn, x, stage_p, stage_c, reps, cfg)
+        pooled = tuple(
+            page_tbl is not None and kind == "attn" for kind in pattern
+        )
+        x, stage_nc = _run_stage(
+            unit_fn, x, stage_p, stage_c, reps, cfg, pooled
+        )
         new_cache.append(stage_nc)
     x = rms_norm(x[:, -1], params["final_norm"], cfg.norm_eps)
     return _unembed(params, cfg, x), new_cache

@@ -362,22 +362,25 @@ def _kernel_decode_step_paged(
     """Paged twin of ``_kernel_decode_step``: the page table rides along as
     a runtime array (no retrace when sequences migrate across pages); the
     lean backend fetches tiles through it natively, the fixed-split
-    baseline gathers to dense first."""
+    baseline gathers to dense first. Each layer's call passes the page
+    base of its pool in the stacked pools it receives."""
 
-    def attn_fn(q, k_pool, v_pool, ctx, k_scales=None, v_scales=None):
+    def attn_fn(q, k_pool, v_pool, ctx, page_base, k_scales=None,
+                v_scales=None):
         seg_ctx = jnp.repeat(ctx.astype(jnp.int32), cfg.n_kv_heads)
         if backend == "lean":
             return lean_decode_paged_from_schedule(
                 q, k_pool, v_pool, seg_ctx, page_tbl, sched,
                 fused=fused, interpret=interpret,
-                k_scales=k_scales, v_scales=v_scales,
+                k_scales=k_scales, v_scales=v_scales, page_base=page_base,
             )
+        tbl = page_tbl + page_base
         if k_scales is not None:
-            kd = paged_gather_kv_dequant(k_pool, k_scales, page_tbl)
-            vd = paged_gather_kv_dequant(v_pool, v_scales, page_tbl)
+            kd = paged_gather_kv_dequant(k_pool, k_scales, tbl)
+            vd = paged_gather_kv_dequant(v_pool, v_scales, tbl)
         else:
-            kd = paged_gather_kv(k_pool, page_tbl)
-            vd = paged_gather_kv(v_pool, page_tbl)
+            kd = paged_gather_kv(k_pool, tbl)
+            vd = paged_gather_kv(v_pool, tbl)
         return flash_decode_from_lens(
             q, kd, vd, seg_ctx,
             num_splits=num_splits, tile=sched.tile_size, interpret=interpret,
@@ -416,7 +419,8 @@ def _kernel_decode_step_cascade(
     (members, pass lengths, per-slot coverage, tables, merge descriptors)
     rides as runtime arrays, so equivalent geometries share this trace."""
 
-    def attn_fn(q, k_pool, v_pool, ctx, k_scales=None, v_scales=None):
+    def attn_fn(q, k_pool, v_pool, ctx, page_base, k_scales=None,
+                v_scales=None):
         suffix = jnp.maximum(
             ctx.astype(jnp.int32) - seq_prefix_len.astype(jnp.int32), 0
         )
@@ -425,7 +429,7 @@ def _kernel_decode_step_cascade(
             q, k_pool, v_pool, seg_suffix, prefix_lens, members,
             prefix_tbl, suffix_tbl, fused_desc, csched,
             fused=fused, interpret=interpret,
-            k_scales=k_scales, v_scales=v_scales,
+            k_scales=k_scales, v_scales=v_scales, page_base=page_base,
         )
 
     cur = jnp.max(ctx_lens)
@@ -561,23 +565,27 @@ def _chunk_attn_fn(offs, lens, *, cfg, backend, sched, interpret):
     """The multi-q-row paged attention closure shared by chunked prefill
     and the speculative verify step (``None`` selects the gather + jnp
     reference path). Rows attend causally up to ``offs + row`` via the
-    schedule's runtime ``qstart``."""
+    schedule's runtime ``qstart``; ``page_base`` is the layer's page base
+    in the stacked pools."""
     if backend == "lean":
 
-        def attn_fn(q, k_pool, v_pool, tbls, o, k_scales=None, v_scales=None):
+        def attn_fn(q, k_pool, v_pool, tbls, o, page_base, k_scales=None,
+                    v_scales=None):
             visible = jnp.maximum(offs + lens, 1).astype(jnp.int32)
             seg_ctx = jnp.repeat(visible, cfg.n_kv_heads)
             seg_qstart = jnp.repeat(offs.astype(jnp.int32), cfg.n_kv_heads)
             return lean_prefill_chunks(
                 q, k_pool, v_pool, seg_ctx, seg_qstart, tbls, sched,
                 interpret=interpret, k_scales=k_scales, v_scales=v_scales,
+                page_base=page_base,
             )
 
         return attn_fn
 
     if backend == "fixed":
 
-        def attn_fn(q, k_pool, v_pool, tbls, o, k_scales=None, v_scales=None):
+        def attn_fn(q, k_pool, v_pool, tbls, o, page_base, k_scales=None,
+                    v_scales=None):
             if k_scales is not None:
                 # fixed-split baseline has no in-kernel dequant — widen the
                 # pool view first (bench/fallback path only)
@@ -588,7 +596,7 @@ def _chunk_attn_fn(offs, lens, *, cfg, backend, sched, interpret):
                     v_pool.astype(jnp.float32) * v_scales[:, :, None, None]
                 ).astype(jnp.bfloat16)
             return flash_prefill_paged(
-                q, k_pool, v_pool, tbls, o, interpret=interpret
+                q, k_pool, v_pool, tbls + page_base, o, interpret=interpret
             )
 
         return attn_fn

@@ -1,7 +1,9 @@
 """The main-path stream-K kernels compile for a TPU v5e at Mistral-NeMo-12B
 attention widths (32 q / 8 kv heads, head_dim 128, bf16 or int8 KV, 8
 sequences of 4k context in 128-token pages), and the fused decode kernel
-compiles at the largest batch its VMEM gate admits.
+compiles at the largest batch its VMEM gate admits. The engine's paged
+decode and chunked-prefill steps, at Yi-34B widths, compile to programs
+that never copy a layer's KV pool or the stack of them.
 
 Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
 *described* v5e chip, which refuses what Mosaic cannot lower (block shapes
@@ -10,6 +12,9 @@ interpret mode on the CPU accepts. The topology is described inside the
 fixture, never at import, so every test worker collects the same tests and
 only the one running this file loads the TPU compiler.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,6 +37,8 @@ from repro.kernels.lean_decode import (
 )
 from repro.kernels.lean_prefill import lean_prefill_chunk_partials
 from repro.kernels.ops import decode_uses_fused
+from repro.models import ModelConfig, init_paged_cache, init_params
+from repro.serving import engine
 
 B, HQ, HKV, D, CTX, PAGE, WORKERS = 8, 32, 8, 128, 4096, 128, 16
 GQ = HQ // HKV
@@ -208,3 +215,69 @@ def test_cascade_fused_decode(one_chip, kv):
         ((seg_tot,), jnp.int32), ((cs.fused_grid_iters,), jnp.int32),
         (desc.shape, jnp.int32), *scales,
     )
+
+
+# Yi-34B's attention and FFN widths, 4 layers, 4 sequences, and the pool of
+# the yi34b-decode-fewlong benchmark cell: 1228 pages of 128 tokens
+YI = ModelConfig(
+    name="yi-34b-4l", d_model=7168, n_layers=4, n_heads=56, n_kv_heads=HKV,
+    head_dim=D, d_ff=20480, vocab_size=64000, stages=((("attn",), 4),),
+    rope_theta=5e6, norm_eps=1e-5, tie_embeddings=False,
+)
+YI_BATCH, YI_PAGES, YI_WIDTH = 4, 1228, 420     # width: pages per sequence
+POOL_ELEMS = YI_PAGES * HKV * PAGE * D          # one layer's K or V pool
+WHOLE_POOL_OPS = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_copies(hlo):
+    """Instructions of an optimized HLO module that copy, slice or update a
+    whole pool, one layer's or the stack's: ops of those kinds, and fusions
+    named after them, whose result has as many elements."""
+    found = []
+    for m in re.finditer(
+            r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(", hlo):
+        name, dims, op = m.groups()
+        elems = int(np.prod([int(x) for x in dims.split(",") if x]))
+        if elems not in (POOL_ELEMS, YI.n_layers * POOL_ELEMS):
+            continue
+        if op in WHOLE_POOL_OPS or (
+                op == "fusion" and any(k in name for k in WHOLE_POOL_OPS)):
+            found.append(f"{name} = {op}[{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk_prefill"])
+def test_model_step_keeps_the_pool_in_place(one_chip, step):
+    """Every layer writes its tokens into its own pool in the stacked cache
+    and the kernel reads its pages there: the compiled step holds no op
+    that copies, slices out or writes back a whole pool."""
+    def sds(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, jnp.bfloat16 if a.dtype == jnp.float32 else a.dtype,
+                sharding=one_chip),
+            tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), YI)))
+    cache = sds(jax.eval_shape(lambda: init_paged_cache(
+        YI, YI_BATCH, YI_WIDTH * PAGE, YI_PAGES, PAGE)))
+    if step == "decode":
+        sched = make_schedule([22528, 30000, 40000, 53248], HKV, PAGE, WORKERS)
+        fn = functools.partial(
+            engine._kernel_decode_step_paged, cfg=YI, backend="lean",
+            sched=sched, num_splits=1, fused=True, interpret=False)
+        args = (i32(YI_BATCH, 1), i32(YI_BATCH), i32(YI_BATCH, YI_WIDTH))
+    else:
+        sched = make_chunk_schedule([16 * CHUNK], HKV, PAGE, WORKERS)
+        fn = functools.partial(
+            engine._chunk_prefill_step, cfg=YI, backend="lean", sched=sched,
+            interpret=False)
+        args = (i32(1, CHUNK), i32(1), i32(1), i32(1, YI_WIDTH))
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert _pool_copies(hlo) == []
