@@ -159,6 +159,40 @@ def init_params(rng, cfg: ModelConfig):
 
 
 # ------------------------------------------------------------------ caches
+def _layer_cache(cfg: ModelConfig, kind: str, reps: int, batch: int,
+                 cache_len: int, kv_dtype):
+    """Dense decode state of ``reps`` stacked layers of one kind, each leaf
+    made at its stacked shape ``(reps, ...)``."""
+    def zeros(*shape, dtype=jnp.float32):
+        return jnp.zeros((reps,) + shape, dtype)
+
+    D = cfg.d_model
+    if kind in ATTN_KINDS:
+        S = min(cache_len, cfg.window) if kind == "win" else cache_len
+        kv = (batch, cfg.n_kv_heads, S, cfg.head_dim)
+        c = {"k": zeros(*kv, dtype=kv_dtype), "v": zeros(*kv, dtype=kv_dtype)}
+        if kind == "xattn":
+            xkv = (batch, cfg.n_kv_heads, cfg.cross_kv_len, cfg.head_dim)
+            c["xk"] = zeros(*xkv, dtype=kv_dtype)
+            c["xv"] = zeros(*xkv, dtype=kv_dtype)
+        return c
+    if kind == "rglru":
+        W = cfg.rnn_width
+        return {"h": zeros(batch, W), "conv": zeros(batch, 3, W)}
+    if kind == "mlstm":
+        pd = int(D * cfg.mlstm_proj_factor)
+        hd = pd // cfg.n_heads
+        return {
+            "C": zeros(batch, cfg.n_heads, hd, hd),
+            "n": zeros(batch, cfg.n_heads, hd),
+            "m": zeros(batch, cfg.n_heads),
+        }
+    if kind == "slstm":
+        hd = D // cfg.n_heads
+        return {k: zeros(batch, cfg.n_heads, hd) for k in ("c", "n", "m", "h")}
+    raise ValueError(kind)
+
+
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
                kv_dtype=None):
     """Decode-state pytree mirroring the stage structure."""
@@ -166,54 +200,11 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
         kv_dtype = (
             jnp.float8_e4m3fn if cfg.kv_cache_dtype == "f8" else jnp.bfloat16
         )
-
-    def layer_cache(kind):
-        D = cfg.d_model
-        if kind in ATTN_KINDS:
-            S = min(cache_len, cfg.window) if kind == "win" else cache_len
-            c = {
-                "k": jnp.zeros((batch, cfg.n_kv_heads, S, cfg.head_dim), kv_dtype),
-                "v": jnp.zeros((batch, cfg.n_kv_heads, S, cfg.head_dim), kv_dtype),
-            }
-            if kind == "xattn":
-                c["xk"] = jnp.zeros(
-                    (batch, cfg.n_kv_heads, cfg.cross_kv_len, cfg.head_dim),
-                    kv_dtype,
-                )
-                c["xv"] = jnp.zeros_like(c["xk"])
-            return c
-        if kind == "rglru":
-            W = cfg.rnn_width
-            return {
-                "h": jnp.zeros((batch, W), jnp.float32),
-                "conv": jnp.zeros((batch, 3, W), jnp.float32),
-            }
-        if kind == "mlstm":
-            pd = int(D * cfg.mlstm_proj_factor)
-            hd = pd // cfg.n_heads
-            return {
-                "C": jnp.zeros((batch, cfg.n_heads, hd, hd), jnp.float32),
-                "n": jnp.zeros((batch, cfg.n_heads, hd), jnp.float32),
-                "m": jnp.zeros((batch, cfg.n_heads), jnp.float32),
-            }
-        if kind == "slstm":
-            hd = D // cfg.n_heads
-            z = jnp.zeros((batch, cfg.n_heads, hd), jnp.float32)
-            return {"c": z, "n": z, "m": z, "h": z}
-        raise ValueError(kind)
-
-    cache = []
-    for pattern, reps in cfg.stages:
-        unit = []
-        for kind in pattern:
-            one = layer_cache(kind)
-            unit.append(
-                jax.tree.map(
-                    lambda x: jnp.broadcast_to(x, (reps,) + x.shape), one
-                )
-            )
-        cache.append(tuple(unit))
-    return cache
+    return [
+        tuple(_layer_cache(cfg, kind, reps, batch, cache_len, kv_dtype)
+              for kind in pattern)
+        for pattern, reps in cfg.stages
+    ]
 
 
 def init_paged_cache(
@@ -233,6 +224,11 @@ def init_paged_cache(
     memory wall), and cross-attention / recurrent state stays per-slot.
     The same logical page ids index every layer's pool (one allocator, many
     pools), exactly as in paged-attention serving stacks.
+
+    Each stage's pools are made at their stacked shape ``(reps, num_pages,
+    H_kv, page_size, head_dim)`` and nothing else is made for them: no
+    dense attention row exists at any point, so building the cache needs
+    no memory beyond the cache itself.
 
     ``kv_cache_dtype == "int8"`` (or ``kv_dtype=jnp.int8``) stores the pools
     *quantized*: each attn layer additionally carries ``k_scale`` /
@@ -255,29 +251,21 @@ def init_paged_cache(
         quant = jnp.dtype(kv_dtype) == jnp.int8
     # dense sub-caches (window rings, cross-attn) stay fp — only the shared
     # page pools quantize
-    dense = init_cache(
-        cfg, batch, cache_len,
-        kv_dtype=jnp.bfloat16 if quant else kv_dtype,
-    )
-    pool = jnp.zeros(
-        (num_pages, cfg.n_kv_heads, page_size, cfg.head_dim), kv_dtype
-    )
-    scales = jnp.zeros((num_pages, cfg.n_kv_heads), jnp.float32)
+    dense_dtype = jnp.bfloat16 if quant else kv_dtype
     cache = []
-    for (pattern, reps), stage_c in zip(cfg.stages, dense):
+    for pattern, reps in cfg.stages:
         unit = []
-        for kind, lc in zip(pattern, stage_c):
-            if kind == "attn":
-                lc = dict(lc)
-                lc["k"] = jnp.broadcast_to(pool, (reps,) + pool.shape)
-                lc["v"] = jnp.broadcast_to(pool, (reps,) + pool.shape)
-                if quant:
-                    lc["k_scale"] = jnp.broadcast_to(
-                        scales, (reps,) + scales.shape
-                    )
-                    lc["v_scale"] = jnp.broadcast_to(
-                        scales, (reps,) + scales.shape
-                    )
+        for kind in pattern:
+            if kind != "attn":
+                unit.append(_layer_cache(cfg, kind, reps, batch, cache_len,
+                                         dense_dtype))
+                continue
+            pool = (reps, num_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+            lc = {"k": jnp.zeros(pool, kv_dtype), "v": jnp.zeros(pool, kv_dtype)}
+            if quant:
+                scales = (reps, num_pages, cfg.n_kv_heads)
+                lc["k_scale"] = jnp.zeros(scales, jnp.float32)
+                lc["v_scale"] = jnp.zeros(scales, jnp.float32)
             unit.append(lc)
         cache.append(tuple(unit))
     return cache

@@ -870,9 +870,24 @@ class DecodeEngine:
             self.page_tbl = np.zeros(
                 (max_batch, self.pages_per_slot), dtype=np.int32
             )
-            self.cache = init_paged_cache(
-                cfg, max_batch, cache_len, num_pages, self.tile
-            )
+            # the span holds the pools' allocation and fill, not only their
+            # dispatch
+            with self.tracer.span("engine.init_cache"):
+                self.cache = jax.block_until_ready(init_paged_cache(
+                    cfg, max_batch, cache_len, num_pages, self.tile
+                ))
+            self.metrics.gauge(
+                "engine_kv_pool_bytes",
+                help="bytes of every attention layer's page pools together",
+            ).set(sum(
+                leaf.nbytes
+                for (pattern, _), stage_c in zip(cfg.stages, self.cache)
+                for kind, lc in zip(pattern, stage_c) if kind == "attn"
+                for leaf in lc.values()
+            ))
+            self.metrics.gauge(
+                "engine_kv_pages_total", help="pages in each layer's pool"
+            ).set(num_pages)
         else:
             self.pool = None
             self.page_tbl = None

@@ -88,3 +88,25 @@ def test_engine_steps_compile_to_named_modules_with_scoped_regions(
     assert "jit__unknown" not in hlo
     for scope in SCOPES:
         assert f"/{scope}/" in hlo, f"no op in scope {scope}"
+
+
+def test_engine_marks_its_cache_build_and_publishes_the_pool_size(smoke, tmp_path):
+    from jax.profiler import ProfileData
+
+    cfg, params = smoke
+    with jax.profiler.trace(str(tmp_path)):
+        eng = DecodeEngine(cfg, params, config=EngineConfig(
+            max_batch=2, cache_len=64, attn_backend="lean",
+            paged=PagedConfig(enabled=True, page_size=8, num_pages=11),
+        ))
+    xplane = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    marks = {ev.name for plane in ProfileData.from_file(str(xplane)).planes
+             if plane.name.startswith("/host")
+             for line in plane.lines for ev in line.events}
+    assert "engine.init_cache" in marks
+    pools = [leaf for pattern_c in eng.cache for lc in pattern_c
+             for key, leaf in lc.items() if key in ("k", "v")]
+    assert pools and all(p.shape[1] == 11 for p in pools)
+    md = eng.metrics.as_dict()
+    assert md["engine_kv_pool_bytes"]["value"] == sum(p.nbytes for p in pools)
+    assert md["engine_kv_pages_total"]["value"] == 11
