@@ -168,15 +168,17 @@ class LeanSchedule:
             object.__setattr__(self, "_packed", desc)
         return desc
 
-    def fused_descriptors(self) -> np.ndarray:
+    def fused_descriptors(self, iters_per_step: int = 1) -> np.ndarray:
         """Descriptors for the fused partial+merge kernel: the (7, G*T)
-        partial-phase rows with ``num_pieces`` merge iterations appended.
+        partial-phase rows with ``num_pieces`` merge iterations appended,
+        then ``OP_PAD`` (all-zero) columns up to a multiple of
+        ``iters_per_step``, the iterations one grid step runs.
 
         Merge iteration ``p`` (grid step ``G*T + p``) reduces partial row
         ``p`` into its segment: SEG = piece_seg[p], PIECE = p, FIRST/LAST
         flag segment boundaries in the (segment-contiguous) piece order,
-        and VALID = 2 marks the merge opcode. Memoized like
-        :meth:`packed_descriptors`."""
+        and VALID = 2 marks the merge opcode. The unpadded array is
+        memoized like :meth:`packed_descriptors`."""
         desc = self.__dict__.get("_packed_fused")
         if desc is None:
             base = self.packed_descriptors()
@@ -193,7 +195,8 @@ class LeanSchedule:
             merge[6] = 2                                    # DESC_VALID: op
             desc = np.concatenate([base, merge], axis=1)
             object.__setattr__(self, "_packed_fused", desc)
-        return desc
+        pad = -desc.shape[1] % iters_per_step
+        return np.pad(desc, ((0, 0), (0, pad))) if pad else desc
 
     def piece_ranges(self) -> Tuple[np.ndarray, np.ndarray]:
         """(starts, counts): segment ``s`` owns partial rows
@@ -212,10 +215,11 @@ class LeanSchedule:
             object.__setattr__(self, "_piece_ranges", pr)
         return pr
 
-    def iter_kv_meta(self, fused: bool = False):
+    def iter_kv_meta(self, fused: bool = False, iters_per_step: int = 1):
         """Per-grid-iteration KV routing metadata for the *paged* kernels:
         ``(batch_idx, head_idx, tile_idx, is_partial)``, each ``(I,) int32``
-        with ``I = grid_iters`` (+ ``num_pieces`` merge rows when ``fused``).
+        with ``I = grid_iters`` (+ ``num_pieces`` merge rows and the padding
+        of :meth:`fused_descriptors` to ``iters_per_step`` when ``fused``).
 
         A paged execution resolves iteration ``i`` to the physical KV page
         ``page_table[batch_idx[i], tile_idx[i]]`` and kv head ``head_idx[i]``
@@ -229,9 +233,14 @@ class LeanSchedule:
         packed descriptors.
         """
         key = "_kv_meta_fused" if fused else "_kv_meta"
+        if fused and iters_per_step > 1:
+            key += f"_{iters_per_step}"
         meta = self.__dict__.get(key)
         if meta is None:
-            desc = self.fused_descriptors() if fused else self.packed_descriptors()
+            desc = (
+                self.fused_descriptors(iters_per_step) if fused
+                else self.packed_descriptors()
+            )
             seg = desc[0]
             ok = desc[6] == 1                           # OP_PARTIAL rows only
             # index S (padding sentinel) lands on the appended 0

@@ -24,9 +24,10 @@ offered:
     partial ``(o, m, l)`` goes to HBM and a second, cheap phase reduces per
     segment — XLA segment ops or the Pallas ``lean_merge`` kernel. The G
     axis stays ``parallel`` (Megacore/multi-core splittable).
-  * **fused** (``lean_decode_fused``): ONE ``pallas_call`` whose flat grid
-    appends the ``P`` merge iterations after the ``G*T`` partial iterations
-    (descriptor-driven, same scalar-prefetch machinery). Partials live in a
+  * **fused** (``lean_decode_fused``): ONE ``pallas_call`` whose flat
+    schedule appends the ``P`` merge iterations after the ``G*T`` partial
+    iterations (descriptor-driven, same scalar-prefetch machinery), ``n``
+    consecutive iterations per grid step. Partials live in a
     VMEM scratch ring — they never round-trip HBM, single-piece segments
     reduce in-register, and there is no second kernel launch. The grid is
     fully ``arbitrary`` (sequential per core), which is the right trade for
@@ -84,21 +85,22 @@ def scale_operands(k_scales, v_scales, index_map):
 
 
 def _online_softmax_tile(
-    q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
+    q, k, v, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
     k_scale=None, v_scale=None,
 ):
-    """One LeanTile online-softmax update (Algorithm 1 lines 20-25) against
-    the VMEM accumulators; ``vlen`` masks the tile's invalid tail (and the
-    whole tile when the runtime length ends before it).
+    """One LeanTile online-softmax update (Algorithm 1 lines 20-25) of the
+    query group ``q`` (gq, d) against the tile ``k``, ``v`` (tile, d), as
+    loaded, and the VMEM accumulators; ``vlen`` masks the tile's invalid
+    tail (and the whole tile when the runtime length ends before it).
 
     ``k_scale``/``v_scale`` are optional f32 dequant scalars for quantized
     (int8) KV tiles: the tile is widened to f32 and multiplied *before*
     entering the dot products, so the online-softmax accumulation — and
     therefore the merge numerics — is identical to the fp path. A scale of
     0 dequantizes to exact zeros (empty or scrubbed pages)."""
-    q = q_ref[0].astype(jnp.float32)                       # (gq, d)
-    k = k_ref[0].astype(jnp.float32)                       # (tile, d)
-    v = v_ref[0].astype(jnp.float32)
+    q = q.astype(jnp.float32)                              # (gq, d)
+    k = k.astype(jnp.float32)                              # (tile, d)
+    v = v.astype(jnp.float32)
     if k_scale is not None:
         k = k * k_scale
     if v_scale is not None:
@@ -174,7 +176,8 @@ def _lean_decode_kernel(
             tile_size,
         )
         _online_softmax_tile(
-            q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
+            q_ref[0], k_ref[0], v_ref[0], acc_ref, m_acc_ref, l_acc_ref,
+            vlen, scale,
             k_scale=None if ks_ref is None else ks_ref[0],
             v_scale=None if vs_ref is None else vs_ref[0],
         )
@@ -300,33 +303,39 @@ def lean_decode_partials(
     return o_p[:P], m_p[:P, :, 0], l_p[:P, :, 0]
 
 
-def _lean_decode_fused_kernel(
-    desc_ref,      # (7, G*T + P) scalar-prefetch descriptors (+merge rows)
-    ctx_ref,       # (S,) scalar-prefetch runtime segment lengths
-    q_ref,         # (1, gq, d)
-    k_ref,         # (1, tile, d)
-    v_ref,         # (1, tile, d)
-    *refs,         # [ks_ref (1,1,1), vs_ref (1,1,1)] when quantized, then:
-                   # o_ref (S, gq, d)  final outputs — VMEM-resident
-                   # lse_ref (S, gq, 1) final logsumexp
-                   # acc_ref   VMEM (gq, d) f32  shared partial/merge acc
-                   # m_acc_ref VMEM (gq, 1) f32
-                   # l_acc_ref VMEM (gq, 1) f32
-                   # po_ref VMEM (P+1, gq, d) f32  piece partials (VMEM only)
-                   # pm_ref VMEM (P+1, gq, 1) f32
-                   # pl_ref VMEM (P+1, gq, 1) f32
-    scale: float,
-    tile_size: int,
-    quantized: bool = False,
+# Bytes of K and V one grid step of the fused kernel moves: a step runs
+# ``n = STEP_KV_BYTES // (2 * tile * d * kv_elem_bytes)`` LeanTile
+# iterations (2 for bf16 128x128 pages, 4 for int8). On a v5e the kernel
+# took least time per page at 2 bf16 pages a step, 8-9% less than at 1;
+# from 3 on, each page costs more again while the unrolled body and its
+# compile time grow with n, hence the cap (PERF.md, section 6).
+STEP_KV_BYTES = 128 * 2**10
+MAX_ITERS_PER_STEP = 4
+
+
+def fused_iters_per_step(tile: int, d: int, kv_elem_bytes: int) -> int:
+    """LeanTile iterations per grid step of the fused kernel, from the
+    bytes of one iteration's K and V tiles (:data:`STEP_KV_BYTES`),
+    clamped to ``[1, MAX_ITERS_PER_STEP]``."""
+    n = STEP_KV_BYTES // (2 * tile * d * kv_elem_bytes)
+    return max(1, min(n, MAX_ITERS_PER_STEP))
+
+
+def fused_grid(sched: LeanSchedule, d: int, kv_elem_bytes: int):
+    """``(grid_steps, iters_per_step)`` of the fused kernel's call for
+    ``sched``: ``ceil((G*T + P) / n)`` steps of ``n`` iterations."""
+    n = fused_iters_per_step(sched.tile_size, d, kv_elem_bytes)
+    return -(-(sched.grid_iters + sched.num_pieces) // n), n
+
+
+def _fused_iteration(
+    i, desc_ref, ctx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
+    lse_ref, acc_ref, m_acc_ref, l_acc_ref, po_ref, pm_ref, pl_ref,
+    scale, tile_size,
 ):
-    if quantized:
-        (ks_ref, vs_ref, o_ref, lse_ref, acc_ref, m_acc_ref, l_acc_ref,
-         po_ref, pm_ref, pl_ref) = refs
-    else:
-        (o_ref, lse_ref, acc_ref, m_acc_ref, l_acc_ref,
-         po_ref, pm_ref, pl_ref) = refs
-        ks_ref = vs_ref = None
-    i = pl.program_id(0)
+    """Schedule iteration ``i`` of the fused kernel: a LeanTile update of
+    the accumulators (OP_PARTIAL), a piece merged into its segment
+    (OP_MERGE), or nothing (OP_PAD)."""
     op = desc_ref[DESC_VALID, i]
     seg = desc_ref[DESC_SEG, i]
     piece = desc_ref[DESC_PIECE, i]
@@ -345,7 +354,8 @@ def _lean_decode_fused_kernel(
             ctx_ref[seg] - desc_ref[DESC_TILE, i] * tile_size, 0, tile_size
         )
         _online_softmax_tile(
-            q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
+            q_ref[seg], k_ref[0], v_ref[0], acc_ref, m_acc_ref, l_acc_ref,
+            vlen, scale,
             k_scale=None if ks_ref is None else ks_ref[0],
             v_scale=None if vs_ref is None else vs_ref[0],
         )
@@ -382,20 +392,56 @@ def _lean_decode_fused_kernel(
             )[None]
 
 
+def _lean_decode_fused_kernel(
+    desc_ref,      # (7, steps * n) scalar-prefetch descriptors (+merge rows)
+    ctx_ref,       # (S,) scalar-prefetch runtime segment lengths
+    q_ref,         # (S, gq, d)  every segment's query group, VMEM-resident
+    *refs,         # n (K, V) tile pairs, each (1, tile, d); n (K, V) scale
+                   # pairs (1, 1, 1) when quantized; then:
+                   # o_ref (S, gq, d)  final outputs — VMEM-resident
+                   # lse_ref (S, gq, 1) final logsumexp
+                   # acc_ref   VMEM (gq, d) f32  shared partial/merge acc
+                   # m_acc_ref VMEM (gq, 1) f32
+                   # l_acc_ref VMEM (gq, 1) f32
+                   # po_ref VMEM (P+1, gq, d) f32  piece partials (VMEM only)
+                   # pm_ref VMEM (P+1, gq, 1) f32
+                   # pl_ref VMEM (P+1, gq, 1) f32
+    scale: float,
+    tile_size: int,
+    iters_per_step: int,
+    quantized: bool = False,
+):
+    n = iters_per_step
+    tiles, refs = refs[: 2 * n], refs[2 * n:]
+    if quantized:
+        scales, refs = refs[: 2 * n], refs[2 * n:]
+    else:
+        scales = (None,) * (2 * n)
+    for j in range(n):  # iterations s*n .. s*n + n - 1, in schedule order
+        _fused_iteration(
+            pl.program_id(0) * n + j, desc_ref, ctx_ref, q_ref,
+            *tiles[2 * j: 2 * j + 2], *scales[2 * j: 2 * j + 2], *refs,
+            scale=scale, tile_size=tile_size,
+        )
+
+
 def fused_vmem_bytes(
     sched: LeanSchedule, gq: int, d: int, kv_elem_bytes: int = 4
 ) -> int:
     """Rough VMEM footprint of the fused kernel's resident state: f32 piece
-    partials + whole-output block + a K and a V tile at the *cache dtype*
-    width (``kv_elem_bytes``: 4 f32, 2 bf16, 1 int8/fp8 — a hardcoded 4
-    here over-triggered the two-phase fallback for narrow KV). Used to
-    gate the fused path (fall back to two-phase when a schedule would blow
-    the budget)."""
+    partials + whole-output block + the resident q (at f32 width, its
+    widest) + a grid step's n K and n V tiles, double-buffered, at the
+    *cache dtype* width (``kv_elem_bytes``: 4 f32, 2 bf16, 1 int8/fp8 — a
+    hardcoded 4 here over-triggered the two-phase fallback for narrow KV).
+    Used to gate the fused path (fall back to two-phase when a schedule
+    would blow the budget)."""
     P, S = sched.num_pieces, sched.num_segments
+    n = fused_iters_per_step(sched.tile_size, d, kv_elem_bytes)
     per_row = gq * (d + 2)
     return (
-        4 * ((P + 1) * per_row + S * gq * (d + 1))
-        + kv_elem_bytes * sched.tile_size * d * 2
+        4 * ((P + 1) * per_row + S * gq * (d + 1))     # partials, o, lse
+        + 4 * S * gq * d                                # q
+        + 2 * 2 * n * kv_elem_bytes * sched.tile_size * d
     )
 
 
@@ -407,16 +453,24 @@ def lean_decode_fused(
     sched: LeanSchedule,
     scale: float,
     interpret: bool = False,
-    route: Optional[jax.Array] = None,   # paged: (G*T + P,) int32 pool rows
+    route: Optional[jax.Array] = None,   # paged: (steps * n,) int32 pool rows
     k_scales: Optional[jax.Array] = None,  # quant: (rows, 1) f32 per-row scales
     v_scales: Optional[jax.Array] = None,
+    iters_per_step: Optional[int] = None,
 ):
     """Fused stream-K decode: ONE ``pallas_call`` for partials AND merge.
 
-    The flat grid runs the ``G*T`` LeanTile iterations followed by ``P``
-    descriptor-driven merge iterations; per-piece ``(o, m, l)`` stay in a
-    VMEM scratch ring the whole time. Returns (o (S, gq, d) f32,
+    The flat schedule runs the ``G*T`` LeanTile iterations followed by
+    ``P`` descriptor-driven merge iterations; per-piece ``(o, m, l)`` stay
+    in a VMEM scratch ring the whole time. Returns (o (S, gq, d) f32,
     lse (S, gq) f32).
+
+    Each grid step runs ``n = iters_per_step`` consecutive iterations
+    (default :func:`fused_iters_per_step` of the tile's bytes) with n K and
+    n V BlockSpecs, so the pipeline fetches the next step's n pages while
+    this step computes; the schedule is padded with ``OP_PAD`` iterations
+    to ``steps * n``. Each iteration does what a one-iteration step does,
+    in the same order, so the output does not depend on ``n``.
 
     The grid is sequential (``arbitrary``) — worker parallelism trades for
     zero HBM partial traffic and a single launch, the winning trade for
@@ -424,61 +478,67 @@ def lean_decode_fused(
     path when :func:`fused_vmem_bytes` exceeds its budget.
 
     ``route`` switches K/V fetching to the paged pool-row layout (see
-    :func:`lean_decode_partials`); merge iterations carry null routes.
-    ``k_scales``/``v_scales`` (paged only) enable int8 KV with in-kernel
-    per-(page, head) dequant — merge iterations route the scales to row 0
-    along with the tiles, where they are never read.
+    :func:`lean_decode_partials`), one row per padded iteration
+    (``sched.iter_kv_meta(fused=True, iters_per_step=n)``); merge and
+    padding iterations carry null routes. ``k_scales``/``v_scales`` (paged
+    only) enable int8 KV with in-kernel per-(page, head) dequant — merge
+    iterations route the scales to row 0 along with the tiles, where they
+    are never read.
     """
     S_seg, gq, d = q_seg.shape
     tile = sched.tile_size
-    G, T = sched.num_workers, sched.tiles_per_worker
+    n = iters_per_step or fused_iters_per_step(
+        tile, d, jnp.dtype(k_seg.dtype).itemsize
+    )
     P = sched.num_pieces
-    desc = jnp.asarray(sched.fused_descriptors())
-    N = G * T + P
+    desc = jnp.asarray(sched.fused_descriptors(n))
+    steps = desc.shape[1] // n
     paged = route is not None
     quant = k_scales is not None
     if quant and not paged:
         raise ValueError("quantized KV requires the paged (route) layout")
-
-    def q_map(i, desc, *_):
-        return (
-            jnp.where(desc[DESC_VALID, i] == OP_PAD, 0, desc[DESC_SEG, i]),
-            0,
-            0,
+    if paged and route.shape != (steps * n,):
+        raise ValueError(
+            f"route has shape {route.shape}, the fused grid {steps} steps"
+            f" of {n} iterations"
         )
 
-    def kv_map_dense(i, desc, *_):
-        ok = desc[DESC_VALID, i] == OP_PARTIAL
-        return (
-            jnp.where(ok, desc[DESC_SEG, i], 0),
-            jnp.where(ok, desc[DESC_TILE, i], 0),
-            0,
-        )
+    def kv_map_dense(j):
+        def index_map(s, desc, *_):
+            i = s * n + j
+            ok = desc[DESC_VALID, i] == OP_PARTIAL
+            return (
+                jnp.where(ok, desc[DESC_SEG, i], 0),
+                jnp.where(ok, desc[DESC_TILE, i], 0),
+                0,
+            )
+        return index_map
 
-    def kv_map_paged(i, desc, ctx, route):
-        return (route[i], 0, 0)
+    def route_map(j):
+        return lambda s, desc, ctx, route: (route[s * n + j], 0, 0)
 
-    kv_map = kv_map_paged if paged else kv_map_dense
-
-    def scale_map(i, desc, ctx, route):
-        return (route[i], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, gq, d), q_map),
-        pl.BlockSpec((1, tile, d), kv_map),
-        pl.BlockSpec((1, tile, d), kv_map),
-    ]
-    scale_specs, scale_inputs = scale_operands(k_scales, v_scales, scale_map)
+    kv_map = route_map if paged else kv_map_dense
+    kv_specs, scale_specs, scale_inputs = [], [], ()
+    for j in range(n):
+        kv_specs += [pl.BlockSpec((1, tile, d), kv_map(j))] * 2
+        specs, inputs = scale_operands(k_scales, v_scales, route_map(j))
+        scale_specs += specs
+        scale_inputs += inputs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if paged else 2,
-        grid=(N,),
-        in_specs=in_specs + scale_specs,
+        grid=(steps,),
+        in_specs=[
+            # whole q: a constant index map keeps it VMEM-resident, and
+            # each iteration reads its segment's rows
+            pl.BlockSpec((S_seg, gq, d), lambda s, *_: (0, 0, 0)),
+            *kv_specs, *scale_specs,
+        ],
         out_specs=[
             # whole-output blocks: the index maps are constant, so the
             # buffers stay VMEM-resident across the grid and flush to HBM
             # exactly once at the end — no revisit hazards
-            pl.BlockSpec((S_seg, gq, d), lambda i, *_: (0, 0, 0)),
-            pl.BlockSpec((S_seg, gq, 1), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec((S_seg, gq, d), lambda s, *_: (0, 0, 0)),
+            pl.BlockSpec((S_seg, gq, 1), lambda s, *_: (0, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((gq, d), jnp.float32),
@@ -491,7 +551,7 @@ def lean_decode_fused(
     )
     kernel = functools.partial(
         _paged_fused_kernel if paged else _lean_decode_fused_kernel,
-        scale=scale, tile_size=tile, quantized=quant,
+        scale=scale, tile_size=tile, iters_per_step=n, quantized=quant,
     )
     out_shapes = [
         jax.ShapeDtypeStruct((S_seg, gq, d), jnp.float32),
@@ -509,20 +569,20 @@ def lean_decode_fused(
         ),
         name="lean_decode_fused",
         interpret=interpret,
-    )(*operands, q_seg, k_seg, v_seg, *scale_inputs)
+    )(*operands, q_seg, *(k_seg, v_seg) * n, *scale_inputs)
     return o, lse[..., 0]
 
 
 # ------------------------------------------------------------------ paged
 # Page-table execution of the same stream-K schedules: K/V arrive as a
 # global page pool flattened to (num_pages * H_kv, page_size, d) and a third
-# scalar-prefetch operand carries, per grid iteration, the flattened pool
+# scalar-prefetch operand carries, per schedule iteration, the flattened pool
 # row ``page * H_kv + head`` to fetch (built in kernels.ops from
 # ``LeanSchedule.iter_kv_meta`` + the runtime page table). The kernel BODIES
 # are the dense ones unchanged — only the K/V BlockSpec index maps differ —
 # so paged and dense execution run the identical fp op sequence and produce
-# bit-identical outputs on identical inputs. Invalid/merge iterations route
-# to row 0 (the null page), whose contents are always masked.
+# bit-identical outputs on identical inputs. Invalid, merge and padding
+# iterations route to the null page's rows, whose contents are never read.
 
 
 def _paged_partial_kernel(desc_ref, ctx_ref, route_ref, *refs, **kw):
@@ -559,19 +619,20 @@ def lean_decode_paged_fused(
     k_rows: jax.Array,         # (num_pages * H_kv, page_size, d) pool rows
     v_rows: jax.Array,
     seg_ctx: jax.Array,        # (S_seg,) int32 runtime context lengths
-    route: jax.Array,          # (G*T + P,) int32 pool row per iteration
+    route: jax.Array,          # (steps * n,) int32 pool row per iteration
     sched: LeanSchedule,
     scale: float,
     interpret: bool = False,
     k_scales: Optional[jax.Array] = None,
     v_scales: Optional[jax.Array] = None,
+    iters_per_step: Optional[int] = None,
 ):
     """Fused paged stream-K decode: :func:`lean_decode_fused` with the
     routing operand."""
     return lean_decode_fused(
         q_seg, k_rows, v_rows, seg_ctx, sched, scale,
         interpret=interpret, route=route,
-        k_scales=k_scales, v_scales=v_scales,
+        k_scales=k_scales, v_scales=v_scales, iters_per_step=iters_per_step,
     )
 
 
@@ -644,7 +705,8 @@ def _lean_cascade_fused_kernel(
             ctx_ref[seg] - desc_ref[DESC_TILE, i] * tile_size, 0, tile_size
         )
         _online_softmax_tile(
-            q_ref, k_ref, v_ref, acc_ref, m_acc_ref, l_acc_ref, vlen, scale,
+            q_ref[0], k_ref[0], v_ref[0], acc_ref, m_acc_ref, l_acc_ref,
+            vlen, scale,
             k_scale=None if ks_ref is None else ks_ref[0],
             v_scale=None if vs_ref is None else vs_ref[0],
         )

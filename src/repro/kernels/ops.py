@@ -46,6 +46,7 @@ from repro.core.leantile import (
 from repro.core.merge import AttnPartial, finalize, merge_n, segment_merge
 from .lean_decode import (
     cascade_fused_vmem_bytes,
+    fused_iters_per_step,
     fused_vmem_bytes,
     lean_cascade_fused,
     lean_decode_fused,
@@ -415,9 +416,10 @@ def lean_decode(
 
 def _paged_route(
     sched: LeanSchedule, page_tbl: jax.Array, num_kv_heads: int, fused: bool,
-    page_base=0,
+    page_base=0, iters_per_step: int = 1,
 ) -> jax.Array:
-    """Per-grid-iteration flattened pool row ``page * H_kv + head``.
+    """Per-schedule-iteration flattened pool row ``page * H_kv + head``
+    (fused: padded to ``iters_per_step``, the iterations a grid step runs).
 
     The schedule contributes static logical routing (batch, head, tile per
     iteration — :meth:`LeanSchedule.iter_kv_meta`); the runtime page table
@@ -427,7 +429,7 @@ def _paged_route(
     always masks) route to the null page's rows, page ``page_base``.
     This is the one place a page id becomes a pool row.
     """
-    batch, head, tile_idx, ok = sched.iter_kv_meta(fused=fused)
+    batch, head, tile_idx, ok = sched.iter_kv_meta(fused, iters_per_step)
     width = page_tbl.shape[1]
     pages = page_tbl[batch, np.minimum(tile_idx, width - 1)]
     pages = jnp.where(jnp.asarray(ok) == 1, pages, 0) + page_base
@@ -515,14 +517,15 @@ def _paged_decode_impl(q, k_pool, v_pool, seg_ctx, page_tbl,
         k_pool, v_pool, k_scales, v_scales
     )
 
-    fused = fused and decode_uses_fused(
-        sched, gq, d, kv_elem_bytes=jnp.dtype(k_pool.dtype).itemsize
-    )
-    route = _paged_route(sched, page_tbl, Hkv, fused, page_base)
+    elem = jnp.dtype(k_pool.dtype).itemsize
+    fused = fused and decode_uses_fused(sched, gq, d, kv_elem_bytes=elem)
+    n = fused_iters_per_step(page_size, d, elem) if fused else 1
+    route = _paged_route(sched, page_tbl, Hkv, fused, page_base, n)
     if fused:
         o_seg, lse = lean_decode_paged_fused(
             q_seg, k_rows, v_rows, seg_ctx, route, sched, scale,
             interpret=plan.interpret, k_scales=ks_rows, v_scales=vs_rows,
+            iters_per_step=n,
         )
     else:
         o_p, m_p, l_p = lean_decode_paged_partials(

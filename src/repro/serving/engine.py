@@ -71,6 +71,7 @@ from repro.core.leantile import (
 )
 from repro.core.attention import paged_gather_kv, paged_gather_kv_dequant
 from repro.kernels import flash_decode, lean_decode
+from repro.kernels.lean_decode import fused_grid
 from repro.kernels.ops import (
     cascade_tables,
     cascade_uses_fused,
@@ -789,6 +790,11 @@ class DecodeEngine:
         self._tick_dumped = False       # one injected-fault dump per tick
         self._fires_dumped = 0          # injector fires already dumped for
         self._sched_costs: dict = {}    # schedule -> roofline cost meta
+        self._grid_steps = self.metrics.gauge(
+            "engine_decode_kernel_grid_steps",
+            help="grid steps per layer of the fused decode kernel in the "
+                 "last tick (0 when another kernel ran)",
+        )
 
         # fault injection + self-healing guards. Both default OFF; with
         # neither configured every hot-path hook below is a single `is None`
@@ -1748,6 +1754,7 @@ class DecodeEngine:
         when eligible, else the fast-path kernel step, else the legacy
         per-tick step. Updates ``self.cache`` and returns the logits."""
         csched = binding = None
+        grid_steps = 0      # the fused decode kernel's, when it runs
         if self.use_fast_path and self.cascade and self.attn_backend == "lean":
             csched, binding = self._cascade_schedule_for_tick(active, ctx_np)
         # benches/diagnostics read the live per-slot suffix coverage here
@@ -1796,7 +1803,7 @@ class DecodeEngine:
             # ONE schedule build (cached) serves both the stats record and
             # the kernel step — nothing is derived twice per tick
             sched = self._tick_schedule(ctx_np)
-            self._note_schedule(sched, "fast")
+            grid = self._note_schedule(sched, "fast")
             with self.tracer.span("engine.inputs"):
                 tokens = jnp.asarray(self.next_tokens)
                 ctx = jnp.asarray(ctx_np, jnp.int32)
@@ -1818,13 +1825,12 @@ class DecodeEngine:
                     sched.num_segments, self.tile, self.num_workers,
                 )
                 if self.attn_backend == "lean":
-                    self.stats.fused_decode_ticks += int(
-                        self.fused and decode_uses_fused(
-                            sched, self.cfg.n_heads // self.cfg.n_kv_heads,
-                            self.cfg.head_dim,
-                            kv_elem_bytes=self.kv_elem_bytes,
-                        )
+                    fused = self.fused and decode_uses_fused(
+                        sched, self.cfg.n_heads // self.cfg.n_kv_heads,
+                        self.cfg.head_dim, kv_elem_bytes=self.kv_elem_bytes,
                     )
+                    self.stats.fused_decode_ticks += int(fused)
+                    grid_steps = grid["grid_steps"] if fused else 0
                 with dispatch, _quiet_donation():
                     if self.paged:
                         logits, self.cache = self._jit_kernel_step_paged(
@@ -1842,6 +1848,7 @@ class DecodeEngine:
                         )
         else:
             logits = self._tick_legacy_step(active)
+        self._grid_steps.set(grid_steps)
         return logits
 
     def _decode_pass(self, level, slots, active, exclude):
@@ -2404,25 +2411,34 @@ class DecodeEngine:
         plus trace annotation (execution path + roofline cost meta onto
         the enclosing ``decode_kernel`` span) — shared by the cascade,
         fast, and legacy decode paths, so the per-tick recording logic
-        exists once."""
-        self._record_schedule(sched)
+        exists once. Returns the fused kernel's grid for ``sched``."""
+        grid = self._record_schedule(sched)
         if self.tracer.enabled:
-            self.tracer.annotate(path=path, **self._schedule_cost(sched))
+            self.tracer.annotate(
+                path=path, **grid, **self._schedule_cost(sched)
+            )
+        return grid
 
-    def _record_schedule(self, sched: LeanSchedule):
+    def _record_schedule(self, sched: LeanSchedule) -> dict:
         # lens come from the schedule itself (one entry per batch slot), so
         # the record is internally consistent: sum(ceil(len/tile)) * Hkv ==
-        # total_tiles whether the schedule is exact (legacy) or bucketed
+        # total_tiles whether the schedule is exact (legacy) or bucketed;
+        # grid_steps / iters_per_step are the fused decode kernel's grid
+        # for this schedule. Returns those two.
+        steps, n = fused_grid(sched, self.cfg.head_dim, self.kv_elem_bytes)
+        grid = {"grid_steps": steps, "iters_per_step": n}
         self.stats.schedules.append(
             {
                 "lens": sched.seg_len[:: self.cfg.n_kv_heads].tolist(),
                 "total_tiles": sched.total_tiles,
                 "tiles_per_worker": sched.tiles_per_worker,
                 "pieces": sched.num_pieces,
+                **grid,
             }
         )
         if len(self.stats.schedules) > self.SCHEDULE_LOG_CAP:
             del self.stats.schedules[: -self.SCHEDULE_LOG_CAP]
+        return grid
 
     def _tick_legacy_step(self, active: List[int]):
         """Pre-fast-path behavior, preserved as the benchmark baseline:

@@ -1,10 +1,15 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.leantile import make_schedule
 from repro.kernels import flash_decode, flash_prefill, lean_decode
+from repro.kernels.lean_decode import lean_decode_fused
+from repro.kernels.ops import _pad_kv, _to_segments
 from repro.kernels.ref import flash_prefill_ref, lean_decode_ref
 
 jax.config.update("jax_platform_name", "cpu")
@@ -45,11 +50,24 @@ def test_lean_decode_vs_oracle(case, dtype):
     )
 
 
-@pytest.mark.parametrize("case", DECODE_CASES)
-def test_lean_decode_fused_vs_two_phase_vs_oracle(case):
-    """The single-pallas_call fused partial+merge kernel must match both
-    the two-phase path and the jnp oracle on ragged batches. The case list
-    includes the 1-segment (B=1 MQA) and pieces>workers edge cases."""
+def _dense_fused_at(n, q, k, v, lens, G, tile):
+    """The dense fused kernel with ``n`` schedule iterations a grid step."""
+    B, Hq, d = q.shape
+    q_seg, k_seg, v_seg, _ = _to_segments(q, k, v)
+    k_seg, v_seg = _pad_kv(k_seg, v_seg, tile)
+    Hkv = k.shape[1]
+    o, _ = lean_decode_fused(
+        q_seg, k_seg, v_seg, jnp.asarray(np.repeat(lens, Hkv), jnp.int32),
+        make_schedule(lens, Hkv, tile, G), 1.0 / np.sqrt(d), interpret=True,
+        iters_per_step=n,
+    )
+    return np.asarray(o.reshape(B, Hq, d).astype(q.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _checked_fused_case(case):
+    """The case's problem and its fused output, once checked against the
+    two-phase path and the jnp oracle (memoized: the case runs per n)."""
     B, Hq, Hkv, S, d, G, tile, ragged = case
     rng = np.random.default_rng(hash(case) % 2**32 + 1)
     q = mk(rng, (B, Hq, d), jnp.float32)
@@ -65,6 +83,20 @@ def test_lean_decode_fused_vs_two_phase_vs_oracle(case):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(two_phase),
                                rtol=1e-5, atol=1e-5)
+    return q, k, v, [int(x) for x in lens], np.asarray(fused)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 8])
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_lean_decode_fused_vs_two_phase_vs_oracle(case, n):
+    """The single-pallas_call fused partial+merge kernel must match both
+    the two-phase path and the jnp oracle on ragged batches. The case list
+    includes the 1-segment (B=1 MQA) and pieces>workers edge cases. At
+    ``n`` schedule iterations a grid step its output is bit-identical to
+    its default grouping's, so to every other ``n``."""
+    q, k, v, lens, fused = _checked_fused_case(case)
+    G, tile = case[5], case[6]
+    assert np.array_equal(_dense_fused_at(n, q, k, v, lens, G, tile), fused)
 
 
 def test_lean_decode_fused_single_segment_single_piece():

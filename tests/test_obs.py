@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.kernels.lean_decode import fused_iters_per_step
 from repro.models import init_params
 from repro.obs.flight import FlightRecorder, load_flight_dump
 from repro.obs.metrics import (
@@ -20,6 +21,7 @@ from repro.obs.metrics import (
 )
 from repro.obs.report import render_flight, render_report
 from repro.obs.trace import NULL_TRACER, Tracer, load_trace
+from repro.serving.config import EngineConfig, ObsConfig, PagedConfig
 from repro.serving.engine import DecodeEngine
 from repro.serving.scheduler import Scheduler, SchedulerConfig
 
@@ -362,6 +364,34 @@ def test_traced_scheduler_run_timeline_matches_lifecycle(smoke, tmp_path):
     assert "FINISHED" in out
     for h in hs:
         assert str(h.uid) in out
+
+
+def test_decode_records_the_fused_kernels_grid(smoke):
+    """The schedule record and the ``decode_kernel`` span carry the fused
+    kernel's grid, ceil((G*T + P) / n) steps of n iterations, and after a
+    tick the registry's gauge holds the steps per layer."""
+    cfg, params = smoke
+    G = 4
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=2, cache_len=64, num_workers=G, attn_backend="lean",
+        paged=PagedConfig(enabled=True, page_size=8),
+        obs=ObsConfig(tracer=Tracer()),
+    ))
+    sch = Scheduler(eng, SchedulerConfig(chunk_size=8))
+    for L in (13, 30):
+        sch.submit(np.arange(L) % cfg.vocab_size, 3)
+    sch.run_to_completion(max_steps=50)
+    rec = eng.stats.schedules[-1]
+    n = fused_iters_per_step(eng.tile, cfg.head_dim, 2)
+    assert rec["iters_per_step"] == n
+    assert rec["grid_steps"] == -(-(G * rec["tiles_per_worker"]
+                                     + rec["pieces"]) // n)
+    meta = [s for s in eng.tracer.spans if s["name"] == "decode_kernel"][-1][
+        "meta"]
+    assert (meta["grid_steps"], meta["iters_per_step"]) == (
+        rec["grid_steps"], n)
+    gauge = eng.metrics.as_dict()["engine_decode_kernel_grid_steps"]
+    assert gauge["value"] == rec["grid_steps"] > 0
 
 
 def test_untraced_engine_records_nothing(smoke):

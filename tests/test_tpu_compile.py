@@ -22,12 +22,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.leantile import (
+    ScheduleCache,
     cascade_fused_descriptors,
     make_cascade_schedule,
     make_chunk_schedule,
     make_schedule,
 )
 from repro.kernels.lean_decode import (
+    fused_grid,
     lean_cascade_fused,
     lean_decode_fused,
     lean_decode_paged_fused,
@@ -102,14 +104,53 @@ def test_dense_fused_decode(one_chip):
     )
 
 
+def _fused_routes(sched, kv_elem_bytes):
+    """Route operand of the fused kernel: one pool row per iteration of
+    its padded grid, ``steps * n``."""
+    steps, n = fused_grid(sched, D, kv_elem_bytes)
+    return ((steps * n,), jnp.int32)
+
+
 def test_paged_fused_decode(one_chip):
     _compile(
         one_chip,
         lambda q, k, v, c, r: lean_decode_paged_fused(
             q, k, v, c, r, SCHED, SCALE
         ),
-        Q, KV_ROWS, KV_ROWS, CTX_ARG,
-        ((SCHED.grid_iters + SCHED.num_pieces,), jnp.int32),
+        Q, KV_ROWS, KV_ROWS, CTX_ARG, _fused_routes(SCHED, 2),
+    )
+
+
+# The benchmark cells' decode shapes, 300 tokens into the window: their
+# mixes' shape_seed 0 context lengths, pools and widths (PERF.md section 4)
+CELLS = {
+    "yi34b-decode-fewlong": (
+        [53240, 32002, 23303, 22527], 7, 1228, 59648),
+    "nemo12b-decode-long": (
+        [10116, 3655, 1938, 1811, 16493, 21732, 9300, 13075, 7810, 23120,
+         16612, 1743, 18640, 1899, 13081, 2815], 4, 1802, 27264),
+}
+
+
+@pytest.mark.parametrize("n", [2, 8])
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_fused_decode_at_the_cells_shapes(one_chip, cell, n):
+    """The fused kernel at the cells' decode shapes (S_seg 32 at gq 7, and
+    128 at gq 4) over bf16 pools, at its default 2 iterations a grid step
+    and at 8, is under the VMEM gate and compiles."""
+    lens, gq, pages, cache_len = CELLS[cell]
+    sched = ScheduleCache().get(
+        [L + 300 for L in lens], HKV, PAGE, WORKERS, max_len=cache_len)
+    assert fused_grid(sched, D, 2)[1] == 2
+    assert decode_uses_fused(sched, gq, D, kv_elem_bytes=2)
+    seg, rows = len(lens) * HKV, ((pages * HKV, PAGE, D), jnp.bfloat16)
+    _compile(
+        one_chip,
+        lambda q, k, v, c, r: lean_decode_paged_fused(
+            q, k, v, c, r, sched, SCALE, iters_per_step=n
+        ),
+        ((seg, gq, D), jnp.bfloat16), rows, rows, ((seg,), jnp.int32),
+        ((len(sched.fused_descriptors(n)[0]),), jnp.int32),
     )
 
 
@@ -130,8 +171,7 @@ def test_int8_paged_fused_decode(one_chip):
         lambda q, k, v, c, r, ks, vs: lean_decode_paged_fused(
             q, k, v, c, r, SCHED, SCALE, k_scales=ks, v_scales=vs
         ),
-        Q, ROWS8, ROWS8, CTX_ARG,
-        ((SCHED.grid_iters + SCHED.num_pieces,), jnp.int32), SCALES, SCALES,
+        Q, ROWS8, ROWS8, CTX_ARG, _fused_routes(SCHED, 1), SCALES, SCALES,
     )
 
 
@@ -161,12 +201,15 @@ def test_paged_decode_at_the_vmem_gate(one_chip, above):
     seg = batch * HKV
     rows = ((batch * GATE_CTX // PAGE + 1) * HKV, PAGE, D)
     kernel = lean_decode_paged_partials if above else lean_decode_paged_fused
-    routes = sched.grid_iters + (0 if above else sched.num_pieces)
+    routes = (
+        ((sched.grid_iters,), jnp.int32) if above
+        else _fused_routes(sched, 2)
+    )
     _compile(
         one_chip,
         lambda q, k, v, c, r: kernel(q, k, v, c, r, sched, SCALE),
         ((seg, GQ, D), jnp.bfloat16), (rows, jnp.bfloat16),
-        (rows, jnp.bfloat16), ((seg,), jnp.int32), ((routes,), jnp.int32),
+        (rows, jnp.bfloat16), ((seg,), jnp.int32), routes,
     )
 
 
