@@ -4,9 +4,9 @@ Compares the freshly-produced benchmark artifact against the committed
 ``BENCH_baseline.json`` and fails (exit 1) when any gated metric regresses
 by more than ``--threshold`` (default 15%).
 
-Gated metrics are *intra-run ratios and counts* — speedup-vs-legacy,
-paged-vs-dense throughput ratio, cascade-vs-baseline decode speedup,
-tokens-decoded-while-prefilling — rather than absolute wall-clock
+Gated metrics are *intra-run ratios and counts* — cascade-vs-baseline
+decode speedup, tokens-decoded-while-prefilling, hardened-vs-plain and
+traced-vs-untraced throughput — rather than absolute wall-clock
 numbers, because shared CI runners make absolute timings jitter far more
 than 15% while the within-run ratios stay stable (both sides of a ratio
 see the same noisy host). A metric *missing* from the current artifact is
@@ -64,8 +64,6 @@ from benchmarks.trajectory import fingerprint_key, load_history
 
 # suite -> (json path, higher-is-better metric)
 METRICS = {
-    "decode_fast_path": ("decode_step", "speedup_vs_legacy"),
-    "paged": ("paged", "paged_over_dense_throughput"),
     "scheduler": (
         "scheduler", "chunked", "decode_tokens_while_long_prefilling",
     ),

@@ -1,11 +1,11 @@
-"""Decode fast-path benchmark: jitted+cached engine vs the legacy per-tick
-path, machine-readable across PRs.
+"""Decode-step benchmark: the engine's jitted paged decode tick,
+machine-readable across PRs.
 
 Measures, on the CPU-interpret smoke config (the CI-reproducible proxy for
-the launcher/host overhead the fast path removes):
+the engine's launcher/host overhead):
 
-  * ticks/sec of the fast path (schedule cache + whole-step jit + fused
-    kernel) vs the legacy baseline (fresh schedule + unjitted outer step),
+  * ticks/sec of the decode tick (schedule cache + whole-step jit + fused
+    kernel), with the ``ref`` backend's for context,
   * schedule-cache hit rate at steady state,
   * host-ms vs device-ms per tick (device = replaying the jitted step with
     fixed inputs; host = everything else the tick does).
@@ -23,14 +23,15 @@ import time
 from pathlib import Path
 
 
-def _mk_engine(cfg, params, backend, **kw):
-    from repro.serving.config import EngineConfig
+def _mk_engine(cfg, params, backend, *, page_size=None, kv_dtype=None,
+               tracer=None, **kw):
+    from repro.serving.config import EngineConfig, ObsConfig, PagedConfig
     from repro.serving.engine import DecodeEngine
 
-    # from_legacy maps the bench's flat knobs onto the typed nest, so every
-    # section constructs engines through the new one-argument API
-    return DecodeEngine(cfg, params, config=EngineConfig.from_legacy(
-        max_batch=4, cache_len=64, attn_backend=backend, num_workers=8, **kw,
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=4, cache_len=64, attn_backend=backend, num_workers=8,
+        paged=PagedConfig(page_size=page_size, kv_dtype=kv_dtype),
+        obs=ObsConfig(tracer=tracer), **kw,
     ))
 
 
@@ -70,14 +71,15 @@ def _device_ms_per_tick(eng, n_reps=8):
     sched = eng._tick_schedule()
     tokens = jnp.asarray(eng.next_tokens)
     ctx = jnp.asarray(eng.ctx_lens, jnp.int32)
+    ptbl = jnp.asarray(eng.page_tbl)
     num_splits = fixed_split_factor(
         int(sched.seg_len.max(initial=1)), sched.num_segments, eng.tile,
         eng.num_workers,
     )
 
     def step():
-        logits, new_cache = eng._jit_kernel_step(
-            eng.params, eng.cache, tokens, ctx,
+        logits, new_cache = eng._jit_kernel_step_paged(
+            eng.params, eng.cache, tokens, ctx, ptbl,
             backend=eng.attn_backend, sched=sched, num_splits=num_splits,
             fused=eng.fused, interpret=eng.interpret,
         )
@@ -92,9 +94,9 @@ def _device_ms_per_tick(eng, n_reps=8):
 
 
 def _attn_kv_bytes(eng) -> int:
-    """Bytes held by global-attention KV state (dense slot rows, or the
-    page pools in paged mode), including any quantization scale sidecars
-    — they are real device footprint."""
+    """Bytes held by global-attention KV state (the page pools),
+    including any quantization scale sidecars — they are real device
+    footprint."""
     total = 0
     for (pattern, reps), st_c in zip(eng.cfg.stages, eng.cache):
         for kind, lc in zip(pattern, st_c):
@@ -107,34 +109,26 @@ def _attn_kv_bytes(eng) -> int:
 
 
 def _run_paged_section(cfg, params, n_ticks: int) -> dict:
-    """Paged vs dense: throughput with the lean fused kernel, KV memory
+    """The page pool: throughput with the lean fused kernel, KV memory
     footprint, and the oversubscription headline — more in-flight slots
-    than the same token budget could hold densely."""
+    than the same token budget could hold at full context."""
     import numpy as np
 
     from repro.serving.engine import DecodeEngine, Request
 
-    # throughput + memory: identical workload, paged vs dense engine.
-    # page_size matches the dense engine's tile (64) so both walk the same
-    # schedule signatures — the comparison isolates the page-table
-    # indirection, not bucket-transition trace costs (which interpret mode
-    # inflates ~1000x vs a real accelerator; see EXPERIMENTS.md).
-    eng_dense = _mk_engine(cfg, params, "lean", use_fast_path=True, fused=True)
-    tps_dense, _ = _ticks_per_sec(eng_dense, cfg, n_ticks)
-    eng_paged = _mk_engine(
-        cfg, params, "lean", use_fast_path=True, fused=True,
-        paged=True, page_size=eng_dense.tile,
-    )
+    # throughput + memory with the default page (the engine's tile, 64)
+    eng_paged = _mk_engine(cfg, params, "lean")
     tps_paged, _ = _ticks_per_sec(eng_paged, cfg, n_ticks)
 
-    # oversubscription demo: 8 slots backed by a pool holding only the
-    # dense-4-slot token budget; lazy paging lets all 8 run concurrently
+    # oversubscription demo: 8 slots backed by a pool holding only 4
+    # slots' full-context token budget; lazy paging lets all 8 run
+    # concurrently
     ps, pps = 16, 64 // 16
     from repro.serving.config import EngineConfig, PagedConfig
 
     eng_over = DecodeEngine(cfg, params, config=EngineConfig(
         max_batch=8, cache_len=64, attn_backend="ref",
-        paged=PagedConfig(enabled=True, page_size=ps, num_pages=1 + 4 * pps),
+        paged=PagedConfig(page_size=ps, num_pages=1 + 4 * pps),
     ))
     rng = np.random.default_rng(0)
     for uid in range(8):
@@ -153,10 +147,7 @@ def _run_paged_section(cfg, params, n_ticks: int) -> dict:
     eng_over.pool.check()
 
     return {
-        "ticks_per_sec_dense": tps_dense,
         "ticks_per_sec_paged": tps_paged,
-        "paged_over_dense_throughput": tps_paged / tps_dense,
-        "attn_kv_bytes_dense": _attn_kv_bytes(eng_dense),
         "attn_kv_bytes_paged": _attn_kv_bytes(eng_paged),
         "schedule_cache_paged": eng_paged.sched_cache.stats.as_dict(),
         "pool": eng_paged.stats.kv_pool,
@@ -200,7 +191,7 @@ def _run_scheduler_section(cfg, params) -> dict:
     for mode in ("chunked", "blocking"):
         eng = DecodeEngine(cfg, params, config=EngineConfig(
             max_batch=4, cache_len=64, attn_backend="lean", num_workers=8,
-            paged=PagedConfig(enabled=True, page_size=16),
+            paged=PagedConfig(page_size=16),
         ))
         sch = Scheduler(eng, SchedulerConfig(
             chunk_size=CHUNK, prefill_pack=1, token_budget=16,
@@ -269,10 +260,7 @@ def _run_hardening_section(cfg, params, n_ticks: int) -> dict:
         if hardened:
             kw["faults"] = FaultInjector({}, enabled=False)
             kw["guards"] = GuardConfig(audit_interval=32)
-        return _mk_engine(
-            cfg, params, "lean", use_fast_path=True, fused=True,
-            paged=True, page_size=16, **kw,
-        )
+        return _mk_engine(cfg, params, "lean", page_size=16, **kw)
 
     def median_tick_s(eng, n):
         ts = []
@@ -339,10 +327,7 @@ def _run_observability_section(cfg, params, n_ticks: int,
 
     def mk(traced: bool):
         kw = {"tracer": Tracer()} if traced else {}
-        return _mk_engine(
-            cfg, params, "lean", use_fast_path=True, fused=True,
-            paged=True, page_size=16, **kw,
-        )
+        return _mk_engine(cfg, params, "lean", page_size=16, **kw)
 
     eng_plain, eng_traced = mk(False), mk(True)
     _ticks_per_sec(eng_plain, cfg, 4)
@@ -378,8 +363,7 @@ def _run_observability_section(cfg, params, n_ticks: int,
         {"nan_output": FaultSpec(rate=1.0, start=3, max_fires=1)}, seed=1
     )
     eng_f = _mk_engine(
-        cfg, params, "lean", use_fast_path=True, fused=True,
-        paged=True, page_size=16, faults=inj,
+        cfg, params, "lean", page_size=16, faults=inj,
         guards=GuardConfig(heal_after=2),
     )
     _feed(eng_f, cfg, n=3)
@@ -419,10 +403,7 @@ def _run_quant_section(cfg, params, n_ticks: int) -> dict:
     from repro.serving.engine import DecodeEngine, Request
 
     def mk(**kw):
-        return _mk_engine(
-            cfg, params, "lean", use_fast_path=True, fused=True,
-            paged=True, page_size=16, **kw,
-        )
+        return _mk_engine(cfg, params, "lean", page_size=16, **kw)
 
     eng_bf16 = mk()
     tps_bf16, _ = _ticks_per_sec(eng_bf16, cfg, n_ticks)
@@ -496,7 +477,7 @@ def _run_speculative_section(cfg, params) -> dict:
     def mk(spec=None):
         return DecodeEngine(cfg, params, config=EngineConfig(
             max_batch=4, cache_len=64, attn_backend="lean", num_workers=8,
-            paged=PagedConfig(enabled=True, page_size=8),
+            paged=PagedConfig(page_size=8),
             spec=spec if spec is not None else SpecConfig(),
         ))
 
@@ -566,26 +547,19 @@ def run_decode_step(n_ticks: int = 24, out_path: str = "BENCH_decode_step.json",
         "fingerprint": fingerprint, "run_id": run_id,
     }}
 
-    # fast path (lean fused) — also collect host/device split
-    eng_fast = _mk_engine(cfg, params, "lean", use_fast_path=True, fused=True)
+    # lean fused decode tick — also collect host/device split
+    eng_fast = _mk_engine(cfg, params, "lean")
     tps_fast, s_per_tick = _ticks_per_sec(eng_fast, cfg, n_ticks)
     dev_ms = _device_ms_per_tick(eng_fast)
     cache_stats = eng_fast.sched_cache.stats.as_dict()
 
-    # legacy baseline (pre-PR behavior: per-tick schedule, unjitted step)
-    eng_legacy = _mk_engine(cfg, params, "lean", use_fast_path=False)
-    n_legacy = max(4, n_ticks // 4)          # it is slow; sample fewer ticks
-    tps_legacy, _ = _ticks_per_sec(eng_legacy, cfg, n_legacy, warmup=1)
-
-    # ref backend fast path for context (jnp attention, always jitted)
-    eng_ref = _mk_engine(cfg, params, "ref", use_fast_path=True)
+    # ref backend for context (jnp attention, same jitted tick)
+    eng_ref = _mk_engine(cfg, params, "ref")
     tps_ref, _ = _ticks_per_sec(eng_ref, cfg, n_ticks)
 
     result["decode_step"] = {
         "ticks_per_sec_fast": tps_fast,
-        "ticks_per_sec_legacy": tps_legacy,
         "ticks_per_sec_ref_backend": tps_ref,
-        "speedup_vs_legacy": tps_fast / tps_legacy,
         "ms_per_tick_fast": s_per_tick * 1e3,
         "device_ms_per_tick": dev_ms,
         "host_ms_per_tick": max(0.0, s_per_tick * 1e3 - dev_ms),
@@ -604,7 +578,6 @@ def run_decode_step(n_ticks: int = 24, out_path: str = "BENCH_decode_step.json",
         append_history(
             {
                 "ticks_per_sec_fast": tps_fast,
-                "ticks_per_sec_legacy": tps_legacy,
                 "ms_per_tick_fast": s_per_tick * 1e3,
                 "spec_speedup_k4": result["speculative"]["spec_speedup_k4"],
             },
@@ -618,11 +591,9 @@ def run_decode_step(n_ticks: int = 24, out_path: str = "BENCH_decode_step.json",
         p = result["paged"]
         s = result["scheduler"]
         rows.append(("decode_step_fast_us_per_tick",
-                     d["ms_per_tick_fast"] * 1e3, d["speedup_vs_legacy"]))
+                     d["ms_per_tick_fast"] * 1e3, d["ticks_per_sec_fast"]))
         rows.append(("decode_step_cache_hit_rate", 0.0,
                      cache_stats["hit_rate"]))
-        rows.append(("decode_step_paged_over_dense", 0.0,
-                     p["paged_over_dense_throughput"]))
         rows.append(("decode_step_paged_max_concurrent", 0.0,
                      float(p["oversubscription"]["max_concurrent_slots"])))
         rows.append(("sched_decode_toks_during_long_prefill", 0.0,
@@ -667,19 +638,17 @@ def main():
     print(json.dumps(result, indent=1))
     p = result["paged"]
     print(
-        f"\nfast {d['ticks_per_sec_fast']:.2f} ticks/s vs legacy "
-        f"{d['ticks_per_sec_legacy']:.2f} ticks/s "
-        f"({d['speedup_vs_legacy']:.1f}x); cache hit rate "
+        f"\nlean {d['ticks_per_sec_fast']:.2f} ticks/s vs ref backend "
+        f"{d['ticks_per_sec_ref_backend']:.2f} ticks/s; cache hit rate "
         f"{d['schedule_cache']['hit_rate']:.2f}; "
         f"host {d['host_ms_per_tick']:.1f}ms + device "
         f"{d['device_ms_per_tick']:.1f}ms per tick"
     )
     o = p["oversubscription"]
     print(
-        f"paged {p['ticks_per_sec_paged']:.2f} ticks/s "
-        f"({p['paged_over_dense_throughput']:.2f}x dense); "
+        f"paged {p['ticks_per_sec_paged']:.2f} ticks/s; "
         f"oversub: {o['max_concurrent_slots']}/{o['slots']} slots live on a "
-        f"{o['dense_equivalent_slots']}-slot dense budget "
+        f"{o['dense_equivalent_slots']}-slot full-context budget "
         f"({o['preemptions']} preemptions)"
     )
     s = result["scheduler"]

@@ -43,17 +43,19 @@ TAIL = 16          # private tail length: keeps the whole measured window
 CHAIN_PAGES = 5    # mixed-depth scenario: one cached chain of 5 pages
 
 
-def _build(cfg, params, *, prefix_cache, cascade, **ekw):
+def _build(cfg, params, *, prefix_cache, cascade, **cascade_kw):
+    from repro.serving.config import (
+        CascadeConfig,
+        EngineConfig,
+        PagedConfig,
+    )
     from repro.serving.engine import DecodeEngine
     from repro.serving.scheduler import Scheduler, SchedulerConfig
 
-    from repro.serving.config import EngineConfig
-
-    eng = DecodeEngine(cfg, params, config=EngineConfig.from_legacy(
-        max_batch=8, cache_len=192, attn_backend="lean",
-        num_workers=8, paged=True, page_size=PAGE,
-        prefix_cache=prefix_cache, cascade=cascade,
-        **({"cascade_stable_ticks": 1} if cascade else {}), **ekw,
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=8, cache_len=192, attn_backend="lean", num_workers=8,
+        paged=PagedConfig(page_size=PAGE, prefix_cache=prefix_cache),
+        cascade=CascadeConfig(enabled=cascade, stable_ticks=1, **cascade_kw),
     ))
     sched = Scheduler(eng, SchedulerConfig(
         chunk_size=32, prefill_pack=4, token_budget=256,
@@ -154,7 +156,7 @@ def _run_mixed_mode(cfg, params, prompts, chain, *, grouping, fused,
     import numpy as np
 
     eng, sched = _build(cfg, params, prefix_cache=True, cascade=True,
-                        cascade_grouping=grouping, cascade_fused=fused)
+                        grouping=grouping, fused=fused)
     donor = sched.submit(np.concatenate([chain, [1]]), 1)  # noqa: F841
     sched.run_to_completion(max_steps=100)
     handles = [sched.submit(p, max_new_tokens=10_000) for p in prompts]

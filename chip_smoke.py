@@ -71,7 +71,7 @@ def make_server(cfg, params, batch):
     are read back once per run."""
     eng = DecodeEngine(cfg, params, config=EngineConfig(
         max_batch=batch, cache_len=CACHE_LEN, attn_backend="lean",
-        paged=PagedConfig(enabled=True, page_size=PAGE),
+        paged=PagedConfig(page_size=PAGE),
     ))
     eng.screens = []
     decode_pass = eng._decode_pass

@@ -23,7 +23,7 @@ rng = np.random.default_rng(0)
 
 eng = DecodeEngine(cfg, params, config=EngineConfig(
     max_batch=4, cache_len=128, attn_backend="lean", num_workers=8,
-    paged=PagedConfig(enabled=True, page_size=16),
+    paged=PagedConfig(page_size=16),
 ))
 sch = Scheduler(eng, SchedulerConfig(
     chunk_size=16, prefill_pack=2, token_budget=32, policy="priority",

@@ -28,7 +28,7 @@ streams = {}
 for backend in ("ref", "lean", "fixed"):
     eng = DecodeEngine(cfg, params, config=EngineConfig(
         max_batch=3, cache_len=96, attn_backend=backend, num_workers=8,
-        paged=PagedConfig(enabled=True, page_size=16),
+        paged=PagedConfig(page_size=16),
     ))
     sch = Scheduler(eng, SchedulerConfig(
         chunk_size=8, prefill_pack=2, token_budget=16, policy="fcfs",
@@ -67,7 +67,7 @@ print("\nall backends token-identical; streaming callbacks matched handles")
 # in a single tick — output stays token-identical to plain greedy decode
 eng = DecodeEngine(cfg, params, config=EngineConfig(
     max_batch=3, cache_len=96, attn_backend="lean", num_workers=8,
-    paged=PagedConfig(enabled=True, page_size=16),
+    paged=PagedConfig(page_size=16),
     spec=SpecConfig(enabled=True, k=4),
 ))
 sch = Scheduler(eng, SchedulerConfig(

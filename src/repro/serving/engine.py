@@ -5,7 +5,7 @@ as slots free up (Orca-style continuous batching), and runs one fused decode
 step per tick. Context lengths are *heterogeneous* — exactly the ragged
 regime of paper §IV-C/Fig. 6.
 
-Decode fast-path (default, ``use_fast_path=True``):
+Decode tick:
 
   * the stream-K schedule comes from a :class:`ScheduleCache` — ragged
     lengths bucket to canonical shapes, so a steady-state tick performs
@@ -13,33 +13,27 @@ Decode fast-path (default, ``use_fast_path=True``):
     identical tick-to-tick;
   * the whole decode step (embed -> layers -> kernel attention -> logits ->
     cache update) runs under ONE ``jax.jit`` keyed on the schedule
-    signature, with the KV cache donated — the lean/fixed kernels no
-    longer fall off the jit cliff;
-  * request admission writes a single slot of the cache tree via
-    ``dynamic_update_slice`` under a donating jit instead of re-building
-    the full tree with ``.at[:, slot].set``;
+    signature, with the KV cache donated;
+  * request admission writes the prompt's pages and the slot's per-slot
+    rows under a donating jit, leaving the rest of the cache in place;
   * per-tick sampling does one device->host argmax sync for the whole
     batch, not one per slot.
 
-``use_fast_path=False`` preserves the original per-tick behavior (fresh
-schedule each tick, unjitted outer step for kernel backends, full-tree admit
-copy) as the benchmark baseline — ``benchmarks/decode_step_bench.py``
-measures one against the other.
-
 Attention backends:
-  * 'lean'   — the Pallas stream-K kernel (interpret=True on CPU); the
-               fast path uses the fused single-``pallas_call`` kernel,
+  * 'lean'   — the Pallas stream-K kernel (interpret=True on CPU), fused
+               into a single ``pallas_call`` when its VMEM budget allows,
   * 'fixed'  — the FlashDecoding fixed-split baseline kernel,
   * 'ref'    — pure-jnp oracle (fast under jit on CPU).
 
 All backends compute exact attention; the schedule is what differs.
 
-Paged KV mode (``paged=True``, fast path only): global-attention KV lives
-in a page pool ``(num_pages, H_kv, page_size, d)`` managed by
-:class:`repro.serving.kvpool.KVPagePool` instead of dense per-slot rows.
-Admission allocates only the pages the prompt needs (copy-on-admit scatter),
-decode grows sequences page-by-page, and finishing a request returns its
-pages immediately — slot capacity decouples from worst-case context, so an
+The KV cache is a page pool: global-attention KV lives in pools
+``(num_pages, H_kv, page_size, d)`` managed by
+:class:`repro.serving.kvpool.KVPagePool`; window rings, recurrent state and
+cross-attention state stay per slot in the same cache tree. Admission
+allocates only the pages the prompt needs (copy-on-admit scatter), decode
+grows sequences page-by-page, and finishing a request returns its pages
+immediately — slot capacity decouples from worst-case context, so an
 undersized pool (``num_pages``) oversubscribes slots and preempts (evict +
 recompute-resume) only when the pool actually fills. The 'lean' backend
 fetches KV tiles *through the page table* natively (tile == page);
@@ -66,11 +60,9 @@ from repro.core.leantile import (
     default_tile_size,
     fixed_split_factor,
     make_chunk_schedule,
-    make_schedule,
     make_spec_schedule,
 )
 from repro.core.attention import paged_gather_kv, paged_gather_kv_dequant
-from repro.kernels import flash_decode, lean_decode
 from repro.kernels.lean_decode import fused_grid
 from repro.kernels.ops import (
     cascade_tables,
@@ -79,14 +71,12 @@ from repro.kernels.ops import (
     flash_decode_from_lens,
     flash_prefill_paged,
     lean_decode_cascade_from_schedule,
-    lean_decode_from_schedule,
     lean_decode_paged_from_schedule,
     lean_prefill_chunks,
 )
 from repro.models import (
     ModelConfig,
     decode_step,
-    init_cache,
     init_paged_cache,
     prefill,
     prefill_chunks,
@@ -94,18 +84,17 @@ from repro.models import (
 )
 from repro.models import supports_chunked_prefill as _cfg_supports_chunked
 from repro.serving.config import EngineConfig
-from repro.serving.faults import FaultInjector, corrupt_trie_node
+from repro.serving.faults import corrupt_trie_node
 from repro.serving.speculative import NGramProposer
 from repro.serving.guards import (
     DEGRADE_CAUSES,
     DEGRADE_LEVELS,
     FatalInvariantError,
-    GuardConfig,
     PoisonError,
 )
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER
 from repro.roofline.analysis import schedule_decode_cost
 from repro.serving.kvpool import KVLayout, KVPagePool
 from repro.serving.prefix_cache import RadixPrefixCache, lcp_group_passes
@@ -360,7 +349,9 @@ def _kernel_decode_step_paged(
     fused: bool,
     interpret: bool,
 ):
-    """Paged twin of ``_kernel_decode_step``: the page table rides along as
+    """One whole decode step with kernel-backed attention — pure in the
+    array args; everything else is hashable and static, so the engine jits
+    this end-to-end per schedule signature. The page table rides along as
     a runtime array (no retrace when sequences migrate across pages); the
     lean backend fetches tiles through it natively, the fixed-split
     baseline gathers to dense first. Each layer's call passes the page
@@ -526,42 +517,6 @@ def _screen_logits(logits):
     )
 
 
-def _kernel_decode_step(
-    params,
-    cache,
-    tokens,
-    ctx_lens,
-    *,
-    cfg: ModelConfig,
-    backend: str,
-    sched: LeanSchedule,
-    num_splits: int,
-    fused: bool,
-    interpret: bool,
-):
-    """One whole decode step with kernel-backed attention — pure in the
-    array args; everything else is hashable and static, so the engine jits
-    this end-to-end per schedule signature."""
-
-    def attn_fn(q, k, v, ctx):
-        # ctx: per-slot visible lengths (already includes the token written
-        # this step, clamped to cache capacity) — runtime values
-        seg_ctx = jnp.repeat(ctx.astype(jnp.int32), cfg.n_kv_heads)
-        if backend == "lean":
-            return lean_decode_from_schedule(
-                q, k, v, seg_ctx, sched, fused=fused, interpret=interpret
-            )
-        return flash_decode_from_lens(
-            q, k, v, seg_ctx,
-            num_splits=num_splits, tile=sched.tile_size, interpret=interpret,
-        )
-
-    cur = jnp.max(ctx_lens)
-    return decode_step(
-        params, cfg, cache, tokens, cur, attn_fn=attn_fn, ctx_lens=ctx_lens
-    )
-
-
 def _chunk_attn_fn(offs, lens, *, cfg, backend, sched, interpret):
     """The multi-q-row paged attention closure shared by chunked prefill
     and the speculative verify step (``None`` selects the gather + jnp
@@ -675,27 +630,10 @@ class DecodeEngine:
         cfg: ModelConfig,
         params,
         config: Optional[EngineConfig] = None,
-        **legacy,
     ):
         """``config`` (an :class:`repro.serving.config.EngineConfig`) is
-        the one configuration argument. The legacy loose-keyword surface
-        (``paged=True, cascade_fused=..., tracer=...``) still works for one
-        release: it maps through :meth:`EngineConfig.from_legacy` and emits
-        a single :class:`DeprecationWarning` per construction."""
-        if legacy:
-            if config is not None:
-                raise TypeError(
-                    "pass either config=EngineConfig(...) or the legacy "
-                    "keyword arguments, not both"
-                )
-            warnings.warn(
-                "DecodeEngine(**loose_kwargs) is deprecated; pass "
-                "config=EngineConfig(...) (see repro.serving.config)",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-            config = EngineConfig.from_legacy(**legacy)
-        elif config is None:
+        the one configuration argument; ``None`` means ``EngineConfig()``."""
+        if config is None:
             config = EngineConfig()
         self.config = config
         # unpack the nest into the names the body below grew up with
@@ -703,11 +641,9 @@ class DecodeEngine:
         cache_len = config.cache_len
         attn_backend = config.attn_backend
         num_workers = config.num_workers
-        use_fast_path = config.use_fast_path
         fused = config.fused
         interpret = config.interpret
         schedule_cache_entries = config.schedule_cache_entries
-        paged = config.paged.enabled
         page_size = config.paged.page_size
         num_pages = config.paged.num_pages
         prefix_cache = config.paged.prefix_cache
@@ -725,29 +661,22 @@ class DecodeEngine:
         flight_dir = config.obs.flight_dir
         watchdog = config.obs.watchdog
         # ``kv_dtype`` overrides the model config's KV storage dtype for
-        # this engine — 'int8' turns on quantized paged pools (per-(page,
-        # head) f32 scales, in-kernel dequant) for 2-4x effective capacity
+        # this engine — 'int8' turns on quantized pools (per-(page, head)
+        # f32 scales, in-kernel dequant) for 2-4x effective capacity
         if kv_dtype is not None and kv_dtype != cfg.kv_cache_dtype:
             import dataclasses
 
             cfg = dataclasses.replace(cfg, kv_cache_dtype=kv_dtype)
-        self.quant = paged and cfg.kv_cache_dtype == "int8"
+        self.quant = cfg.kv_cache_dtype == "int8"
         # KV cache element width: int8/f8 caches are 1 byte, all else bf16
         self.kv_elem_bytes = 1 if cfg.kv_cache_dtype in ("int8", "f8") else 2
-        if cfg.kv_cache_dtype == "int8" and not paged:
-            raise ValueError(
-                "kv_dtype='int8' quantizes the paged pools — requires "
-                "paged=True (dense caches stay fp)"
-            )
         self.cfg = cfg
         self.params = params
         self.max_batch = max_batch
         self.cache_len = cache_len
         self.attn_backend = attn_backend
         self.num_workers = num_workers
-        self.use_fast_path = use_fast_path
         self.fused = fused
-        self.paged = paged
         self.cascade = cascade
         # cascade v2 policy knobs: fused single-kernel execution (VMEM
         # budget still gates per schedule), trie-path grouping mode
@@ -805,13 +734,6 @@ class DecodeEngine:
             # every injected fire logs a fault_fire event, so postmortem
             # dumps always name the injected point in their tail
             faults.recorder = self.flight
-        if guards is not None and not paged:
-            raise ValueError(
-                "guards (self-healing) require paged=True: quarantining a "
-                "slot masks it via null page-table rows, and poison "
-                "recovery is recompute-resume preemption — both are paged "
-                "mechanisms"
-            )
         self.guard_cfg = guards
         # per-slot position on the degraded-mode fallback chain
         # (see guards.DEGRADE_LEVELS) + consecutive bad/good tick runs
@@ -842,65 +764,54 @@ class DecodeEngine:
             )
 
         # tile is fixed per engine (schedule/jit key stability); the cache
-        # capacity bounds every slot's visible context. Paged mode: lean
-        # tiles map 1:1 onto KV pages, so page_size overrides the tile.
-        if paged and page_size is not None:
+        # capacity bounds every slot's visible context. Lean tiles map 1:1
+        # onto KV pages, so page_size overrides the tile.
+        if page_size is not None:
             self.tile = int(page_size)
         else:
             self.tile = min(default_tile_size(cfg.head_dim), max(8, cache_len))
         self.pages_per_slot = -(-cache_len // self.tile)
 
-        if paged:
-            if not use_fast_path:
-                raise ValueError(
-                    "paged KV requires the fast path (use_fast_path=True)"
-                )
-            # default pool = dense-equivalent token capacity (+ null page);
-            # pass a smaller num_pages to oversubscribe slots vs memory
-            if num_pages is None:
-                num_pages = 1 + max_batch * self.pages_per_slot
-            n_attn = sum(
-                reps for pattern, reps in cfg.stages
-                for kind in pattern if kind == "attn"
-            )
-            layout = KVLayout(
-                kv_dtype=cfg.kv_cache_dtype,
-                n_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.head_dim,
-                page_size=self.tile,
-                n_attn_layers=n_attn,
-                scale_granularity=cfg.kv_scale_granularity,
-            )
-            self.pool = KVPagePool(num_pages, self.tile, layout=layout)
-            self.pool.register_metrics(self.metrics)
-            self.page_tbl = np.zeros(
-                (max_batch, self.pages_per_slot), dtype=np.int32
-            )
-            # the span holds the pools' allocation and fill, not only their
-            # dispatch
-            with self.tracer.span("engine.init_cache"):
-                self.cache = jax.block_until_ready(init_paged_cache(
-                    cfg, max_batch, cache_len, num_pages, self.tile
-                ))
-            self.metrics.gauge(
-                "engine_kv_pool_bytes",
-                help="bytes of every attention layer's page pools together",
-            ).set(sum(
-                leaf.nbytes
-                for (pattern, _), stage_c in zip(cfg.stages, self.cache)
-                for kind, lc in zip(pattern, stage_c) if kind == "attn"
-                for leaf in lc.values()
+        # default pool = one slot's worth of pages for every slot (+ null
+        # page); pass a smaller num_pages to oversubscribe slots vs memory
+        if num_pages is None:
+            num_pages = 1 + max_batch * self.pages_per_slot
+        n_attn = sum(
+            reps for pattern, reps in cfg.stages
+            for kind in pattern if kind == "attn"
+        )
+        layout = KVLayout(
+            kv_dtype=cfg.kv_cache_dtype,
+            n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.head_dim,
+            page_size=self.tile,
+            n_attn_layers=n_attn,
+            scale_granularity=cfg.kv_scale_granularity,
+        )
+        self.pool = KVPagePool(num_pages, self.tile, layout=layout)
+        self.pool.register_metrics(self.metrics)
+        self.page_tbl = np.zeros(
+            (max_batch, self.pages_per_slot), dtype=np.int32
+        )
+        # the span holds the pools' allocation and fill, not only their
+        # dispatch
+        with self.tracer.span("engine.init_cache"):
+            self.cache = jax.block_until_ready(init_paged_cache(
+                cfg, max_batch, cache_len, num_pages, self.tile
             ))
-            self.metrics.gauge(
-                "engine_kv_pages_total", help="pages in each layer's pool"
-            ).set(num_pages)
-        else:
-            self.pool = None
-            self.page_tbl = None
-            self.cache = init_cache(cfg, max_batch, cache_len)
+        self.metrics.gauge(
+            "engine_kv_pool_bytes",
+            help="bytes of every attention layer's page pools together",
+        ).set(sum(
+            leaf.nbytes
+            for (pattern, _), stage_c in zip(cfg.stages, self.cache)
+            for kind, lc in zip(pattern, stage_c) if kind == "attn"
+            for leaf in lc.values()
+        ))
+        self.metrics.gauge(
+            "engine_kv_pages_total", help="pages in each layer's pool"
+        ).set(num_pages)
 
-        if prefix_cache and not paged:
-            raise ValueError("prefix_cache=True requires paged=True")
         if cascade:
             if not prefix_cache:
                 raise ValueError("cascade=True requires prefix_cache=True")
@@ -947,7 +858,6 @@ class DecodeEngine:
         # instead of the engine-local one
         self.preempt_sink = None
 
-        self._jit_decode = jax.jit(self._decode_fn)
         self._jit_decode_paged = jax.jit(self._decode_fn_paged)
         self._jit_prefill_slot = jax.jit(
             self._prefill_fn, static_argnames=("plen",)
@@ -958,16 +868,9 @@ class DecodeEngine:
             static_argnames=("backend", "sched", "interpret"),
             donate_argnames=("cache",),
         )
-        self._jit_admit = jax.jit(_write_slot, donate_argnums=(0,))
         self._jit_admit_paged = jax.jit(
             _bind(_write_slot_paged, cfg=cfg, page_size=self.tile),
             donate_argnums=(0,),
-        )
-        self._jit_kernel_step = jax.jit(
-            _bind(_kernel_decode_step, cfg=cfg),
-            static_argnames=("backend", "sched", "num_splits", "fused",
-                             "interpret"),
-            donate_argnames=("cache",),
         )
         self._jit_kernel_step_paged = jax.jit(
             _bind(_kernel_decode_step_paged, cfg=cfg),
@@ -989,9 +892,9 @@ class DecodeEngine:
         self._jit_screen = jax.jit(_screen_logits)
 
         # speculative (draft-verify) decode: one verify sweep scores k
-        # drafts per slot. Requires the chunked-prefill machinery (paged
-        # pool + all-pooled-KV architecture) — the verify step IS a chunk
-        # step whose "chunk" is [last token, k drafts].
+        # drafts per slot. Requires the chunked-prefill machinery (an
+        # all-pooled-KV architecture) — the verify step IS a chunk step
+        # whose "chunk" is [last token, k drafts].
         spec = config.spec
         self.spec_k = int(spec.k) if spec.enabled else 0
         self.proposer = spec.proposer
@@ -1002,7 +905,7 @@ class DecodeEngine:
                 raise ValueError(
                     "speculative decode runs the multi-row verify step "
                     "through the chunked-prefill kernels — requires "
-                    "paged=True and an all-'attn' architecture "
+                    "an all-'attn' architecture "
                     "(see supports_chunked_prefill)"
                 )
             if self.proposer is None:
@@ -1039,38 +942,10 @@ class DecodeEngine:
                 sp.annotate(**sched.work_summary())
         return sched
 
-    # ------------------------------------------------------------- attn fn
-    def _make_attn_fn(self):
-        """Legacy (non-jit-stable) kernel closure, kept as the benchmark
-        baseline: host lengths are baked into the trace every tick."""
-        backend = self.attn_backend
-        if backend == "ref":
-            return None
-        ctx = [int(c) + 1 for c in self.ctx_lens]  # +1: token being written
-
-        def attn_fn(q, k, v, ctx_arr):
-            # host-known ragged lengths drive the schedule; clamp to cache
-            lens = [min(c, k.shape[2]) for c in ctx]
-            if backend == "lean":
-                return lean_decode(
-                    q, k, v, lens, num_workers=self.num_workers,
-                    interpret=self.interpret,
-                )
-            return flash_decode(q, k, v, lens, interpret=self.interpret)
-
-        return attn_fn
-
     # ------------------------------------------------------------- jit fns
-    def _decode_fn(self, params, cache, tokens, ctx_lens):
+    def _decode_fn_paged(self, params, cache, tokens, ctx_lens, page_tbl):
         # ragged decode: per-slot context lengths drive RoPE positions,
         # cache write offsets, and attention masks
-        cur = jnp.max(ctx_lens)
-        logits, new_cache = decode_step(
-            params, self.cfg, cache, tokens, cur, ctx_lens=ctx_lens
-        )
-        return logits, new_cache
-
-    def _decode_fn_paged(self, params, cache, tokens, ctx_lens, page_tbl):
         cur = jnp.max(ctx_lens)
         logits, new_cache = decode_step(
             params, self.cfg, cache, tokens, cur, ctx_lens=ctx_lens,
@@ -1237,7 +1112,7 @@ class DecodeEngine:
     def admit_blocking(self, req: Request, slot: int) -> bool:
         """Classic admission: whole-prompt prefill into ``slot``, cache row
         written, first token sampled. Returns False (engine unchanged) when
-        the paged pool cannot currently hold the prompt. Does NOT touch the
+        the pool cannot currently hold the prompt. Does NOT touch the
         engine queue — callers (``_admit`` or a Scheduler) own queueing."""
         with self.tracer.span(
             "admit", uid=req.uid, prompt_tokens=len(req.prompt)
@@ -1246,33 +1121,23 @@ class DecodeEngine:
 
     def _admit_blocking_inner(self, req: Request, slot: int) -> bool:
         plen = len(req.prompt)
-        pages = None
-        if self.paged:
-            self._check_fits_pool(req)
-            # pages allocate lazily: admission takes only what the
-            # prompt needs, decode grows page-by-page
-            n = max(1, -(-plen // self.tile))
-            pages = self._pool_alloc(slot, n)
-            if pages is None:
-                return False            # pool exhausted; retry next tick
-            self.page_tbl[slot, :n] = pages
+        self._check_fits_pool(req)
+        # pages allocate lazily: admission takes only what the prompt
+        # needs, decode grows page-by-page
+        n = max(1, -(-plen // self.tile))
+        pages = self._pool_alloc(slot, n)
+        if pages is None:
+            return False                # pool exhausted; retry next tick
+        self.page_tbl[slot, :n] = pages
         self.slot_req[slot] = req
         logits, cache1 = self._run_prompt_prefill(req.prompt)
-        # copy slot-0 of the fresh cache into our slot
-        if self.paged:
-            with _quiet_donation():
-                self.cache = self._jit_admit_paged(
-                    self.cache, cache1,
-                    jnp.asarray(pages, jnp.int32),
-                    jnp.asarray(slot, jnp.int32),
-                )
-        elif self.use_fast_path:
-            with _quiet_donation():
-                self.cache = self._jit_admit(
-                    self.cache, cache1, jnp.asarray(slot, jnp.int32)
-                )
-        else:
-            self.cache = _copy_slot(self.cache, cache1, slot)
+        # copy slot-0 of the fresh cache into our pages and slot rows
+        with _quiet_donation():
+            self.cache = self._jit_admit_paged(
+                self.cache, cache1,
+                jnp.asarray(pages, jnp.int32),
+                jnp.asarray(slot, jnp.int32),
+            )
         self.ctx_lens[slot] = plen
         nxt = int(jnp.argmax(logits[0]))
         req.generated.append(nxt)
@@ -1289,25 +1154,23 @@ class DecodeEngine:
 
     # --------------------------------------------------------- chunked prefill
     def supports_chunked_prefill(self) -> bool:
-        """Chunked prefill streams prompt pieces straight into the paged
-        pool — it needs paged mode (the pool + page tables ARE the staging
-        area) and an architecture whose whole prompt state lives in pooled
+        """Chunked prefill streams prompt pieces straight into the page
+        pool (the pool + page tables ARE the staging area) — it needs an
+        architecture whose whole prompt state lives in pooled
         global-attention KV."""
-        return self.paged and _cfg_supports_chunked(self.cfg)
+        return _cfg_supports_chunked(self.cfg)
 
     def claim_slot(self, req: Request) -> Optional[int]:
         """Reserve a free slot for ``req`` without prefilling anything —
         the entry point of the PREFILLING lifecycle state. The slot starts
         at context 0 with an all-null page table row; chunk pages allocate
         lazily per chunk (:meth:`ensure_chunk_pages`)."""
-        if self.paged:
-            self._check_fits_pool(req)
+        self._check_fits_pool(req)
         for slot in range(self.max_batch):
             if self.slot_req[slot] is None:
                 self.slot_req[slot] = req
                 self.ctx_lens[slot] = 0
-                if self.paged:
-                    self.page_tbl[slot, :] = 0
+                self.page_tbl[slot, :] = 0
                 self._slot_shared_tiles[slot] = set()
                 self._slot_prefix_full[slot] = 0
                 return slot
@@ -1355,8 +1218,8 @@ class DecodeEngine:
         """
         if not self.supports_chunked_prefill():
             raise RuntimeError(
-                "chunked prefill requires paged=True and an all-'attn' "
-                "architecture (see supports_chunked_prefill)"
+                "chunked prefill requires an all-'attn' architecture "
+                "(see supports_chunked_prefill)"
             )
         if len(work) > pack_width:
             raise ValueError(f"{len(work)} chunks > pack width {pack_width}")
@@ -1413,11 +1276,11 @@ class DecodeEngine:
         self._log_tick_tokens(self.stats.tick_prefill_tokens, n_tokens)
         return next_tok
 
-    # ------------------------------------------------------------ paged mgmt
+    # ------------------------------------------------------------ page mgmt
     def _ensure_decode_pages(self, active: List[int]) -> List[int]:
         """Grow each active slot's page list to cover this tick's KV write.
         A slot the pool cannot serve is preempted (pages freed, request
-        requeued for recompute-resume) — the paged analogue of running out
+        requeued for recompute-resume) — the pool's analogue of running out
         of batch slots, except it only triggers when the pool is
         oversubscribed."""
         alive = []
@@ -1477,8 +1340,6 @@ class DecodeEngine:
         breaking): works for both DECODING and PREFILLING occupants —
         a mid-prefill request simply restarts its prompt on re-admission
         (its ``generated`` list is still empty)."""
-        if not self.paged:
-            raise RuntimeError("preemption only applies to paged engines")
         if self.slot_req[slot] is None:
             raise ValueError(f"slot {slot} is idle")
         self._preempt(slot)
@@ -1522,12 +1383,11 @@ class DecodeEngine:
         self._reset_guard(slot)
 
     def _free_slot_pages(self, slot: int):
-        if self.paged:
-            if self.pool.holds(slot):
-                self.pool.free_seq(slot)
-            self.page_tbl[slot, :] = 0
-            self._slot_shared_tiles[slot] = set()
-            self._slot_prefix_full[slot] = 0
+        if self.pool.holds(slot):
+            self.pool.free_seq(slot)
+        self.page_tbl[slot, :] = 0
+        self._slot_shared_tiles[slot] = set()
+        self._slot_prefix_full[slot] = 0
 
     def _cascade_grouping(self, active: List[int]):
         """Grouped cascade passes for this tick: the radix page *paths*
@@ -1679,9 +1539,8 @@ class DecodeEngine:
             s for s in range(self.max_batch)
             if self.slot_req[s] and s not in exclude
         ]
-        if self.paged:
-            with self.tracer.span("engine.pages"):
-                active = self._ensure_decode_pages(active)
+        with self.tracer.span("engine.pages"):
+            active = self._ensure_decode_pages(active)
         if self.guard_cfg is not None:
             self._run_audits()
         if not active:
@@ -1751,11 +1610,11 @@ class DecodeEngine:
 
     def _decode_pass_main(self, active: List[int], ctx_np, ptbl_np):
         """The engine's configured (level-0) decode path: cascade grouping
-        when eligible, else the fast-path kernel step, else the legacy
-        per-tick step. Updates ``self.cache`` and returns the logits."""
+        when eligible, else the jitted kernel step. Updates ``self.cache``
+        and returns the logits."""
         csched = binding = None
         grid_steps = 0      # the fused decode kernel's, when it runs
-        if self.use_fast_path and self.cascade and self.attn_backend == "lean":
+        if self.cascade and self.attn_backend == "lean":
             csched, binding = self._cascade_schedule_for_tick(active, ctx_np)
         # benches/diagnostics read the live per-slot suffix coverage here
         self._casc_binding = binding
@@ -1799,7 +1658,7 @@ class DecodeEngine:
                 "levels": int(binding.num_levels),
                 "fused": bool(fused),
             }
-        elif self.use_fast_path:
+        else:
             # ONE schedule build (cached) serves both the stats record and
             # the kernel step — nothing is derived twice per tick
             sched = self._tick_schedule(ctx_np)
@@ -1807,18 +1666,13 @@ class DecodeEngine:
             with self.tracer.span("engine.inputs"):
                 tokens = jnp.asarray(self.next_tokens)
                 ctx = jnp.asarray(ctx_np, jnp.int32)
-                ptbl = jnp.asarray(ptbl_np) if self.paged else None
+                ptbl = jnp.asarray(ptbl_np)
             dispatch = self.tracer.span("engine.dispatch")
             if self.attn_backend == "ref":
                 with dispatch:
-                    if self.paged:
-                        logits, self.cache = self._jit_decode_paged(
-                            self.params, self.cache, tokens, ctx, ptbl
-                        )
-                    else:
-                        logits, self.cache = self._jit_decode(
-                            self.params, self.cache, tokens, ctx
-                        )
+                    logits, self.cache = self._jit_decode_paged(
+                        self.params, self.cache, tokens, ctx, ptbl
+                    )
             else:
                 num_splits = fixed_split_factor(
                     int(sched.seg_len.max(initial=1)),
@@ -1832,22 +1686,12 @@ class DecodeEngine:
                     self.stats.fused_decode_ticks += int(fused)
                     grid_steps = grid["grid_steps"] if fused else 0
                 with dispatch, _quiet_donation():
-                    if self.paged:
-                        logits, self.cache = self._jit_kernel_step_paged(
-                            self.params, self.cache, tokens, ctx, ptbl,
-                            backend=self.attn_backend, sched=sched,
-                            num_splits=num_splits, fused=self.fused,
-                            interpret=self.interpret,
-                        )
-                    else:
-                        logits, self.cache = self._jit_kernel_step(
-                            self.params, self.cache, tokens, ctx,
-                            backend=self.attn_backend, sched=sched,
-                            num_splits=num_splits, fused=self.fused,
-                            interpret=self.interpret,
-                        )
-        else:
-            logits = self._tick_legacy_step(active)
+                    logits, self.cache = self._jit_kernel_step_paged(
+                        self.params, self.cache, tokens, ctx, ptbl,
+                        backend=self.attn_backend, sched=sched,
+                        num_splits=num_splits, fused=self.fused,
+                        interpret=self.interpret,
+                    )
         self._grid_steps.set(grid_steps)
         return logits
 
@@ -1881,14 +1725,10 @@ class DecodeEngine:
         ctx_np = self.ctx_lens.copy()
         ptbl_np = self.page_tbl
         if masked:
-            if not self.use_fast_path:
-                raise RuntimeError("slot masking requires the fast path")
+            ptbl_np = self.page_tbl.copy()
             for s in masked:
                 ctx_np[s] = 0
-            if self.paged:
-                ptbl_np = self.page_tbl.copy()
-                for s in masked:
-                    ptbl_np[s, :] = 0
+                ptbl_np[s, :] = 0
         if level == 0:
             return self._decode_pass_main(slots, ctx_np, ptbl_np)
         with self.tracer.span("engine.inputs"):
@@ -2068,14 +1908,12 @@ class DecodeEngine:
         the verify sweep's when one ran. In speculative mode every value
         in the returned dict is a ``List[int]`` (single-token slots emit
         one-element lists)."""
-        # context cap: the cache row, and in paged mode also the whole
-        # pool — a context allowed past usable_pages * tile could never be
+        # context cap: the slot's capacity, and also the whole pool — a
+        # context allowed past usable_pages * tile could never be
         # re-admitted after a recompute-resume preemption (its regrown
         # prompt would fail the pool fit check), so it is finished here,
         # with its final token, like any other capacity cut
-        cap = self.cache_len
-        if self.paged:
-            cap = min(cap, self.pool.usable_pages * self.tile)
+        cap = min(self.cache_len, self.pool.usable_pages * self.tile)
         out = {}
         n_emitted = 0
         for slots, next_all, finite in results:
@@ -2100,15 +1938,14 @@ class DecodeEngine:
                     # finished sequences release their pages immediately
                     # (after offering their prefix to the radix cache) —
                     # this is what lets the pool admit more in-flight work
-                    # than a dense worst-case cache could hold
+                    # than worst-case per-slot reservations could hold
                     self.release_slot(s)
         if spec is not None:
             n_emitted += self._emit_spec_tokens(spec, out, guard_on, cap)
         self.stats.ticks += 1
         self._log_tick_tokens(self.stats.tick_decode_tokens, n_emitted)
         self.stats.schedule_cache = self.sched_cache.stats.as_dict()
-        if self.paged:
-            self.stats.kv_pool = self.pool.as_dict()
+        self.stats.kv_pool = self.pool.as_dict()
         if self.prefix_cache is not None:
             self.stats.prefix_cache = self.prefix_cache.as_dict()
         if self.guard_cfg is not None:
@@ -2283,9 +2120,7 @@ class DecodeEngine:
         if self._audit_clock % gc.audit_interval:
             return
         self.stats.audits_run += 1
-        targets = []
-        if self.pool is not None:
-            targets.append(("kv_pool", self.pool))
+        targets = [("kv_pool", self.pool)]
         if self.prefix_cache is not None:
             targets.append(("prefix_cache", self.prefix_cache))
         with self.tracer.span("audit", targets=len(targets)):
@@ -2332,7 +2167,7 @@ class DecodeEngine:
         if inj.fire("tick_latency"):
             spec = inj.spec("tick_latency")
             time.sleep(spec.magnitude if spec.magnitude > 0 else 0.002)
-        if self.paged and inj.fire("preempt_storm"):
+        if inj.fire("preempt_storm"):
             spec = inj.spec("preempt_storm")
             victims = [
                 s for s in range(self.max_batch)
@@ -2343,7 +2178,7 @@ class DecodeEngine:
                 self._preempt(s)
         if self.prefix_cache is not None and inj.fire("trie_corrupt"):
             corrupt_trie_node(self.prefix_cache, inj.rng("trie_corrupt"))
-        if self.paged and inj.fire("nan_kv"):
+        if inj.fire("nan_kv"):
             self._inject_nan_kv(exclude)
 
     def _inject_nan_kv(self, exclude):
@@ -2393,15 +2228,12 @@ class DecodeEngine:
         if cost is None:
             if len(self._sched_costs) > 128:
                 self._sched_costs.clear()
-            elem = 2
-            if self.paged and self.pool.layout is not None:
-                elem = self.pool.layout.elem_bytes
             cost = schedule_decode_cost(
                 sched,
                 n_q_heads=self.cfg.n_heads,
                 n_kv_heads=self.cfg.n_kv_heads,
                 head_dim=self.cfg.head_dim,
-                kv_elem_bytes=elem,
+                kv_elem_bytes=self.pool.layout.elem_bytes,
             )
             self._sched_costs[sched] = cost
         return cost
@@ -2409,9 +2241,9 @@ class DecodeEngine:
     def _note_schedule(self, sched: LeanSchedule, path: str):
         """The single per-pass schedule bookkeeping point — stats record
         plus trace annotation (execution path + roofline cost meta onto
-        the enclosing ``decode_kernel`` span) — shared by the cascade,
-        fast, and legacy decode paths, so the per-tick recording logic
-        exists once. Returns the fused kernel's grid for ``sched``."""
+        the enclosing ``decode_kernel`` span) — shared by the cascade and
+        kernel decode paths, so the per-tick recording logic exists once.
+        Returns the fused kernel's grid for ``sched``."""
         grid = self._record_schedule(sched)
         if self.tracer.enabled:
             self.tracer.annotate(
@@ -2422,7 +2254,7 @@ class DecodeEngine:
     def _record_schedule(self, sched: LeanSchedule) -> dict:
         # lens come from the schedule itself (one entry per batch slot), so
         # the record is internally consistent: sum(ceil(len/tile)) * Hkv ==
-        # total_tiles whether the schedule is exact (legacy) or bucketed;
+        # total_tiles whether the schedule is exact or bucketed;
         # grid_steps / iters_per_step are the fused decode kernel's grid
         # for this schedule. Returns those two.
         steps, n = fused_grid(sched, self.cfg.head_dim, self.kv_elem_bytes)
@@ -2440,50 +2272,8 @@ class DecodeEngine:
             del self.stats.schedules[: -self.SCHEDULE_LOG_CAP]
         return grid
 
-    def _tick_legacy_step(self, active: List[int]):
-        """Pre-fast-path behavior, preserved as the benchmark baseline:
-        the schedule is built for the stats record AND rebuilt inside
-        ``lean_decode``, and kernel backends run unjitted at the step
-        level."""
-        lens = [int(self.ctx_lens[s]) + 1 for s in active]
-        sched = make_schedule(
-            lens, self.cfg.n_kv_heads,
-            min(default_tile_size(self.cfg.head_dim), max(8, max(lens))),
-            self.num_workers,
-        )
-        self._note_schedule(sched, "legacy")
-
-        attn_fn = self._make_attn_fn()
-        if attn_fn is None:
-            logits, self.cache = self._jit_decode(
-                self.params, self.cache,
-                jnp.asarray(self.next_tokens),
-                jnp.asarray(self.ctx_lens, jnp.int32),
-            )
-        else:
-            # kernel-backed path (schedule depends on host lens -> no jit of
-            # the outer step; the kernel itself is jit/pallas)
-            logits, self.cache = decode_step(
-                self.params, self.cfg, self.cache,
-                jnp.asarray(self.next_tokens),
-                jnp.asarray(int(self.ctx_lens.max())),
-                attn_fn=attn_fn,
-                ctx_lens=jnp.asarray(self.ctx_lens, jnp.int32),
-            )
-        return logits
-
     def run_to_completion(self, max_ticks: int = 10_000):
         while (self.queue or any(self.slot_req)) and self.stats.ticks < max_ticks:
             self.tick()
         return self.stats
 
-
-def _copy_slot(cache, cache1, slot):
-    """Copy batch row 0 of cache1 into row ``slot`` of cache (legacy
-    full-tree rebuild, kept for the fast-path benchmark baseline)."""
-    def cp(dst, src):
-        return dst.at[:, slot].set(src[:, 0].astype(dst.dtype))
-
-    return jax.tree.map(
-        lambda d, s: cp(d, s), cache, cache1
-    )

@@ -109,7 +109,7 @@ DEGRADE_CAUSES = (
 
 @dataclass
 class GuardConfig:
-    """Engine self-healing knobs (attach via ``DecodeEngine(guards=...)``).
+    """Engine self-healing knobs (attach via ``EngineConfig(guards=...)``).
 
     ``nan_guard`` screens every decode tick's logits for non-finite rows;
     an affected slot emits no token that tick (its context does not

@@ -29,9 +29,9 @@ layer that turns those into a server:
     per-uid QUEUED -> PREFILLING -> DECODING -> FINISHED timelines with
     TTFT/TPOT derived independently of the histograms.
 
-Chunked prefill requires a paged engine and an all-global-attention
-architecture (``engine.supports_chunked_prefill()``); otherwise the
-scheduler transparently falls back to blocking admission — same lifecycle,
+Chunked prefill requires an all-global-attention architecture
+(``engine.supports_chunked_prefill()``); otherwise the scheduler
+transparently falls back to blocking admission — same lifecycle,
 same telemetry, same token streams. The blocking path doubles as the
 *oracle* for the chunked path: both must generate token-identical output
 (``tests/test_scheduler.py``).
@@ -190,8 +190,7 @@ class Scheduler:
             self.chunked = self.config.chunked
             if self.chunked and not engine.supports_chunked_prefill():
                 raise ValueError(
-                    "chunked prefill requires a paged engine and an "
-                    "all-'attn' architecture "
+                    "chunked prefill requires an all-'attn' architecture "
                     "(engine.supports_chunked_prefill() is False)"
                 )
         self.queue: List[ScheduledRequest] = []

@@ -418,17 +418,26 @@ def setup():
 
 
 def _sched_run(cfg, params, waves, *, prefix_cache, cascade,
-               backend="lean", new=4, **ekw):
+               backend="lean", new=4, num_pages=None,
+               cascade_stable_ticks=1, cascade_grouping="lcp"):
+    from repro.serving.config import (
+        CascadeConfig,
+        EngineConfig,
+        PagedConfig,
+    )
     from repro.serving.engine import DecodeEngine
     from repro.serving.scheduler import Scheduler, SchedulerConfig
 
-    if cascade:
-        ekw.setdefault("cascade_stable_ticks", 1)
-    eng = DecodeEngine(
-        cfg, params, max_batch=4, cache_len=64, attn_backend=backend,
-        num_workers=4, paged=True, page_size=8,
-        prefix_cache=prefix_cache, cascade=cascade, **ekw,
-    )
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=4, cache_len=64, attn_backend=backend, num_workers=4,
+        paged=PagedConfig(
+            page_size=8, num_pages=num_pages, prefix_cache=prefix_cache,
+        ),
+        cascade=CascadeConfig(
+            enabled=cascade, stable_ticks=cascade_stable_ticks,
+            grouping=cascade_grouping,
+        ),
+    ))
     sched = Scheduler(eng, SchedulerConfig(chunk_size=8, prefill_pack=2,
                                            token_budget=32))
     out = []
@@ -505,6 +514,11 @@ def test_engine_group_collapse_falls_back_to_paged(setup):
     the engine must leave the cascade path — no grouped pass exists — and
     keep decoding correctly on the vanilla paged path."""
     cfg, params = setup
+    from repro.serving.config import (
+        CascadeConfig,
+        EngineConfig,
+        PagedConfig,
+    )
     from repro.serving.engine import DecodeEngine
     from repro.serving.scheduler import Scheduler, SchedulerConfig
 
@@ -523,11 +537,11 @@ def test_engine_group_collapse_falls_back_to_paged(setup):
     assert eng.stats.cascade_ticks > 0
     # collapse: one sharer runs 12 tokens, the other only 2 — once the
     # short one finishes the survivor must decode OFF the cascade path
-    eng2 = DecodeEngine(
-        cfg, params, max_batch=2, cache_len=64, attn_backend="lean",
-        num_workers=4, paged=True, page_size=8, prefix_cache=True,
-        cascade=True, cascade_stable_ticks=1,
-    )
+    eng2 = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=2, cache_len=64, attn_backend="lean", num_workers=4,
+        paged=PagedConfig(page_size=8, prefix_cache=True),
+        cascade=CascadeConfig(enabled=True, stable_ticks=1),
+    ))
     sched2 = Scheduler(eng2, SchedulerConfig(chunk_size=8, prefill_pack=2,
                                              token_budget=32))
     sched2.submit(np.concatenate([shared, [1]]), max_new_tokens=1)

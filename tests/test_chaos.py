@@ -22,6 +22,7 @@ from hypothesis import strategies as st
 from repro.configs import get_smoke_config
 from repro.models import init_params
 from repro.obs.flight import load_flight_dump
+from repro.serving.config import EngineConfig, ObsConfig, PagedConfig
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.faults import FaultInjector, FaultSpec
 from repro.serving.guards import GuardConfig
@@ -37,15 +38,17 @@ def setup():
     return cfg, params
 
 
-def _mk_engine(cfg, params, *, faults=None, guards=None, **kw):
+def _mk_engine(cfg, params, *, faults=None, guards=None,
+               prefix_cache=False, flight_dir=None, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("cache_len", 32)
     kw.setdefault("num_workers", 4)
-    kw.setdefault("page_size", 8)
-    return DecodeEngine(
-        cfg, params, attn_backend="lean", paged=True,
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        attn_backend="lean",
+        paged=PagedConfig(page_size=8, prefix_cache=prefix_cache),
+        obs=ObsConfig(flight_dir=flight_dir),
         faults=faults, guards=guards, **kw,
-    )
+    ))
 
 
 def _requests(cfg, n=4, new=12, seed=0):

@@ -10,8 +10,7 @@ from pathlib import Path
 from benchmarks.check_regression import METRICS, check
 
 DOC = {
-    "decode_step": {"speedup_vs_legacy": 500.0},
-    "paged": {"paged_over_dense_throughput": 0.9},
+    "decode_step": {"ticks_per_sec_fast": 500.0},
     "scheduler": {"chunked": {"decode_tokens_while_long_prefilling": 15}},
     "prefix": {
         "headline": {
@@ -45,14 +44,16 @@ def test_injected_regression_fails_every_suite():
 
 def test_single_suite_regression_fails_only_that_suite():
     cur = copy.deepcopy(DOC)
-    cur["paged"]["paged_over_dense_throughput"] = 0.9 * 0.8
+    cur["prefix"]["headline"]["decode_speedup_cascade"] = 1.4 * 0.8
     rows, failures = check(cur, DOC)
-    assert failures == ["paged"]
+    assert failures == ["prefix_cascade"]
 
 
 def test_within_threshold_drift_passes():
     cur = copy.deepcopy(DOC)
-    cur["decode_step"]["speedup_vs_legacy"] = 500.0 * 0.9   # -10% < 15%
+    cur["scheduler"]["chunked"]["decode_tokens_while_long_prefilling"] = (
+        15 * 0.9                                            # -10% < 15%
+    )
     _rows, failures = check(cur, DOC)
     assert failures == []
 
@@ -267,7 +268,7 @@ def test_update_baseline_clamps_parity_ratios(tmp_path: Path):
     doc = json.loads(out.read_text())
     assert doc["hardening"]["hardened_over_plain_throughput"] == 1.0
     assert doc["observability"]["traced_over_untraced_throughput"] == 0.99
-    assert doc["decode_step"]["speedup_vs_legacy"] == 500.0
+    assert doc["quant"]["capacity_ratio_vs_bf16"] == 1.9
     # the regenerated baseline gates cleanly against the artifact it
     # came from
     _rows, failures = check(cur, doc)

@@ -1,23 +1,14 @@
-"""EngineConfig: typed constructor surface vs the deprecated loose-kwarg
-surface. The contract: both spell the *same* engine — identical subsystem
-wiring, identical decoded streams — and mixing them is an error.
+"""EngineConfig: the engine's one constructor argument. Its defaults build
+a paged ``ref`` engine, its sinks thread through, and the page pool is the
+only KV layout it accepts.
 """
-import warnings
-
 import jax
-import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import init_params
-from repro.serving.config import (
-    CascadeConfig,
-    EngineConfig,
-    ObsConfig,
-    PagedConfig,
-    SpecConfig,
-)
-from repro.serving.engine import DecodeEngine, Request
+from repro.serving.config import EngineConfig, ObsConfig, PagedConfig
+from repro.serving.engine import DecodeEngine
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -29,97 +20,20 @@ def setup():
     return cfg, params
 
 
-_LEGACY = dict(
-    max_batch=3, cache_len=64, attn_backend="lean", num_workers=8,
-    paged=True, page_size=8, kv_dtype="int8", prefix_cache=True,
-    cascade=True, cascade_fused=False, cascade_stable_ticks=3,
-    schedule_cache_entries=64,
-)
-
-
-def _nested():
-    return EngineConfig(
-        max_batch=3, cache_len=64, attn_backend="lean", num_workers=8,
-        paged=PagedConfig(enabled=True, page_size=8, kv_dtype="int8",
-                          prefix_cache=True),
-        cascade=CascadeConfig(enabled=True, fused=False, stable_ticks=3),
-        schedule_cache_entries=64,
-    )
-
-
-def test_from_legacy_maps_every_group():
-    assert EngineConfig.from_legacy(**_LEGACY) == _nested()
-
-
-def test_from_legacy_unknown_kwarg_is_typeerror():
-    with pytest.raises(TypeError, match="unexpected keyword 'pagesize'"):
-        EngineConfig.from_legacy(pagesize=8)
-
-
-def test_legacy_ctor_warns_once_and_matches_config_ctor(setup):
-    cfg, params = setup
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        legacy = DecodeEngine(cfg, params, **_LEGACY)
-    deps = [w for w in caught if issubclass(w.category, DeprecationWarning)
-            and "EngineConfig" in str(w.message)]
-    assert len(deps) == 1
-
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        typed = DecodeEngine(cfg, params, config=_nested())
-    assert not [w for w in caught
-                if issubclass(w.category, DeprecationWarning)]
-
-    # same resolved config object, same subsystem wiring
-    assert legacy.config == typed.config == _nested()
-    for attr in ("max_batch", "cache_len", "attn_backend", "tile",
-                 "pages_per_slot", "cascade", "spec_k"):
-        assert getattr(legacy, attr) == getattr(typed, attr), attr
-    assert (legacy.pool is None) == (typed.pool is None)
-    assert (legacy.prefix_cache is None) == (typed.prefix_cache is None)
-
-
-def test_legacy_and_typed_streams_identical(setup):
-    cfg, params = setup
-
-    def run(eng):
-        reqs = [
-            Request(uid=i,
-                    prompt=np.arange(1, 7 + 3 * i) % cfg.vocab_size,
-                    max_new_tokens=8)
-            for i in range(3)
-        ]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_to_completion(max_ticks=200)
-        return {r.uid: list(r.generated) for r in reqs}
-
-    with pytest.warns(DeprecationWarning):
-        legacy = DecodeEngine(cfg, params, **_LEGACY)
-    typed = DecodeEngine(cfg, params, config=_nested())
-    assert run(legacy) == run(typed)
-
-
-def test_config_plus_legacy_kwargs_is_typeerror(setup):
-    cfg, params = setup
-    with pytest.raises(TypeError, match="not both"):
-        DecodeEngine(cfg, params, config=EngineConfig(), max_batch=2)
-
-
-def test_unknown_legacy_kwarg_is_typeerror(setup):
-    cfg, params = setup
-    with pytest.raises(TypeError, match="unexpected keyword"):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            DecodeEngine(cfg, params, max_batch=2, bogus_knob=1)
-
-
-def test_config_defaults_are_dense_ref_engine(setup):
+def test_config_defaults_are_paged_ref_engine(setup):
     cfg, params = setup
     eng = DecodeEngine(cfg, params, config=EngineConfig())
-    assert eng.pool is None and eng.spec_k == 0 and not eng.cascade
+    assert eng.pool is not None and eng.spec_k == 0 and not eng.cascade
+    assert eng.attn_backend == "ref" and eng.prefix_cache is None
+    # default pool: every slot's full page run plus the null page
+    assert eng.pool.num_pages == 1 + eng.max_batch * eng.pages_per_slot
     assert eng.config == EngineConfig()
+
+
+def test_paged_config_disabled_is_rejected():
+    with pytest.raises(ValueError, match="one KV layout"):
+        PagedConfig(enabled=False)
+    assert PagedConfig(enabled=True) == PagedConfig()
 
 
 def test_obs_config_threads_sinks(setup):
@@ -133,8 +47,3 @@ def test_obs_config_threads_sinks(setup):
         config=EngineConfig(obs=ObsConfig(tracer=tracer, metrics=metrics)),
     )
     assert eng.tracer is tracer and eng.metrics is metrics
-
-
-def test_spec_config_round_trips_through_legacy_surface():
-    # spec has no legacy spelling — from_legacy always yields the default
-    assert EngineConfig.from_legacy(max_batch=2).spec == SpecConfig()

@@ -16,6 +16,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.kernels.ops import _clamp_ctx_lens
 from repro.models import init_params
+from repro.serving.config import CascadeConfig, EngineConfig, PagedConfig
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.faults import (
     FaultInjector,
@@ -345,11 +346,11 @@ def test_corrupt_trie_node_detected_and_repaired():
 
 # ---------------------------------------------------------- engine audits
 def _guarded_engine(cfg, params, **gkw):
-    return DecodeEngine(
-        cfg, params, max_batch=2, cache_len=32, attn_backend="lean",
-        num_workers=4, paged=True, page_size=8, prefix_cache=True,
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=2, cache_len=32, attn_backend="lean", num_workers=4,
+        paged=PagedConfig(page_size=8, prefix_cache=True),
         guards=GuardConfig(audit_interval=1, **gkw),
-    )
+    ))
 
 
 def _submit_and_tick(eng, cfg, n_ticks=2, new=8):
@@ -359,13 +360,6 @@ def _submit_and_tick(eng, cfg, n_ticks=2, new=8):
     for _ in range(n_ticks):
         eng.tick()
     return eng
-
-
-def test_guards_require_paged(setup):
-    cfg, params = setup
-    with pytest.raises(ValueError, match="require paged"):
-        DecodeEngine(cfg, params, max_batch=2, cache_len=32,
-                     guards=GuardConfig())
 
 
 def test_audit_action_raise_surfaces_fatal_invariant(setup):
@@ -418,12 +412,12 @@ def test_guarded_engine_tokens_identical_when_healthy(setup):
                     max_new_tokens=5)
             for i in range(3)
         ]
-        eng = DecodeEngine(
-            cfg, params, max_batch=2, cache_len=32, attn_backend="lean",
-            num_workers=4, paged=True, page_size=8,
+        eng = DecodeEngine(cfg, params, config=EngineConfig(
+            max_batch=2, cache_len=32, attn_backend="lean", num_workers=4,
+            paged=PagedConfig(page_size=8),
             faults=FaultInjector({}, enabled=False) if guarded else None,
             guards=GuardConfig(audit_interval=2) if guarded else None,
-        )
+        ))
         for r in reqs:
             eng.submit(r)
         eng.run_to_completion(max_ticks=60)
@@ -437,10 +431,10 @@ def test_guarded_engine_tokens_identical_when_healthy(setup):
 
 # --------------------------------------------- scheduler deadlines / backoff
 def _sched(cfg, params, *, max_batch=2, num_pages=None, chunked=None, **skw):
-    eng = DecodeEngine(
-        cfg, params, max_batch=max_batch, cache_len=32, attn_backend="ref",
-        paged=True, page_size=8, num_pages=num_pages,
-    )
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=max_batch, cache_len=32, attn_backend="ref",
+        paged=PagedConfig(page_size=8, num_pages=num_pages),
+    ))
     return Scheduler(eng, SchedulerConfig(
         chunk_size=8, prefill_pack=1, token_budget=16, chunked=chunked,
         **skw,
@@ -579,12 +573,15 @@ def test_pool_exhaustion_mid_cascade_recovers_token_identical(setup):
     ]
     outs = {}
     for tight in (False, True):
-        eng = DecodeEngine(
-            cfg, params, max_batch=4, cache_len=64, attn_backend="lean",
-            num_workers=4, paged=True, page_size=8,
-            num_pages=8 if tight else None,       # 7 usable vs ample
-            prefix_cache=True, cascade=True, cascade_stable_ticks=1,
-        )
+        eng = DecodeEngine(cfg, params, config=EngineConfig(
+            max_batch=4, cache_len=64, attn_backend="lean", num_workers=4,
+            paged=PagedConfig(
+                page_size=8,
+                num_pages=8 if tight else None,   # 7 usable vs ample
+                prefix_cache=True,
+            ),
+            cascade=CascadeConfig(enabled=True, stable_ticks=1),
+        ))
         sch = Scheduler(eng, SchedulerConfig(
             chunk_size=8, prefill_pack=2, token_budget=32,
         ))

@@ -313,11 +313,11 @@ def smoke():
 
 def test_traced_scheduler_run_timeline_matches_lifecycle(smoke, tmp_path):
     cfg, params = smoke
-    eng = DecodeEngine(
-        cfg, params, max_batch=2, cache_len=64, num_workers=4,
-        attn_backend="lean", paged=True, page_size=8,
-        tracer=Tracer(),
-    )
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=2, cache_len=64, num_workers=4, attn_backend="lean",
+        paged=PagedConfig(page_size=8),
+        obs=ObsConfig(tracer=Tracer()),
+    ))
     sch = Scheduler(eng, SchedulerConfig(
         chunk_size=8, prefill_pack=2, token_budget=16,
     ))
@@ -396,10 +396,10 @@ def test_decode_records_the_fused_kernels_grid(smoke):
 
 def test_untraced_engine_records_nothing(smoke):
     cfg, params = smoke
-    eng = DecodeEngine(
-        cfg, params, max_batch=2, cache_len=32, num_workers=4,
-        attn_backend="lean", paged=True, page_size=8,
-    )
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=2, cache_len=32, num_workers=4, attn_backend="lean",
+        paged=PagedConfig(page_size=8),
+    ))
     sch = Scheduler(eng, SchedulerConfig(chunk_size=8))
     h = sch.submit(np.arange(5), 2)
     sch.run_to_completion(max_steps=50)

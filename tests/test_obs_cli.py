@@ -13,6 +13,7 @@ from repro.models import init_params
 from repro.obs import SLOConfig, Tracer, WatchConfig
 from repro.obs.__main__ import main
 from repro.obs.watch import PerfWatchdog
+from repro.serving.config import EngineConfig, ObsConfig, PagedConfig
 from repro.serving.engine import DecodeEngine
 from repro.serving.scheduler import Scheduler, SchedulerConfig
 
@@ -30,11 +31,11 @@ def artifacts(tmp_path_factory):
     cfg = get_smoke_config("mistral-nemo-12b")
     params = init_params(jax.random.PRNGKey(0), cfg)
     tracer = Tracer()
-    eng = DecodeEngine(
-        cfg, params, max_batch=4, cache_len=32, attn_backend="lean",
-        num_workers=4, paged=True, page_size=8, tracer=tracer,
-        flight_dir=str(tmp),
-    )
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=4, cache_len=32, attn_backend="lean", num_workers=4,
+        paged=PagedConfig(page_size=8),
+        obs=ObsConfig(tracer=tracer, flight_dir=str(tmp)),
+    ))
     # near-zero targets guarantee breaches -> non-empty budget table,
     # and a guaranteed slo_burn firing -> a watchdog-armed dump
     wd = PerfWatchdog(

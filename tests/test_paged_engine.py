@@ -1,11 +1,13 @@
 """Paged serving engine: admission/free lifecycle, pool accounting, token
-equivalence with the dense engine, and churn stress (marked slow)."""
+equivalence with a plain batch-1 model loop, and churn stress (marked
+slow)."""
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import init_params
+from repro.serving.config import EngineConfig, PagedConfig
 from repro.serving.engine import DecodeEngine, Request
 
 jax.config.update("jax_platform_name", "cpu")
@@ -30,55 +32,67 @@ def _requests(cfg, n=3, seed=0, plen=lambda i: 8 + 7 * i, new=6):
     ]
 
 
+def _engine(cfg, params, *, page_size=None, num_pages=None, kv_dtype=None,
+            **kw):
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        paged=PagedConfig(
+            page_size=page_size, num_pages=num_pages, kv_dtype=kv_dtype,
+        ),
+        **kw,
+    ))
+
+
 def _run(cfg, params, reqs, max_ticks=80, **kw):
-    eng = DecodeEngine(cfg, params, **kw)
+    eng = _engine(cfg, params, **kw)
     for r in reqs:
         eng.submit(r)
     eng.run_to_completion(max_ticks=max_ticks)
     return eng
 
 
-def test_paged_engine_tokens_match_dense_ref(setup):
-    cfg, params = setup
-    outs = {}
-    for paged in (False, True):
-        reqs = _requests(cfg)
-        _run(cfg, params, reqs, max_batch=2, cache_len=64,
-             attn_backend="ref", paged=paged, page_size=16 if paged else None)
-        outs[paged] = [tuple(r.generated) for r in reqs]
-    assert outs[True] == outs[False], "paged ref engine diverged from dense"
+@pytest.mark.parametrize(
+    "arch, backend",
+    [
+        (arch, backend)
+        for arch in ("mistral-nemo-12b", "recurrentgemma-9b", "xlstm-350m",
+                     "nemotron-4-15b")
+        for backend in ("ref", "lean")
+    ] + [("gemma3-4b", "lean")],
+)
+def test_paged_engine_tokens_match_model_loop(model_loop, arch, backend):
+    """Ragged continuous batching over the page pool (3 requests through 2
+    slots) gives each request the tokens of its own batch-1 model loop —
+    global-attention pools, window rings, recurrent and mLSTM/sLSTM state
+    alike."""
+    cfg = get_smoke_config(arch)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    reqs = _requests(cfg, new=5)
+    _run(cfg, params, reqs, max_batch=2, cache_len=64, num_workers=4,
+         attn_backend=backend, page_size=16)
+    for r in reqs:
+        assert list(r.generated) == model_loop(
+            cfg, params, r.prompt, 5, cache_len=64
+        ), r.uid
 
 
-def test_paged_engine_tokens_match_dense_lean(setup):
-    cfg, params = setup
-    outs = {}
-    for paged in (False, True):
-        reqs = _requests(cfg, new=4)
-        _run(cfg, params, reqs, max_batch=2, cache_len=32, num_workers=4,
-             attn_backend="lean", paged=paged, page_size=8 if paged else None)
-        outs[paged] = [tuple(r.generated) for r in reqs]
-    assert outs[True] == outs[False], "paged lean engine diverged from dense"
-
-
-def test_paged_fresh_admit_single_token_prompt(setup):
+def test_paged_fresh_admit_single_token_prompt(setup, model_loop):
     """ctx==0 freshly-admitted edge at the engine level: a 1-token prompt
     admitted into an otherwise idle paged engine decodes identically to the
-    dense engine from its very first tick."""
+    batch-1 model loop from its very first tick."""
     cfg, params = setup
-    outs = {}
-    for paged in (False, True):
-        reqs = _requests(cfg, n=1, plen=lambda i: 1, new=3)
-        _run(cfg, params, reqs, max_batch=2, cache_len=32,
-             attn_backend="ref", paged=paged, page_size=8 if paged else None)
-        outs[paged] = [tuple(r.generated) for r in reqs]
-    assert outs[True] == outs[False]
+    reqs = _requests(cfg, n=1, plen=lambda i: 1, new=3)
+    _run(cfg, params, reqs, max_batch=2, cache_len=32,
+         attn_backend="ref", page_size=8)
+    assert list(reqs[0].generated) == model_loop(
+        cfg, params, reqs[0].prompt, 3, cache_len=32
+    )
 
 
 def test_pool_accounting_no_leaks_after_drain(setup):
     cfg, params = setup
     reqs = _requests(cfg, n=5, seed=3)
     eng = _run(cfg, params, reqs, max_batch=2, cache_len=64,
-               attn_backend="ref", paged=True, page_size=16)
+               attn_backend="ref", page_size=16)
     eng.pool.check()
     assert eng.pool.num_allocated == 0
     assert eng.pool.num_free == eng.pool.usable_pages
@@ -89,8 +103,8 @@ def test_pool_accounting_no_leaks_after_drain(setup):
 
 def test_pool_admit_evict_hooks_fire(setup):
     cfg, params = setup
-    eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                       attn_backend="ref", paged=True, page_size=16)
+    eng = _engine(cfg, params, max_batch=2, cache_len=64,
+                  attn_backend="ref", page_size=16)
     events = []
     eng.pool.on_admit.append(lambda seq, pages: events.append(("+", seq, len(pages))))
     eng.pool.on_evict.append(lambda seq, pages: events.append(("-", seq, len(pages))))
@@ -108,17 +122,15 @@ def test_infeasible_request_fails_fast_not_livelock(setup):
     silently retrying (or prefill+preempt cycling) forever."""
     cfg, params = setup
     # 2 usable pages of 16 tokens; a 64-token prompt needs 4
-    eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                       attn_backend="ref", paged=True, page_size=16,
-                       num_pages=3)
+    eng = _engine(cfg, params, max_batch=2, cache_len=64,
+                  attn_backend="ref", page_size=16, num_pages=3)
     eng.submit(Request(uid=0, prompt=np.arange(64) % cfg.vocab_size,
                        max_new_tokens=2))
     with pytest.raises(RuntimeError, match="usable pages"):
         eng.run_to_completion(max_ticks=10)
     # prompt fits exactly but the first decode write does not: also caught
-    eng2 = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                        attn_backend="ref", paged=True, page_size=16,
-                        num_pages=2)
+    eng2 = _engine(cfg, params, max_batch=2, cache_len=64,
+                   attn_backend="ref", page_size=16, num_pages=2)
     eng2.submit(Request(uid=1, prompt=np.arange(16) % cfg.vocab_size,
                         max_new_tokens=2))
     with pytest.raises(RuntimeError, match="usable pages"):
@@ -129,7 +141,7 @@ def test_schedule_cache_hit_rate_stays_high_under_paging(setup):
     cfg, params = setup
     reqs = _requests(cfg, n=6, seed=5, new=8)
     eng = _run(cfg, params, reqs, max_ticks=200, max_batch=2, cache_len=64,
-               attn_backend="ref", paged=True, page_size=16)
+               attn_backend="ref", page_size=16)
     st = eng.stats.schedule_cache
     assert st["hit_rate"] > 0.5, st
     assert st["hits"] >= eng.stats.ticks - st["misses"]
@@ -143,9 +155,9 @@ def test_paged_lifecycle_churn_stress(setup):
     pages leak, and the schedule cache keeps hitting."""
     cfg, params = setup
     rng = np.random.default_rng(42)
-    eng = DecodeEngine(cfg, params, max_batch=4, cache_len=64,
-                       attn_backend="ref", paged=True, page_size=8,
-                       num_pages=1 + 3 * 8)    # 24 usable vs 32 dense-equiv
+    eng = _engine(cfg, params, max_batch=4, cache_len=64,
+                  attn_backend="ref", page_size=8,
+                  num_pages=1 + 3 * 8)    # 24 usable vs 32 for every slot
     reqs = [
         Request(uid=i,
                 prompt=rng.integers(0, cfg.vocab_size, int(rng.integers(1, 30))),
@@ -201,7 +213,7 @@ def test_fused_decode_ticks_follow_the_vmem_gate(
     if budget is not None:
         monkeypatch.setattr(ops, "FUSED_VMEM_BUDGET", budget)
     kw = dict(max_batch=2, cache_len=32, num_workers=4, attn_backend="lean",
-              paged=True, page_size=8, kv_dtype=kv_dtype)
+              page_size=8, kv_dtype=kv_dtype)
     reqs = _requests(cfg, new=4)
     eng = _run(cfg, params, reqs, **kw)
     assert eng.kv_elem_bytes == eng.cache[0][0]["k"].dtype.itemsize

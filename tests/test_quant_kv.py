@@ -37,6 +37,7 @@ from repro.kernels.ops import (
 )
 from repro.kernels.ref import lean_decode_ref
 from repro.models import init_params
+from repro.serving.config import EngineConfig, PagedConfig
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.kvpool import KVLayout, KVPagePool
 
@@ -415,13 +416,14 @@ def setup():
     return cfg, params
 
 
-def _engine(cfg, params, **kw):
+def _engine(cfg, params, *, page_size=8, kv_dtype=None, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("cache_len", 32)
     kw.setdefault("num_workers", 4)
-    kw.setdefault("page_size", 8)
     kw.setdefault("attn_backend", "lean")
-    return DecodeEngine(cfg, params, paged=True, **kw)
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        paged=PagedConfig(page_size=page_size, kv_dtype=kv_dtype), **kw,
+    ))
 
 
 def _streams(eng, cfg, n=3, new=10, seed=0):
@@ -466,13 +468,6 @@ def test_engine_int8_streams_consistent_and_near_bf16(setup):
     )
     assert lay.page_bytes < bf16.page_bytes
     eng.pool.check(scales=eng._kv_scale_arrays())
-
-
-def test_engine_int8_requires_paged(setup):
-    cfg, params = setup
-    with pytest.raises(ValueError):
-        DecodeEngine(cfg, params, attn_backend="lean", paged=False,
-                     kv_dtype="int8", max_batch=2, cache_len=32)
 
 
 def test_fill_page_poisons_and_scrubs_via_scales(setup):

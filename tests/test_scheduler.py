@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import get_smoke_config
 from repro.core.leantile import bucket_length
 from repro.models import init_params
+from repro.serving.config import EngineConfig, PagedConfig
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.scheduler import (
     RequestState,
@@ -42,13 +43,19 @@ def _prompts(cfg, n=4, seed=0, base=8, step=7):
     return [rng.integers(0, cfg.vocab_size, base + step * i) for i in range(n)]
 
 
-def _paged_engine(cfg, params, backend="ref", **kw):
+def _paged_engine(cfg, params, backend="ref", *, page_size=16,
+                  num_pages=None, prefix_cache=False, **kw):
     kw.setdefault("max_batch", 2)
     kw.setdefault("cache_len", 64)
     kw.setdefault("num_workers", 8)
-    return DecodeEngine(
-        cfg, params, attn_backend=backend, paged=True, page_size=16, **kw
-    )
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        attn_backend=backend,
+        paged=PagedConfig(
+            page_size=page_size, num_pages=num_pages,
+            prefix_cache=prefix_cache,
+        ),
+        **kw,
+    ))
 
 
 def _record_logits(eng, into):
@@ -220,8 +227,9 @@ def test_prefill_compile_count_bounded(smoke):
     prompts = [rng.integers(0, cfg.vocab_size, L) for L in lengths]
 
     def run(bucket: bool):
-        eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                           attn_backend="ref")
+        eng = DecodeEngine(cfg, params, config=EngineConfig(
+            max_batch=2, cache_len=64, attn_backend="ref",
+        ))
         eng.bucket_prefill = bucket
         for i, p in enumerate(prompts):
             eng.submit(Request(uid=i, prompt=p, max_new_tokens=3))
@@ -251,8 +259,9 @@ def test_priority_policy_and_starvation_bound(smoke):
     no admission ever passes over a starving request."""
     cfg, params = smoke
     rng = np.random.default_rng(6)
-    eng = DecodeEngine(cfg, params, max_batch=1, cache_len=64,
-                       attn_backend="ref")
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=1, cache_len=64, attn_backend="ref",
+    ))
     bound = 4
     sch = Scheduler(eng, SchedulerConfig(
         policy="priority", starvation_bound=bound, chunked=False,
@@ -353,9 +362,8 @@ def test_pool_capacity_cut_instead_of_unservable_regrowth(smoke):
     such sequences at the pool bound instead — with a final token, like
     the cache-capacity cut — and keep serving everyone else."""
     cfg, params = smoke
-    eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                       attn_backend="ref", paged=True, page_size=8,
-                       num_pages=1 + 4)       # 4 usable pages = 32 tokens
+    eng = _paged_engine(cfg, params, "ref", page_size=8,
+                        num_pages=1 + 4)  # 4 usable pages = 32 tokens
     sch = Scheduler(eng, SchedulerConfig(chunk_size=8, chunked=True))
     events = []
     big = sch.submit(np.arange(28, dtype=np.int32), 10_000, uid=0,

@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import init_params
+from repro.serving.config import EngineConfig
 from repro.serving.engine import DecodeEngine, Request
 
 jax.config.update("jax_platform_name", "cpu")
@@ -33,7 +34,9 @@ def _requests(cfg, n=3, seed=0):
 
 def test_engine_generates_and_drains(setup):
     cfg, params = setup
-    eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64)
+    eng = DecodeEngine(
+        cfg, params, config=EngineConfig(max_batch=2, cache_len=64)
+    )
     reqs = _requests(cfg)
     for r in reqs:
         eng.submit(r)
@@ -48,8 +51,9 @@ def test_engine_backends_token_identical(setup):
     cfg, params = setup
     outs = {}
     for backend in ("ref", "lean", "fixed"):
-        eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                           attn_backend=backend, num_workers=8)
+        eng = DecodeEngine(cfg, params, config=EngineConfig(
+            max_batch=2, cache_len=64, attn_backend=backend, num_workers=8,
+        ))
         reqs = _requests(cfg)
         for r in reqs:
             eng.submit(r)
@@ -64,8 +68,9 @@ def test_fast_path_steady_state_zero_schedule_builds(setup):
     numpy schedule work — every tick after warmup is a schedule-cache hit
     (the jitted step replays under the same schedule signature)."""
     cfg, params = setup
-    eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                       attn_backend="lean", num_workers=8)
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=2, cache_len=64, attn_backend="lean", num_workers=8,
+    ))
     for r in _requests(cfg):
         eng.submit(r)
     eng.run_to_completion(max_ticks=50)
@@ -75,29 +80,14 @@ def test_fast_path_steady_state_zero_schedule_builds(setup):
     assert st["hit_rate"] > 0.5
 
 
-def test_fast_path_matches_legacy_ref_tokens(setup):
-    """The jitted fast path (cached schedules, dynamic-update-slice admit)
-    must be a pure perf refactor: token-for-token identical to the legacy
-    unjitted reference engine."""
-    cfg, params = setup
-    outs = {}
-    for fast in (True, False):
-        eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                           attn_backend="ref", use_fast_path=fast)
-        reqs = _requests(cfg)
-        for r in reqs:
-            eng.submit(r)
-        eng.run_to_completion(max_ticks=50)
-        outs[fast] = [tuple(r.generated) for r in reqs]
-    assert outs[True] == outs[False]
-
-
 def test_fused_and_two_phase_engine_tokens_identical(setup):
     cfg, params = setup
     outs = {}
     for fused in (True, False):
-        eng = DecodeEngine(cfg, params, max_batch=2, cache_len=64,
-                           attn_backend="lean", num_workers=8, fused=fused)
+        eng = DecodeEngine(cfg, params, config=EngineConfig(
+            max_batch=2, cache_len=64, attn_backend="lean", num_workers=8,
+            fused=fused,
+        ))
         reqs = _requests(cfg)
         for r in reqs:
             eng.submit(r)
@@ -110,8 +100,9 @@ def test_ragged_schedules_are_balanced(setup):
     """Every tick's lean schedule gives each worker the same tile count
     (the paper's Fig. 6 property) despite ragged slot lengths."""
     cfg, params = setup
-    eng = DecodeEngine(cfg, params, max_batch=3, cache_len=64,
-                       num_workers=8)
+    eng = DecodeEngine(cfg, params, config=EngineConfig(
+        max_batch=3, cache_len=64, num_workers=8,
+    ))
     for r in _requests(cfg):
         eng.submit(r)
     eng.run_to_completion(max_ticks=50)

@@ -149,15 +149,14 @@ def test_spec_partial_accept_identity(setup):
     assert 0 < eng.stats.spec_accepted_tokens < eng.stats.spec_draft_tokens
 
 
-def test_spec_dense_nonspec_matches_paged_spec(setup):
-    """Cross-layout: dense non-spec ref == paged speculative ref."""
+def test_spec_dense_nonspec_matches_paged_spec(setup, model_loop):
+    """Cross-layout: the plain batch-1 model loop over a dense cache ==
+    the paged speculative ref engine."""
     cfg, params = setup
-    reqs = _requests(cfg)
-    dense = DecodeEngine(
-        cfg, params,
-        config=EngineConfig(max_batch=4, cache_len=64, attn_backend="ref"),
-    )
-    ref = _run(dense, reqs)
+    ref = {
+        r.uid: model_loop(cfg, params, r.prompt, r.max_new_tokens, 64)
+        for r in _requests(cfg)
+    }
     spec = SpecConfig(enabled=True, k=4, proposer=OracleProposer(ref))
     got = _run(_engine(cfg, params, spec=spec), _requests(cfg))
     assert got == ref
@@ -242,8 +241,10 @@ def test_spec_accept_rate_gauge(setup):
     assert rate == pytest.approx(expect)
 
 
-def test_spec_requires_chunked_prefill_machinery(setup):
-    cfg, params = setup
+def test_spec_requires_chunked_prefill_machinery():
+    # window rings and recurrent state have no chunk-resume plumbing
+    cfg = get_smoke_config("recurrentgemma-9b")
+    params = init_params(jax.random.PRNGKey(0), cfg)
     with pytest.raises(ValueError, match="speculative"):
         DecodeEngine(
             cfg, params,

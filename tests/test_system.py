@@ -11,6 +11,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.data.pipeline import DataConfig, batch_at
 from repro.models import init_params
+from repro.serving.config import EngineConfig
 from repro.serving.engine import DecodeEngine, Request
 from repro.training.checkpoint import restore_checkpoint, save_checkpoint
 from repro.training.optimizer import OptConfig, adamw_init
@@ -49,7 +50,9 @@ def test_train_checkpoint_restart_serve_lifecycle():
         assert np.isfinite(float(m2["loss"]))
 
         # serve the trained weights
-        eng = DecodeEngine(cfg, params2, max_batch=2, cache_len=64)
+        eng = DecodeEngine(
+            cfg, params2, config=EngineConfig(max_batch=2, cache_len=64)
+        )
         reqs = [Request(uid=i, prompt=np.arange(5 + i) % cfg.vocab_size,
                         max_new_tokens=4) for i in range(3)]
         for r in reqs:

@@ -32,6 +32,7 @@ from repro.obs.watch import (
     RetraceStormDetector,
     TickSpikeDetector,
 )
+from repro.serving.config import EngineConfig, ObsConfig, PagedConfig
 from repro.serving.engine import DecodeEngine, Request
 from repro.serving.faults import FaultInjector, FaultSpec
 from repro.serving.guards import GuardConfig
@@ -47,14 +48,19 @@ def setup():
     return cfg, params
 
 
-def _mk_engine(cfg, params, **kw):
+def _mk_engine(cfg, params, *, prefix_cache=False, tracer=None,
+               watchdog=None, flight_dir=None, **kw):
     kw.setdefault("max_batch", 4)
     kw.setdefault("cache_len", 32)
     kw.setdefault("num_workers", 4)
-    kw.setdefault("paged", True)
-    kw.setdefault("page_size", 8)
     kw.setdefault("attn_backend", "lean")
-    return DecodeEngine(cfg, params, **kw)
+    return DecodeEngine(cfg, params, config=EngineConfig(
+        paged=PagedConfig(page_size=8, prefix_cache=prefix_cache),
+        obs=ObsConfig(
+            tracer=tracer, watchdog=watchdog, flight_dir=flight_dir,
+        ),
+        **kw,
+    ))
 
 
 # ------------------------------------------------------------- detectors
